@@ -89,9 +89,9 @@ def main():
                    "./gpt2_bpe, falling back to tiktoken")
     args = p.parse_args()
 
-    from mamba_distributed_tpu.utils.platform import honor_jax_platforms_env
+    from mamba_distributed_tpu.utils.platform import configure_compile_cache
 
-    honor_jax_platforms_env()
+    configure_compile_cache()
 
     from mamba_distributed_tpu.eval import evaluate_hellaswag, iterate_examples
     from mamba_distributed_tpu.models import lm_forward

@@ -44,9 +44,9 @@ def parse_args():
 def main():
     args = parse_args()
 
-    from mamba_distributed_tpu.utils.platform import honor_jax_platforms_env
+    from mamba_distributed_tpu.utils.platform import configure_compile_cache
 
-    honor_jax_platforms_env()
+    configure_compile_cache()
 
     import jax
     import jax.numpy as jnp

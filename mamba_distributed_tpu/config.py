@@ -90,9 +90,8 @@ class ModelConfig:
     # SP, ulysses runs flash after its head all-to-all and ring runs the
     # flash pair kernels per hop (fully-future hops skipped outright).
     # Decode steps always use the tiny-t XLA path.  "auto" (default)
-    # resolves to "pallas" on TPU — where the flash kernels measured +12%
-    # train throughput on hybrid-280m (round-4 sweep, MEASUREMENTS.md) —
-    # and "xla" elsewhere (ops/pallas/common.py:resolve_attn_impl).
+    # resolves to "pallas" on TPU and "xla" elsewhere
+    # (ops/pallas/common.py:resolve_attn_impl).
     attn_impl: str = "auto"
 
     # --- precision policy (reference: bf16 autocast + fp32 master weights,

@@ -1,6 +1,6 @@
 """ctypes binding + lazy build for the native C++ shard reader.
 
-Builds ``data/native/shard_reader.cc`` once per machine (g++ -O3 -shared)
+Builds ``data/native/shard_reader.cc`` once per checkout (g++ -O3 -shared)
 into a cache directory and exposes ``NativeShard`` — an mmap-backed .npy
 token shard with single-pass x/y batch assembly.  ``available()`` gates
 callers; everything falls back to the numpy path when the toolchain or the
@@ -12,9 +12,10 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
-import tempfile
 
 import numpy as np
+
+from mamba_distributed_tpu.utils.platform import CACHE_ROOT
 
 _SRC = os.path.join(os.path.dirname(__file__), "native", "shard_reader.cc")
 _lib = None
@@ -26,9 +27,10 @@ def _build_and_load():
     if _tried:
         return _lib
     _tried = True
+    # one fixed git-ignored directory in the checkout: a shared temp dir
+    # would reuse whatever newer .so another checkout left there
     cache_dir = os.environ.get(
-        "MAMBA_TPU_NATIVE_CACHE",
-        os.path.join(tempfile.gettempdir(), "mamba_tpu_native"),
+        "MAMBA_TPU_NATIVE_CACHE", os.path.join(CACHE_ROOT, "native")
     )
     os.makedirs(cache_dir, exist_ok=True)
     so_path = os.path.join(cache_dir, "shard_reader.so")

@@ -1,7 +1,7 @@
 """ctypes binding + lazy build for the native BPE merge loop.
 
 Same pattern as data/native.py (the shard reader): build
-``data/native/bpe_merge.cc`` once per machine into a cache dir, gate on
+``data/native/bpe_merge.cc`` once per checkout into a cache dir, gate on
 ``available()``, fall back to the pure-Python merge when the toolchain
 is missing or ``MDT_NATIVE_BPE=0``.
 """
@@ -11,7 +11,8 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
-import tempfile
+
+from mamba_distributed_tpu.utils.platform import CACHE_ROOT
 
 _SRC = os.path.join(os.path.dirname(__file__), "native", "bpe_merge.cc")
 _lib = None
@@ -25,9 +26,10 @@ def _build_and_load():
     _tried = True
     if os.environ.get("MDT_NATIVE_BPE") == "0":
         return None
+    # one fixed git-ignored directory in the checkout: a shared temp dir
+    # would reuse whatever newer .so another checkout left there
     cache_dir = os.environ.get(
-        "MAMBA_TPU_NATIVE_CACHE",
-        os.path.join(tempfile.gettempdir(), "mamba_tpu_native"),
+        "MAMBA_TPU_NATIVE_CACHE", os.path.join(CACHE_ROOT, "native")
     )
     os.makedirs(cache_dir, exist_ok=True)
     so_path = os.path.join(cache_dir, "bpe_merge.so")

@@ -26,7 +26,7 @@ targets over the finished-request stream.
 Everything here is strictly host-side: no device syncs, nothing traced
 by jit — enabling telemetry cannot change what XLA compiles (pinned by
 tests/test_obs.py trace-count tests).  docs/OBSERVABILITY.md has the
-schema and span taxonomy.
+schema and the span names.
 """
 
 from mamba_distributed_tpu.obs.context import mint_trace_id

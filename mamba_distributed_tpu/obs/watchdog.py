@@ -22,12 +22,8 @@ compile, with its wall duration) and keeps:
     record through the tracer (the ``slo_breach`` discipline — once
     per window, never a per-compile flood).
 
-Fallback: a jax build without the monitoring listener API degrades to
-polling the engine's Python-side ``TRACE_COUNTS`` deltas via
-``attach_trace_counts`` — compile counts stay right (one trace = one
-compile for the jit entry points those counters wrap), durations
-degrade to 0.  Strictly host-side either way: the listener runs on
-the thread that triggered the compile, after the compile.
+Strictly host-side: the listener runs on the thread that triggered
+the compile, after the compile.
 """
 
 from __future__ import annotations
@@ -35,11 +31,11 @@ from __future__ import annotations
 import threading
 import time
 
+import jax.monitoring
+
 from mamba_distributed_tpu.obs.tracer import NULL_TRACER
 
-# substring match: the event key moved across jax versions
-# ("/jax/backend_compile", "/jax/core/compile/backend_compile_duration")
-_COMPILE_EVENT = "backend_compile"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 class CompileWatchdog:
@@ -83,54 +79,29 @@ class CompileWatchdog:
         self._thrash_fired = False
         self.thrash_events = 0
         self._listener = None
-        self._trace_counts = None
-        self._trace_counts_seen = 0
 
     # ---------------------------------------------------------- install
 
-    def install(self) -> bool:
-        """Register the ``jax.monitoring`` duration listener.  Returns
-        False when the API is unavailable (use ``attach_trace_counts``
-        then).  Idempotent."""
+    def install(self) -> None:
+        """Register the ``jax.monitoring`` duration listener.
+        Idempotent."""
         if self._listener is not None:
-            return True
-        try:
-            import jax.monitoring as monitoring
-
-            register = monitoring.register_event_duration_secs_listener
-        except (ImportError, AttributeError):
-            return False
+            return
 
         def listener(event, duration, **kwargs):
-            if _COMPILE_EVENT in event:
+            if event == _COMPILE_EVENT:
                 self.on_compile(duration)
 
-        register(listener)
+        jax.monitoring.register_event_duration_secs_listener(listener)
         self._listener = listener
-        return True
 
     def uninstall(self) -> None:
-        """Best-effort deregistration (the public API has no remove;
-        tests install/uninstall repeatedly and must not stack
-        listeners)."""
+        """Deregister the listener (tests install/uninstall repeatedly
+        and must not stack listeners)."""
         if self._listener is None:
             return
-        try:
-            from jax._src import monitoring as priv
-
-            priv._unregister_event_duration_listener_by_callback(
-                self._listener
-            )
-        except Exception:
-            pass  # listener stays but self-filters nothing further
+        jax.monitoring.unregister_event_duration_listener(self._listener)
         self._listener = None
-
-    def attach_trace_counts(self, counts: dict) -> None:
-        """Fallback source: a dict of Python-side jit trace counters
-        (``serving/engine.TRACE_COUNTS``-shaped) polled at each drain —
-        new traces count as compiles with unknown (0) duration."""
-        self._trace_counts = counts
-        self._trace_counts_seen = sum(counts.values())
 
     # ------------------------------------------------------------- feed
 
@@ -170,13 +141,6 @@ class CompileWatchdog:
     def drain(self) -> tuple[int, float]:
         """(compiles, compile_ms) since the previous drain — what the
         engine stamps on this tick's record."""
-        if self._trace_counts is not None:
-            total = sum(self._trace_counts.values())
-            fresh = total - self._trace_counts_seen
-            if fresh > 0:
-                self._trace_counts_seen = total
-                for _ in range(fresh):
-                    self.on_compile(0.0)
         with self._lock:
             out = (self._win_compiles, round(self._win_ms, 3))
             self._win_compiles = 0

@@ -9,11 +9,6 @@ the selective-scan state lives in registers for the whole sequence — which
 is where the MFU headroom lives (SURVEY.md §7 stage 5).  Decode-side,
 ``ragged_paged_decode_attention`` walks the serving pool's paged KV per
 slot (models/attention.py).
-
-Every submodule takes ``CompilerParams`` from ``ops.pallas.common`` — a
-compat alias over jax's TPUCompilerParams/CompilerParams rename — so
-importing ANY kernel module works on either jax API, in any import order
-(a partially imported package can no longer shadow the rest).
 """
 
 from mamba_distributed_tpu.ops.pallas.attention_kernels import (

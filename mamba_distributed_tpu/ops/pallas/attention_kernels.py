@@ -40,10 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mamba_distributed_tpu.ops.pallas.common import (
-    CompilerParams,
-    resolve_interpret,
-)
+from mamba_distributed_tpu.ops.pallas.common import resolve_interpret
 
 _NEG_INF = float("-inf")
 
@@ -254,7 +251,7 @@ def _fa_fwd_impl(qt, kt, vt, offset, tk_valid, qb, kb, interpret):
             pltpu.VMEM((qb, 128), jnp.float32),
             pltpu.VMEM((qb, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -278,7 +275,7 @@ def _fa_bwd_dq_call(qt, kt, vt, do, lse, dlt, offset, tk_valid, qb, kb,
         (1, 1, kb, hd), lambda bi, hi, qi, kj: (bi, hi // rep, kj, 0)
     )
     lse_spec = pl.BlockSpec((1, 1, qb, 8), lambda bi, hi, qi, kj: (bi, hi, qi, 0))
-    seq_kv = CompilerParams(
+    seq_kv = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
     )
 
@@ -305,7 +302,7 @@ def _fa_bwd_dkv_call(qt, kt, vt, do, lse, dlt, offset, tk_valid, qb, kb,
     rep = nh // nkv
     nq, nk = tq // qb, tk // kb
     sm_scale = 1.0 / math.sqrt(hd)
-    seq_kv = CompilerParams(
+    seq_kv = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
     )
 
@@ -689,7 +686,7 @@ def ragged_paged_decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((S, nkv, R8, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -991,7 +988,7 @@ def ragged_paged_prefill_attention(
         # prefetch block) alias the page-pool outputs: the write is in
         # place under donation
         input_output_aliases={npre + 3: 1, npre + 4: 2},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -37,10 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mamba_distributed_tpu.ops.pallas.common import (
-    CompilerParams,
-    resolve_interpret,
-)
+from mamba_distributed_tpu.ops.pallas.common import resolve_interpret
 from mamba_distributed_tpu.ops.scan import _prep
 
 
@@ -152,7 +149,7 @@ def _m1_pallas_fwd(uf, df, Af, Bf, Cf, h0, interpret):
             jax.ShapeDtypeStruct((b, n, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, dblk), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -317,7 +314,7 @@ def _m1_pallas_bwd_impl(uf, df, Af, Bf, Cf, dy, interpret,
     io_spec = pl.BlockSpec((1, t_blk, dblk), lambda bi, di, ti: (bi, ti, di))
     bc_spec = pl.BlockSpec((1, t_blk, n), lambda bi, di, ti: (bi, ti, 0))
     A_spec = pl.BlockSpec((n, dblk), lambda bi, di, ti: (0, di))
-    seq_semantics = CompilerParams(
+    seq_semantics = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
     )
 

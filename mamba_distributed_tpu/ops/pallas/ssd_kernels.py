@@ -45,15 +45,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mamba_distributed_tpu.ops.pallas.common import (
-    CompilerParams,
-    resolve_interpret,
-)
+from mamba_distributed_tpu.ops.pallas.common import resolve_interpret
 from mamba_distributed_tpu.ops.scan import _divisor_chunk
 from mamba_distributed_tpu.ops.ssd import cumsum_mxu, state_passing
 
 # every grid cell is independent — let both megacore TensorCores split it
-_PARALLEL3 = CompilerParams(
+_PARALLEL3 = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel"),
 )
 
@@ -264,7 +261,7 @@ def _ssd_pallas_fwd_impl(
                   cell5((1, 1)), bc5, bc5, h_spec],
         out_specs=(cell5((l, p)), h_spec),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -506,7 +503,7 @@ def _ssd_pallas_bwd_impl(
         out_specs=(cell5r((l, p)), cell5r((l, 1)), cell5r((l, 1)),
                    cell5r((l, n)), cell5r((l, n)), cell5r((1, 1)), h_spec),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -4,8 +4,7 @@ Neither the reference nor any BASELINE configuration uses pipeline
 parallelism (SURVEY.md §2.3 lists it "out of scope"); it is part of the
 framework's full parallelism menu.  The trainer wires it in whenever the
 mesh has a ``pipe`` axis > 1 (training/train_step.py builds the train
-step around :func:`pipelined_layers`, composing with data parallelism;
-``__graft_entry__.dryrun_multichip`` exercises that path end-to-end).
+step around :func:`pipelined_layers`, composing with data parallelism).
 
 TPU-idiomatic formulation: the scan-over-layers parameter stack is
 sharded on its *layer* axis over a ``stage`` mesh axis, and a GPipe-style
@@ -30,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from mamba_distributed_tpu.parallel.compat import shard_map
 
 
 def _tree_where(pred, a, b):
@@ -138,7 +136,7 @@ def pipelined_layers(
         xs_specs = jax.tree.map(
             lambda x: P(None, batch_axes, *(None,) * (jnp.ndim(x) - 2)), xs
         )
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(param_specs, xs_specs),
@@ -301,7 +299,7 @@ def pipelined_decode_layers(
         lambda v: P(axis, *(None,) * (jnp.ndim(v) - 1)), stacked_state
     )
     act_specs = jax.tree.map(lambda x: P(*(None,) * jnp.ndim(x)), act)
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(param_specs, state_specs, act_specs),

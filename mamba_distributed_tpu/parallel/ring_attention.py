@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from mamba_distributed_tpu.parallel.compat import shard_map
 from mamba_distributed_tpu.ops.blockwise_attention import (
     DEFAULT_BLOCK,
     ols_block_update,
@@ -89,7 +88,7 @@ def ring_attention(seq_ctx, q, k, v, k_block: int = DEFAULT_BLOCK,
         acc = accumulate(acc, kv, n - 1)
         return ols_finalize(acc, q_l.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=ctx.mesh, in_specs=(bat4, bat4, bat4), out_specs=bat4,
         check_vma=False,
     )
@@ -265,7 +264,7 @@ def _ring_attention_pallas(seq_ctx, q, k, v):
         out = ring_core(qt0, kt0, vt0)
         return jnp.moveaxis(out, 1, 2)               # (b, tl, nh, hd)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=ctx.mesh, in_specs=(bat4, bat4, bat4), out_specs=bat4,
         check_vma=False,
     )

@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from mamba_distributed_tpu.parallel.compat import shard_map
 from mamba_distributed_tpu.ops.conv import causal_conv1d
 from mamba_distributed_tpu.ops.ssd import (
     chunk_local,
@@ -102,7 +101,7 @@ def sp_conv1d(
         return causal_conv1d(x_l, w, b, activation=activation, initial_state=halo)
 
     in_specs = (bat, P(None, None)) + ((P(None),) if has_bias else ())
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=ctx.mesh, in_specs=in_specs, out_specs=bat, check_vma=False
     )
     args = (x, weight) + ((bias,) if has_bias else ())
@@ -220,7 +219,7 @@ def sp_ssd(
     in_specs = (bat4, bat3, P(None), bat4, bat4)
     if has_D:
         in_specs += (P(None, None) if D.ndim == 2 else P(None),)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_pallas if ssm_impl == "pallas" else local,
         mesh=ctx.mesh, in_specs=in_specs, out_specs=bat4, check_vma=False,
     )
@@ -355,7 +354,7 @@ def sp_selective_scan(
     if has_bias:
         in_specs.append(P(None))
         args.append(delta_bias)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=ctx.mesh, in_specs=tuple(in_specs), out_specs=bat3,
         check_vma=False,
     )

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from mamba_distributed_tpu.parallel.compat import shard_map
 
 
 def ulysses_attention(seq_ctx, q, k, v, impl: str = "xla"):
@@ -76,7 +75,7 @@ def ulysses_attention(seq_ctx, q, k, v, impl: str = "xla"):
             out, ctx.axis, split_axis=1, concat_axis=2, tiled=True
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=ctx.mesh, in_specs=(bat4, bat4, bat4), out_specs=bat4,
         check_vma=False,
     )

@@ -55,6 +55,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import sys
 import time
 
 import jax
@@ -96,6 +97,7 @@ from mamba_distributed_tpu.serving.scheduler import (
     check_tenant_quota,
 )
 from mamba_distributed_tpu.utils.metrics import ServingMetrics
+from mamba_distributed_tpu.utils.platform import describe_devices
 
 # Python-side-effect trace counters (one bump per jit trace) — the
 # bucketing exists to bound these; tests/test_serving.py pins them (the
@@ -467,6 +469,9 @@ class ServingEngine:
                                 model_shards=cfg.serving_model_shards,
                                 stage_shards=cfg.serving_stage_shards)
         self.mesh = mesh
+        # stderr: the bench scripts keep stdout for their one JSON line
+        print(f"serving engine: {describe_devices(mesh)}", file=sys.stderr,
+              flush=True)
         self.num_shards = 1 if mesh is None else int(mesh.shape["data"])
         self.model_shards = (
             1 if mesh is None else int(dict(mesh.shape).get("model", 1))
@@ -566,13 +571,19 @@ class ServingEngine:
         # one-shot prefill at decode-length rates would systematically
         # understate serving_mfu in exactly that config
         prefill_seq = cfg.effective_prefill_chunk_tokens or 256
+        # the rates are counts; the peak is a fact about a TPU — off
+        # one there is none, and serving_mfu stays None
+        dev = jax.devices()[0] if mesh is None else mesh.devices.flat[0]
         self.metrics.configure_goodput(
             flops_per_decode_token=flops_per_token(
                 cfg, 1, training=False, convention="model"),
             flops_per_prefill_token=flops_per_token(
                 cfg, prefill_seq, training=False, convention="model"),
-            peak_flops=peak_flops_per_chip() * self.num_shards
-            * self.model_shards * self.stage_shards,
+            peak_flops=(
+                peak_flops_per_chip(dev) * self.num_shards
+                * self.model_shards * self.stage_shards
+                if dev.platform == "tpu" else None
+            ),
         )
         if self.stage_shards > 1:
             self.metrics.configure_pipeline(self.stage_shards)
@@ -1445,9 +1456,16 @@ class ServingEngine:
                 # full carry from the pool pages + the host-owned
                 # table row / length
                 state["attn_blocks"] = self.pool["state"]["attn_blocks"]
+                # copies, not views of the host mirrors: on the CPU
+                # backend jnp.asarray aliases a 64-byte-aligned host
+                # buffer instead of copying it, the chunk step below is
+                # only DISPATCHED here, and the length mirror advances
+                # right after — a still-queued step would read the
+                # advanced length (wrong tokens, depending on where
+                # numpy happened to allocate the mirror)
                 state["attn_meta"] = (
-                    jnp.asarray(self._page_tbl[slot : slot + 1]),
-                    jnp.asarray(self._kv_len[slot : slot + 1]),
+                    jnp.asarray(self._page_tbl[slot : slot + 1].copy()),
+                    jnp.asarray(self._kv_len[slot : slot + 1].copy()),
                 )
             i = tracked.chunks_done
             ids, mask = chunk_inputs(r.prompt_ids, plan, i)

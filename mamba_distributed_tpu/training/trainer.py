@@ -39,6 +39,7 @@ from mamba_distributed_tpu.training.optimizer import lr_schedule, make_optimizer
 from mamba_distributed_tpu.training.train_step import make_eval_step, make_train_step
 from mamba_distributed_tpu.utils.flops import flops_per_token, peak_flops_per_chip
 from mamba_distributed_tpu.utils.metrics import MetricsLogger
+from mamba_distributed_tpu.utils.platform import describe_devices
 
 
 class Trainer:
@@ -54,6 +55,8 @@ class Trainer:
         self.mesh = build_mesh(cfg.mesh, devices)
         self.master = jax.process_index() == 0
         self.verbose = verbose and self.master
+        if self.verbose:
+            print(f"trainer: {describe_devices(self.mesh)}")
 
         if cfg.mesh.seq > 1:
             from mamba_distributed_tpu.parallel.seq_parallel import SeqContext
@@ -162,7 +165,12 @@ class Trainer:
         self._flops_per_token_model = flops_per_token(
             cfg.model, cfg.seq_len, convention="model"
         )
-        self._peak = peak_flops_per_chip() * self.mesh.devices.size
+        # MFU is a statement about a TPU: off one, none is logged
+        dev = self.mesh.devices.flat[0]
+        self._peak = (
+            peak_flops_per_chip(dev) * self.mesh.devices.size
+            if dev.platform == "tpu" else None
+        )
 
     # ------------------------------------------------------------------
 
@@ -250,8 +258,10 @@ class Trainer:
             loss_f, grad_norm_f = float(loss), float(grad_norm)
             overflow = int(out[4]) if self._overflow_on else None
             tok_per_sec = tokens_per_step / dt
-            mfu = self._flops_per_token_model * tok_per_sec / self._peak
-            mfu_hw = self._flops_per_token * tok_per_sec / self._peak
+            mfu = mfu_hw = None
+            if self._peak is not None:
+                mfu = self._flops_per_token_model * tok_per_sec / self._peak
+                mfu_hw = self._flops_per_token * tok_per_sec / self._peak
             self.logger.train_step(
                 step, loss_f, float(self.schedule(step)), grad_norm_f,
                 dt, tok_per_sec, mfu, mfu_hw,

@@ -18,11 +18,10 @@ Two conventions (both reported by bench.py; docs/KERNELS.md):
 
 from __future__ import annotations
 
-import jax
-
 from mamba_distributed_tpu.config import ModelConfig
 
-# bf16 peak per chip. v5 lite == v5e.
+# bf16 peak FLOP/s per chip, keyed by a substring of ``device_kind``
+# (Google Cloud TPU documentation; "v5 lite" is how a v5e reports itself).
 _PEAK = {
     "v4": 275e12,
     "v5 lite": 197e12,
@@ -33,14 +32,18 @@ _PEAK = {
 }
 
 
-def peak_flops_per_chip(device=None) -> float:
-    if device is None:
-        device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
+def peak_flops_per_chip(device) -> float:
+    """Peak of ``device``; an unknown ``device_kind`` raises — an MFU
+    against some other chip's peak is not a number.  Callers ask only
+    when ``device.platform == "tpu"`` and log no MFU otherwise."""
+    kind = device.device_kind.lower()
     for key, val in _PEAK.items():
         if key in kind:
             return val
-    return 197e12  # conservative default
+    raise ValueError(
+        f"no peak FLOP/s on record for device_kind {device.device_kind!r}; "
+        f"known: {sorted(_PEAK)}"
+    )
 
 
 def _mamba2_layer_flops(

@@ -51,25 +51,30 @@ class MetricsLogger:
                 append_jsonl(self.jsonl_file, record)
 
     def train_step(self, step: int, loss: float, lr: float, grad_norm: float,
-                   dt_s: float, tokens_per_sec: float, mfu: float,
+                   dt_s: float, tokens_per_sec: float,
+                   mfu: float | None = None,
                    mfu_hw: float | None = None) -> None:
         """``mfu`` is the model-FLOPs convention (the judged one);
         ``mfu_hw`` additionally counts the chunked algorithm's extra
-        arithmetic (utils/flops.py module docstring)."""
+        arithmetic (utils/flops.py module docstring).  Both are None
+        off a TPU — there is no peak to divide by — and the line and
+        the record then carry no MFU at all."""
         if not self.master:
             return
+        mfu_txt = "" if mfu is None else f" | mfu: {mfu * 100:.1f}%"
         print(
             f"step {step:5d} | loss: {loss:.6f} | lr {lr:.4e} | "
             f"norm: {grad_norm:.4f} | dt: {dt_s * 1000:.2f}ms | "
-            f"tok/sec: {tokens_per_sec:.2f} | mfu: {mfu * 100:.1f}%"
+            f"tok/sec: {tokens_per_sec:.2f}{mfu_txt}"
         )
         record = {
             "step": step, "kind": "train", "loss": round(loss, 6),
             "lr": lr, "grad_norm": round(grad_norm, 4),
             "step_ms": round(dt_s * 1000, 2),
             "tokens_per_sec": round(tokens_per_sec, 1),
-            "mfu": round(mfu, 4),
         }
+        if mfu is not None:
+            record["mfu"] = round(mfu, 4)
         if mfu_hw is not None:
             record["mfu_hw"] = round(mfu_hw, 4)
         self._append(f"{step} train {loss:.6f}", record)
@@ -334,12 +339,13 @@ class ServingMetrics:
 
     def configure_goodput(self, flops_per_decode_token: float,
                           flops_per_prefill_token: float,
-                          peak_flops: float) -> None:
+                          peak_flops: float | None) -> None:
         """Install the analytic FLOPs rates (utils/flops.py, "model"
         convention — no device counters involved) that turn each tick's
         useful-token counts into a host-computed ``serving_mfu``.  The
-        engine calls this once at construction; unconfigured metrics
-        still emit the goodput token fields with ``serving_mfu=None``."""
+        engine calls this once at construction; unconfigured metrics —
+        and engines off a TPU, which pass ``peak_flops=None`` — still
+        emit the goodput token fields with ``serving_mfu=None``."""
         self._fpt_decode = flops_per_decode_token
         self._fpt_prefill = flops_per_prefill_token
         self._peak_flops = peak_flops

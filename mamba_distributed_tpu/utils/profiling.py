@@ -31,11 +31,8 @@ def trace(log_dir: str):
 
 
 class StepTimer:
-    """Wall-clock timing with an explicit sync on a device scalar.
-
-    ``block_until_ready`` is a no-op on some experimental platforms, so
-    syncing is done by fetching the scalar's value.
-    """
+    """Wall-clock timing with an explicit sync on a device scalar
+    (fetching its value waits for the work that produced it)."""
 
     def __init__(self):
         self._t0 = None

@@ -7,7 +7,7 @@ elementwise fusions, copies/reshapes/pads, scan stacking, reduce-window),
 plus the top-N individual fusions — the actionable view that drove the
 round-4 MXU-ification.
 
-  python scripts/analyze_trace.py /tmp/battery_r4/profile [--steps 5] [--top 30]
+  python scripts/analyze_trace.py chiprun_out/profile [--steps 5] [--top 30]
 
 The trace file is found recursively (plugins/profile/*/.trace.json.gz).
 """

@@ -9,7 +9,7 @@ O(1); this script measures that as sampled tokens/sec/chip.
 Prints one JSON line; ``--json PATH`` also writes it to PATH (the
 machine-readable bench artifact BENCH_SERVING.json collects).  Env
 knobs: DECODE_B (default 8), DECODE_PROMPT (default 128), DECODE_NEW
-(default 256), BENCH_PRESET, BENCH_PLATFORM.  ``--model-shards N``
+(default 256), BENCH_PRESET.  ``--model-shards N``
 decodes with the weights tensor-parallel over a 2-D serving mesh's
 model axis (``generate(mesh=)``; docs/SERVING.md "2-D serving mesh").
 
@@ -258,12 +258,12 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
 
-    if os.environ.get("BENCH_PLATFORM"):
-        jax.config.update("jax_platforms", os.environ["BENCH_PLATFORM"])
+    from mamba_distributed_tpu.utils.platform import configure_compile_cache
 
+    configure_compile_cache()
     _progress("initializing backend...")
     dev = jax.devices()[0]
-    _progress(f"backend up: {dev.device_kind or dev.platform}")
+    _progress(f"backend up: {dev.device_kind}")
 
     if args.hybrid_paged:
         emit_bench_record(_hybrid_paged_bench(args), args.json)

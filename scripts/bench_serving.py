@@ -14,7 +14,7 @@ the signature count at O(log max_prompt_len) for both.
 Prints one JSON line.  Env knobs: BENCH_PRESET (default mamba2-tiny — a
 CPU-minutes model; set mamba2-280m on real chips), SERVE_REQUESTS (16),
 SERVE_CAPACITY (8), SERVE_PROMPT_MIN/MAX (8/96), SERVE_MAX_NEW (32),
-SERVE_TOKENS_PER_TICK (8), BENCH_PLATFORM, BENCH_SEED (0).
+SERVE_TOKENS_PER_TICK (8), BENCH_SEED (0).
 
 ``--jsonl PATH`` streams the timed engine run's per-tick and per-request
 telemetry records (kind serving_tick / request) to PATH — the stream
@@ -1039,12 +1039,21 @@ def main() -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    if os.environ.get("BENCH_PLATFORM"):
-        jax.config.update("jax_platforms", os.environ["BENCH_PLATFORM"])
+    from mamba_distributed_tpu.utils.platform import configure_compile_cache
 
+    configure_compile_cache()
     _progress("initializing backend...")
     dev = jax.devices()[0]
-    _progress(f"backend up: {dev.device_kind or dev.platform}")
+    _progress(f"backend up: {dev.device_kind}")
+    if args.service and dev.platform != "cpu":
+        # this process now holds the chip for the in-process baseline,
+        # and the worker subprocesses the mode spawns need the same chip
+        # (a chip belongs to one process): they would never come up
+        raise SystemExit(
+            f"--service compares an in-process fabric with worker "
+            f"subprocesses, and on {dev.platform} both need the chip "
+            f"this process already holds; run it with JAX_PLATFORMS=cpu"
+        )
 
     from mamba_distributed_tpu.config import get_preset
     from mamba_distributed_tpu.inference import generate

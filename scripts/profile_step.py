@@ -8,7 +8,7 @@ attributing step time when chasing the >=45% MFU north star.
   PROFILE_DIR=/tmp/tr BENCH_B=16 python scripts/profile_step.py
 
 Env knobs: PROFILE_DIR (default ./profile), PROFILE_STEPS (default 5),
-plus bench.py's BENCH_PRESET/B/T/SSM_IMPL/REMAT/REMAT_POLICY/PLATFORM.
+plus bench.py's BENCH_PRESET/B/T/SSM_IMPL/REMAT/REMAT_POLICY.
 The step setup is bench.build_step — exactly what bench.py times.
 """
 

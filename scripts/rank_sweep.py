@@ -1,11 +1,11 @@
 """Rank sweep_bench results and recommend shipping defaults.
 
-  python scripts/rank_sweep.py /tmp/battery_r5/sweep_results.jsonl
+  python scripts/rank_sweep.py chiprun_out/sweep_results.jsonl
 
 Reads the JSONL a sweep run printed (one object per row, errors
 included), groups rows by preset, ranks by tok_per_sec, and prints the
 deltas vs each preset's first (baseline-config) row — the table that
-drives the "flip the preset defaults" decision after a claim window.
+drives the "flip the preset defaults" decision after a sweep.
 """
 
 from __future__ import annotations
@@ -29,7 +29,7 @@ def main(path: str) -> int:
             try:
                 r = json.loads(line)
             except json.JSONDecodeError:
-                # a claim dropped mid-sweep leaves a partial trailing line;
+                # a sweep killed mid-row leaves a partial trailing line;
                 # rank what completed (the matrix is value-ordered)
                 truncated += 1
                 continue
@@ -69,11 +69,11 @@ def main(path: str) -> int:
             spec = {k: v for k, v in r.items() if k != "error"}
             print(f"  {spec}\n    {r['error'][:160]}")
     if truncated:
-        print(f"== {truncated} unparseable line(s) skipped (claim dropped "
-              "mid-sweep?)")
+        print(f"== {truncated} unparseable line(s) skipped (sweep killed "
+              "mid-row?)")
     return 0 if rows else 1
 
 
 if __name__ == "__main__":
     raise SystemExit(main(sys.argv[1] if len(sys.argv) > 1 else
-                          "/tmp/battery_r5/sweep_results.jsonl"))
+                          "chiprun_out/sweep_results.jsonl"))

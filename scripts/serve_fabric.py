@@ -206,6 +206,19 @@ def main() -> int:
     from mamba_distributed_tpu.serving.service.worker import config_from_json
 
     cfg = config_from_json(args.config)
+    autoscale_max = (args.autoscale_max if args.autoscale_max is not None
+                     else cfg.autoscale_max_replicas)
+    most_workers = max(args.spawn or 0, autoscale_max if args.spawn else 0)
+    if most_workers > 1 and os.environ.get("JAX_PLATFORMS") != "cpu":
+        # every worker process takes every local chip and nothing
+        # assigns one to each, so a second worker on an accelerator
+        # host can never come up.  This process stays off JAX and
+        # cannot ask which backend the workers will get; the variable
+        # is the one thing it can see.
+        ap.error("more than one spawned worker needs JAX_PLATFORMS=cpu "
+                 "(the loopback CI fabric): on an accelerator host one "
+                 "worker process owns every local chip — use --spawn 1, "
+                 "or start one worker per host and pass --workers")
     procs: list[subprocess.Popen] = []
     if args.spawn:
         n = args.spawn
@@ -283,8 +296,6 @@ def main() -> int:
     # workers exactly like the seed ones (same config/capacity/flags)
     # through a ProcessProvisioner; scale-downs drain + shut down.
     # Spawn mode only — externally-started workers are the operator's.
-    autoscale_max = (args.autoscale_max if args.autoscale_max is not None
-                     else cfg.autoscale_max_replicas)
     autoscale = None
     if autoscale_max:
         if not args.spawn:

@@ -131,8 +131,7 @@ def main() -> int:
                          "the front end is down")
     ap.add_argument("--compile-watchdog", action="store_true",
                     help="count/time every XLA backend compile "
-                         "(jax.monitoring; falls back to polling the "
-                         "engine's trace counters), stamping compiles/"
+                         "(jax.monitoring), stamping compiles/"
                          "compile_ms on tick records and /metrics")
     ap.add_argument("--compile-thrash-threshold", type=int, default=0,
                     metavar="N",
@@ -157,6 +156,10 @@ def main() -> int:
     args = ap.parse_args()
 
     import jax
+
+    from mamba_distributed_tpu.utils.platform import configure_compile_cache
+
+    configure_compile_cache()
 
     from mamba_distributed_tpu.config import get_preset
     from mamba_distributed_tpu.models import init_lm_params
@@ -191,18 +194,13 @@ def main() -> int:
     engine_kw = {}
     if args.compile_watchdog:
         from mamba_distributed_tpu.obs import CompileWatchdog
-        from mamba_distributed_tpu.serving import engine as engine_mod
 
         watchdog = CompileWatchdog(
             thrash_threshold=args.compile_thrash_threshold,
             thrash_window_s=args.compile_thrash_window_s,
             tracer=tracer,
         )
-        if not watchdog.install():
-            # no jax.monitoring on this build: poll the shared jit
-            # entry points' trace counters instead (coarser — no
-            # durations, but the thrash sentinel still works)
-            watchdog.attach_trace_counts(engine_mod.TRACE_COUNTS)
+        watchdog.install()
         engine_kw["compile_watchdog"] = watchdog
     if args.tick_regression_factor:
         from mamba_distributed_tpu.obs import TickRegressionDetector

@@ -1,6 +1,6 @@
 """Sweep train-step configurations on the local chip in one process.
 
-One device claim, many configs: reuses bench.time_config (the exact
+One process, many configs: reuses bench.time_config (the exact
 protocol bench.py reports) across ssm_impl / remat / batch-size
 combinations and prints one JSON line per configuration, plus a final
 {"best": ...} line. Used to pick the defaults bench.py ships with.
@@ -21,7 +21,7 @@ from bench import init_backend, time_config  # noqa: E402
 
 # Round-5 question set. Each row answers a named question from
 # VERDICT r4 ("next round" items 1-3); rows are ordered so the
-# highest-value answers land first if the claim drops mid-sweep.
+# highest-value answers land first if the run is cut short.
 DEFAULT_CONFIGS = [
     # -- MFU ranking: chunk size re-rank post-cumsum_mxu (r4 measured
     #    chunk 512 +7% BEFORE the MXU-ification; re-rank together now)

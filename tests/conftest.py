@@ -21,6 +21,11 @@ import jax  # noqa: E402
 # actually initialized, which no plugin does.
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_default_matmul_precision", "highest")
+# entry points called in-process (train.main(), generate.main(), ...) place
+# the persistent compile cache; keep it switched off in THIS process so a
+# warm cache cannot change what later tests compile and count.  CLI tests
+# run subprocesses, which do use the cache.
+jax.config.update("jax_enable_compilation_cache", False)
 
 import pytest  # noqa: E402
 
@@ -45,6 +50,31 @@ def pytest_collection_modifyitems(config, items):
 @pytest.fixture
 def rng():
     return jax.random.PRNGKey(0)
+
+
+def _mapped_regions() -> int:
+    with open("/proc/self/maps") as f:
+        return sum(1 for _ in f)
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _stay_under_max_map_count():
+    """Every XLA:CPU executable keeps several memory mappings, and one
+    process runs the whole suite: some 470 tests in it reaches the
+    kernel's ``vm.max_map_count`` (65,530) and the next compile
+    segfaults inside ``backend_compile_and_load``.  Dropping the compiled
+    programs gives the mappings back; it is done between modules, and
+    only once the count is past half the limit, so nothing recompiles
+    that did not have to."""
+    yield
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            limit = int(f.read())
+        near = _mapped_regions() > limit // 2
+    except OSError:  # not Linux: no such limit to watch
+        return
+    if near:
+        jax.clear_caches()
 
 
 def make_toy_bpe(dirpath, merges=()):

@@ -647,7 +647,8 @@ def test_engine_stamps_traces_and_goodput(tmp_path):
         # lanes computed = capacity * tokens_per_tick (+ chunk lanes)
         assert t["useful_tokens"] + t["wasted_token_lanes"] >= 2 * 2
         assert t["goodput_tokens_per_sec"] is not None
-        assert "serving_mfu" in t and t["serving_mfu"] >= 0
+        # MFU is a statement about a TPU; on the CPU the field is None
+        assert "serving_mfu" in t and t["serving_mfu"] is None
         seen_live.update(t["traces"])
     assert seen_live == traces  # every request decoded under its trace
     total_emitted = sum(t["tokens_emitted"] for t in ticks)
@@ -664,7 +665,7 @@ def test_engine_stamps_traces_and_goodput(tmp_path):
     g = metrics.summary()["goodput"]
     assert g["useful_tokens"] == 12 + 15
     assert g["goodput_tokens_per_sec"] > 0
-    assert g["serving_mfu"] is not None and g["serving_mfu"] >= 0
+    assert g["serving_mfu"] is None
     assert g["useful_fraction"] is not None and 0 < g["useful_fraction"] <= 1
 
 

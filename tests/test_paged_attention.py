@@ -9,7 +9,6 @@ re-verified in isolation after kernel work.
 """
 
 import jax
-import jax.export  # attribute access alone fails on 0.4.37's lazy module
 import jax.numpy as jnp
 import numpy as np
 import pytest

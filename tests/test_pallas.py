@@ -2,7 +2,6 @@
 same kernels compile for real on TPU)."""
 
 import jax
-import jax.export  # attribute access alone fails on 0.4.37's lazy module
 import jax.numpy as jnp
 import numpy as np
 import pytest

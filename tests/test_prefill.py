@@ -373,6 +373,52 @@ def test_failed_chunk_requeues_and_frees_slot(monkeypatch):
     assert len(eng.results[rid].new_tokens) == 4  # served after recovery
 
 
+def test_chunk_step_gets_copies_of_the_host_kv_mirrors(monkeypatch):
+    """The hybrid chunk step is dispatched asynchronously (and donates
+    its state) while the host's KV-length mirror advances right after.
+    On the CPU backend ``jnp.asarray`` ALIASES a 64-byte-aligned host
+    buffer, so a view of the mirror handed to the step shares memory
+    with it: a queued step reads the advanced length — wrong tokens
+    whenever numpy happens to allocate the mirror aligned.  Pin, with
+    the mirrors forced aligned, that what the step gets is no alias."""
+    from mamba_distributed_tpu.serving import engine as engine_mod
+
+    def aligned_like(arr):
+        raw = np.zeros(arr.nbytes + 128, np.uint8)
+        start = (-raw.ctypes.data) % 64
+        out = raw[start:start + arr.nbytes].view(arr.dtype)
+        return out.reshape(arr.shape)
+
+    cfg = tiny_cfg(attn_layer_idx=(1,), attn_num_heads=4,
+                   attn_num_kv_heads=2, remat=False, kv_page_tokens=8,
+                   kv_slot_tokens=64)
+    params = init_lm_params(jax.random.PRNGKey(0), cfg)
+    eng = ServingEngine(params, cfg, capacity=2, tokens_per_tick=2)
+    eng._kv_len = aligned_like(eng._kv_len)
+    eng._page_tbl = aligned_like(eng._page_tbl)
+
+    aliased = []
+    real = engine_mod.prefill_chunk
+
+    def spy(params, ids, mask, state, **kw):
+        tbl, lens = state["attn_meta"]
+        aliased.append((
+            tbl.unsafe_buffer_pointer() == eng._page_tbl.ctypes.data,
+            lens.unsafe_buffer_pointer() == eng._kv_len.ctypes.data,
+        ))
+        return real(params, ids, mask, state, **kw)
+
+    monkeypatch.setattr(engine_mod, "prefill_chunk", spy)
+    prompt, key = rand_prompt(40), jax.random.PRNGKey(3)
+    rid = eng.submit(GenerationRequest(prompt_ids=prompt, max_new_tokens=4,
+                                       key=key))  # lands in slot 0
+    while eng.pending:
+        eng.step()
+    assert aliased == [(False, False)] * 3  # 48 padded tokens, 3 chunks
+    assert eng.results[rid].new_tokens.tolist() == solo(
+        params, cfg, prompt, key, max_new_tokens=4)
+
+
 # ------------------------------------------------------------- satellites
 
 

@@ -379,7 +379,6 @@ def test_int8_kernels_tpu_lowering():
     toy size, because the scale arrays ride the SMEM scalar-prefetch
     channel and its capacity is the scaling ceiling (ROADMAP
     quantization residuals)."""
-    import jax.export  # attribute access alone fails on 0.4.37
 
     from mamba_distributed_tpu.ops.pallas.attention_kernels import (
         ragged_paged_decode_attention,

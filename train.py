@@ -168,9 +168,9 @@ def build_config(args):
 
 def main():
     args = parse_args()
-    from mamba_distributed_tpu.utils.platform import honor_jax_platforms_env
+    from mamba_distributed_tpu.utils.platform import configure_compile_cache
 
-    honor_jax_platforms_env()
+    configure_compile_cache()
     if args.multihost:
         jax.distributed.initialize()
     cfg = build_config(args)
