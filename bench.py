@@ -62,9 +62,8 @@ def _metric_name(preset: str) -> str:
 def init_backend():
     """Place the compile cache, initialize the backend and insist on a TPU.
 
-    Shared by bench.py, scripts/profile_step.py, scripts/sweep_bench.py
-    and scripts/tpu_smoke.py so the measured and profiled backends can
-    never diverge.  These are chip tools: on any other platform they
+    Shared by bench.py, scripts/sweep_bench.py and scripts/tpu_smoke.py
+    so their backends can never diverge.  These are chip tools: on any other platform they
     exit non-zero before printing anything on stdout.
     """
     import jax
@@ -85,9 +84,8 @@ def init_backend():
 def build_step(spec: dict):
     """Build the single-chip jitted train step for one configuration.
 
-    Shared by time_config and scripts/profile_step.py so the measured and
-    profiled setup can never diverge.  Returns (cfg, step, params,
-    opt_state, x, y) with x/y carrying the (1, B, T) accum axis.
+    Returns (cfg, step, params, opt_state, x, y) with x/y carrying the
+    (1, B, T) accum axis.
     """
     import jax
     import jax.numpy as jnp

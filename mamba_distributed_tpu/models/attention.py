@@ -25,6 +25,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from mamba_distributed_tpu.config import ModelConfig
 from mamba_distributed_tpu.models.common import init_linear, linear
+from mamba_distributed_tpu.obs import scopes
 
 
 def _attn_dims(cfg: ModelConfig):
@@ -135,48 +136,54 @@ def attention_mixer(
     b, t, _ = u.shape
     compute_dtype = jnp.dtype(cfg.compute_dtype)
 
-    qkv = linear(params["wqkv"], u, compute_dtype)
-    q, k, v = _split_qkv(qkv, cfg)
-    if rot > 0:
-        angles = rope_angles(jnp.arange(t), rot, cfg.rope_theta)
-        q = apply_rope(q, angles)
-        k = apply_rope(k, angles)
+    with jax.named_scope(scopes.ATTN_QKV):
+        qkv = linear(params["wqkv"], u, compute_dtype)
+        q, k, v = _split_qkv(qkv, cfg)
+        if rot > 0:
+            angles = rope_angles(jnp.arange(t), rot, cfg.rope_theta)
+            q = apply_rope(q, angles)
+            k = apply_rope(k, angles)
 
     from mamba_distributed_tpu.ops.pallas.common import resolve_attn_impl
 
     attn_impl = resolve_attn_impl(cfg.attn_impl)
-    if seq_ctx is not None:
-        if cfg.attn_sp_impl == "ulysses":
-            from mamba_distributed_tpu.parallel.ulysses import (
-                ulysses_attention,
+    with jax.named_scope(scopes.ATTN_KERNEL):
+        if seq_ctx is not None:
+            if cfg.attn_sp_impl == "ulysses":
+                from mamba_distributed_tpu.parallel.ulysses import (
+                    ulysses_attention,
+                )
+
+                out = ulysses_attention(seq_ctx, q, k, v, impl=attn_impl)
+            else:
+                from mamba_distributed_tpu.parallel.ring_attention import (
+                    ring_attention,
+                )
+
+                out = ring_attention(seq_ctx, q, k, v, impl=attn_impl)
+        elif attn_impl == "pallas":
+            from mamba_distributed_tpu.ops.pallas.attention_kernels import (
+                flash_sdpa_causal,
             )
 
-            out = ulysses_attention(seq_ctx, q, k, v, impl=attn_impl)
+            # flash kernel: online softmax in VMEM, fully-future blocks
+            # skipped
+            out = flash_sdpa_causal(q, k, v)
         else:
-            from mamba_distributed_tpu.parallel.ring_attention import (
-                ring_attention,
+            from mamba_distributed_tpu.ops.blockwise_attention import (
+                blockwise_sdpa_causal,
             )
 
-            out = ring_attention(seq_ctx, q, k, v, impl=attn_impl)
-    elif attn_impl == "pallas":
-        from mamba_distributed_tpu.ops.pallas.attention_kernels import (
-            flash_sdpa_causal,
-        )
-
-        # flash kernel: online softmax in VMEM, fully-future blocks skipped
-        out = flash_sdpa_causal(q, k, v)
-    else:
-        from mamba_distributed_tpu.ops.blockwise_attention import (
-            blockwise_sdpa_causal,
-        )
-
-        # O(t*block) memory — never materializes the (t, t) score tensor
-        # (config 5 at T=8192); the tiny-t paged decode path keeps the
-        # explicit-mask _sdpa_positions
-        out = blockwise_sdpa_causal(q, k, v)
+            # O(t*block) memory — never materializes the (t, t) score
+            # tensor (config 5 at T=8192); the tiny-t paged decode path
+            # keeps the explicit-mask _sdpa_positions
+            out = blockwise_sdpa_causal(q, k, v)
     # remat_policy="mixer" save point (models/lm.py:_remat)
     out = checkpoint_name(out, "mixer_out")
-    y = linear(params["out_proj"], out.reshape(b, t, nh * hd), compute_dtype)
+    with jax.named_scope(scopes.ATTN_OUT):
+        y = linear(
+            params["out_proj"], out.reshape(b, t, nh * hd), compute_dtype
+        )
     if return_final_state:
         return y, (k, v)
     return y
@@ -434,12 +441,13 @@ def attention_mixer_step(params: dict, cfg: ModelConfig, u_t: jax.Array,
     pg = cfg.kv_page_tokens
     W = page_table.shape[1]
 
-    qkv = linear(params["wqkv"], u_t[:, None, :], compute_dtype)
-    q, k, v = _split_qkv(qkv, cfg)
-    if rot > 0:
-        angles = rope_angles(lengths[:, None], rot, cfg.rope_theta)
-        q = apply_rope(q, angles)
-        k = apply_rope(k, angles)
+    with jax.named_scope(scopes.ATTN_QKV):
+        qkv = linear(params["wqkv"], u_t[:, None, :], compute_dtype)
+        q, k, v = _split_qkv(qkv, cfg)
+        if rot > 0:
+            angles = rope_angles(lengths[:, None], rot, cfg.rope_theta)
+            q = apply_rope(q, angles)
+            k = apply_rope(k, angles)
 
     mask = (
         jnp.ones((b,), bool) if write_mask is None else write_mask
@@ -476,42 +484,51 @@ def attention_mixer_step(params: dict, cfg: ModelConfig, u_t: jax.Array,
             return (pages.at[phys].set(page.astype(pages.dtype)),
                     scales.at[phys].set(new_s))
 
-        k_pages, k_scale = qwrite(k_pages, k_scale, k[:, 0])
-        v_pages, v_scale = qwrite(v_pages, v_scale, v[:, 0])
+        with jax.named_scope(scopes.KV_WRITE):
+            k_pages, k_scale = qwrite(k_pages, k_scale, k[:, 0])
+            v_pages, v_scale = qwrite(v_pages, v_scale, v[:, 0])
     else:
         # head-major pages: the token offset sits one axis past the
         # heads, so the (b,) phys/off pair scatters a (b, nkv, hd) row
         # block per write
-        k_pages = k_pages.at[phys, :, off].set(k[:, 0].astype(k_pages.dtype))
-        v_pages = v_pages.at[phys, :, off].set(v[:, 0].astype(v_pages.dtype))
+        with jax.named_scope(scopes.KV_WRITE):
+            k_pages = k_pages.at[phys, :, off].set(
+                k[:, 0].astype(k_pages.dtype))
+            v_pages = v_pages.at[phys, :, off].set(
+                v[:, 0].astype(v_pages.dtype))
 
     from mamba_distributed_tpu.ops.pallas.common import resolve_attn_impl
 
     qpos = jnp.minimum(lengths, W * pg - 1)
-    if resolve_attn_impl(cfg.attn_impl) == "pallas":
-        from mamba_distributed_tpu.ops.pallas.attention_kernels import (
-            ragged_paged_decode_attention,
-        )
+    with jax.named_scope(scopes.ATTN_KERNEL):
+        if resolve_attn_impl(cfg.attn_impl) == "pallas":
+            from mamba_distributed_tpu.ops.pallas.attention_kernels import (
+                ragged_paged_decode_attention,
+            )
 
-        # kv_len = tokens readable AFTER the write; the kernel skips
-        # whole pages past it, so decode cost tracks live tokens (int8
-        # pools: dequant fused into the page walk via the prefetched
-        # scales)
-        out = ragged_paged_decode_attention(
-            q[:, 0], k_pages, v_pages, page_table,
-            jnp.minimum(qpos + 1, W * pg),
-            k_scale=k_scale, v_scale=v_scale,
-        )[:, None]
-    else:
-        # tokens readable after the write = qpos + 1 per row: gather
-        # only the pages that hold them (the rest go to trash — masked
-        # anyway), so decode cost tracks live tokens off-TPU too
-        kk, vv = gather_kv_pages(
-            k_pages, v_pages, page_table, (qpos + pg) // pg,
-            k_scale=k_scale, v_scale=v_scale, dtype=compute_dtype,
+            # kv_len = tokens readable AFTER the write; the kernel skips
+            # whole pages past it, so decode cost tracks live tokens
+            # (int8 pools: dequant fused into the page walk via the
+            # prefetched scales)
+            out = ragged_paged_decode_attention(
+                q[:, 0], k_pages, v_pages, page_table,
+                jnp.minimum(qpos + 1, W * pg),
+                k_scale=k_scale, v_scale=v_scale,
+            )[:, None]
+        else:
+            # tokens readable after the write = qpos + 1 per row: gather
+            # only the pages that hold them (the rest go to trash —
+            # masked anyway), so decode cost tracks live tokens off-TPU
+            # too
+            kk, vv = gather_kv_pages(
+                k_pages, v_pages, page_table, (qpos + pg) // pg,
+                k_scale=k_scale, v_scale=v_scale, dtype=compute_dtype,
+            )
+            out = _sdpa_positions(q, kk, vv, qpos[:, None])
+    with jax.named_scope(scopes.ATTN_OUT):
+        y = linear(
+            params["out_proj"], out.reshape(b, nh * hd), compute_dtype
         )
-        out = _sdpa_positions(q, kk, vv, qpos[:, None])
-    y = linear(params["out_proj"], out.reshape(b, nh * hd), compute_dtype)
     if quant:
         return y, (k_pages, v_pages, k_scale, v_scale)
     return y, (k_pages, v_pages)
@@ -610,19 +627,20 @@ def attention_mixer_chunk(params: dict, cfg: ModelConfig, u: jax.Array,
     pg = cfg.kv_page_tokens
     W = page_table.shape[1]
 
-    qkv = linear(params["wqkv"], u, compute_dtype)
-    q, k, v = _split_qkv(qkv, cfg)
-    if token_mask is None:
-        real = jnp.ones((b, c), bool)
-    else:
-        real = token_mask > 0.5
-    pad = c - jnp.sum(real.astype(jnp.int32), axis=1)          # (b,)
-    pos = lengths[:, None] + jnp.arange(c)[None, :] - pad[:, None]
-    posc = jnp.maximum(pos, 0)                                  # (b, c)
-    if rot > 0:
-        angles = rope_angles(posc, rot, cfg.rope_theta)
-        q = apply_rope(q, angles)
-        k = apply_rope(k, angles)
+    with jax.named_scope(scopes.ATTN_QKV):
+        qkv = linear(params["wqkv"], u, compute_dtype)
+        q, k, v = _split_qkv(qkv, cfg)
+        if token_mask is None:
+            real = jnp.ones((b, c), bool)
+        else:
+            real = token_mask > 0.5
+        pad = c - jnp.sum(real.astype(jnp.int32), axis=1)          # (b,)
+        pos = lengths[:, None] + jnp.arange(c)[None, :] - pad[:, None]
+        posc = jnp.maximum(pos, 0)                                  # (b, c)
+        if rot > 0:
+            angles = rope_angles(posc, rot, cfg.rope_theta)
+            q = apply_rope(q, angles)
+            k = apply_rope(k, angles)
 
     if quant:
         ks_new, vs_new, takes = _chunk_page_scales(
@@ -636,13 +654,15 @@ def attention_mixer_chunk(params: dict, cfg: ModelConfig, u: jax.Array,
             ragged_paged_prefill_attention,
         )
 
-        out, k_pages, v_pages = ragged_paged_prefill_attention(
-            q, k, v, k_pages, v_pages, page_table, lengths, c - pad,
-            **({} if not quant else dict(
-                k_scale_old=k_scale, v_scale_old=v_scale,
-                k_scale_new=ks_new, v_scale_new=vs_new,
-            )),
-        )
+        # the kernel writes the chunk's K/V into the pages itself
+        with jax.named_scope(scopes.ATTN_KERNEL):
+            out, k_pages, v_pages = ragged_paged_prefill_attention(
+                q, k, v, k_pages, v_pages, page_table, lengths, c - pad,
+                **({} if not quant else dict(
+                    k_scale_old=k_scale, v_scale_old=v_scale,
+                    k_scale_new=ks_new, v_scale_new=vs_new,
+                )),
+            )
         if quant:
             k_scale, v_scale = ks_new, vs_new
     elif quant:
@@ -687,16 +707,19 @@ def attention_mixer_chunk(params: dict, cfg: ModelConfig, u: jax.Array,
             )
             return pages.at[dst].set(merged.astype(pages.dtype))
 
-        k_pages = merge(k_pages, k_scale, ks_new, k)
-        v_pages = merge(v_pages, v_scale, vs_new, v)
+        with jax.named_scope(scopes.KV_WRITE):
+            k_pages = merge(k_pages, k_scale, ks_new, k)
+            v_pages = merge(v_pages, v_scale, vs_new, v)
         k_scale, v_scale = ks_new, vs_new
         tokens = jnp.minimum(lengths + (c - pad), W * pg)
-        kk, vv = gather_kv_pages(
-            k_pages, v_pages, page_table,
-            jnp.maximum((tokens + pg - 1) // pg, 1),
-            k_scale=k_scale, v_scale=v_scale, dtype=compute_dtype,
-        )
-        out = _sdpa_positions(q, kk, vv, jnp.minimum(posc, W * pg - 1))
+        with jax.named_scope(scopes.ATTN_KERNEL):
+            kk, vv = gather_kv_pages(
+                k_pages, v_pages, page_table,
+                jnp.maximum((tokens + pg - 1) // pg, 1),
+                k_scale=k_scale, v_scale=v_scale, dtype=compute_dtype,
+            )
+            out = _sdpa_positions(
+                q, kk, vv, jnp.minimum(posc, W * pg - 1))
     else:
         pidx = jnp.clip(posc // pg, 0, W - 1)
         phys = jnp.where(
@@ -705,20 +728,26 @@ def attention_mixer_chunk(params: dict, cfg: ModelConfig, u: jax.Array,
         off = jnp.where(real, posc % pg, 0)
         # head-major pages: the (b, c) phys/off pair scatters
         # (b, c, nkv, hd) blocks one axis past the heads
-        k_pages = k_pages.at[phys, :, off].set(k.astype(k_pages.dtype))
-        v_pages = v_pages.at[phys, :, off].set(v.astype(v_pages.dtype))
+        with jax.named_scope(scopes.KV_WRITE):
+            k_pages = k_pages.at[phys, :, off].set(k.astype(k_pages.dtype))
+            v_pages = v_pages.at[phys, :, off].set(v.astype(v_pages.dtype))
         # live extent after this chunk's write = prefix + its real
         # tokens; pages past it gather as trash (fully masked), so the
         # chunk's fallback cost tracks live tokens, not table width
         # (at least one page: a degenerate all-pad row clamps its
         # queries to position 0, which must stay a real gather)
         tokens = jnp.minimum(lengths + (c - pad), W * pg)
-        kk, vv = gather_kv_pages(
-            k_pages, v_pages, page_table,
-            jnp.maximum((tokens + pg - 1) // pg, 1),
+        with jax.named_scope(scopes.ATTN_KERNEL):
+            kk, vv = gather_kv_pages(
+                k_pages, v_pages, page_table,
+                jnp.maximum((tokens + pg - 1) // pg, 1),
+            )
+            out = _sdpa_positions(
+                q, kk, vv, jnp.minimum(posc, W * pg - 1))
+    with jax.named_scope(scopes.ATTN_OUT):
+        y = linear(
+            params["out_proj"], out.reshape(b, c, nh * hd), compute_dtype
         )
-        out = _sdpa_positions(q, kk, vv, jnp.minimum(posc, W * pg - 1))
-    y = linear(params["out_proj"], out.reshape(b, c, nh * hd), compute_dtype)
     if quant:
         return y, (k_pages, v_pages, k_scale, v_scale)
     return y, (k_pages, v_pages)

@@ -35,6 +35,7 @@ from mamba_distributed_tpu.models.attention import (
     pack_attention_pages,
 )
 from mamba_distributed_tpu.models.common import init_linear, linear
+from mamba_distributed_tpu.obs import scopes
 from mamba_distributed_tpu.models.mamba1 import (
     init_mamba1_params,
     init_mamba1_state,
@@ -113,12 +114,13 @@ def _embed(params: dict, ids: jax.Array, compute_dtype) -> jax.Array:
     "scale": f32 (V, 1)}`` with one scale per vocab row, so the lookup
     dequantizes just the gathered rows."""
     emb = params["embedding"]
-    if isinstance(emb, dict):
-        # dequantize in f32 (scales keep full precision — same rule as
-        # linear() and _tied_logits), then cast once
-        rows = emb["kernel"][ids].astype(jnp.float32) * emb["scale"][ids]
-        return rows.astype(compute_dtype)
-    return emb[ids].astype(compute_dtype)
+    with jax.named_scope(scopes.EMBED):
+        if isinstance(emb, dict):
+            # dequantize in f32 (scales keep full precision — same rule as
+            # linear() and _tied_logits), then cast once
+            rows = emb["kernel"][ids].astype(jnp.float32) * emb["scale"][ids]
+            return rows.astype(compute_dtype)
+        return emb[ids].astype(compute_dtype)
 
 
 def _tied_logits(params: dict, normed: jax.Array, compute_dtype) -> jax.Array:
@@ -333,10 +335,13 @@ def _head_matrix(params, cfg: ModelConfig):
 def _final_logits(params, cfg: ModelConfig, hidden, residual):
     """Final fused add+norm -> (tied) LM head, fp32-accumulated."""
     compute_dtype = jnp.dtype(cfg.compute_dtype)
-    normed = _final_norm(params, cfg, hidden, residual)
-    if cfg.tie_embeddings:
-        return _tied_logits(params, normed, compute_dtype)
-    return linear(params["lm_head"], normed, compute_dtype).astype(jnp.float32)
+    with jax.named_scope(scopes.LM_HEAD_LOSS):
+        normed = _final_norm(params, cfg, hidden, residual)
+        if cfg.tie_embeddings:
+            return _tied_logits(params, normed, compute_dtype)
+        return linear(
+            params["lm_head"], normed, compute_dtype
+        ).astype(jnp.float32)
 
 
 def _remat(fn, cfg: ModelConfig, static_argnums=()):
@@ -473,9 +478,10 @@ def _backbone(
             )
             return carry, None
 
-        (res, aux_total), _ = jax.lax.scan(
-            group, (res, aux_total), (mstack, params["attn_blocks"])
-        )
+        with jax.named_scope(scopes.LAYERS):
+            (res, aux_total), _ = jax.lax.scan(
+                group, (res, aux_total), (mstack, params["attn_blocks"])
+            )
     elif cfg.attn_layer_idx:
         attn_idx = set(cfg.attn_layer_idx)
         mi = ai = 0
@@ -483,11 +489,12 @@ def _backbone(
             attn = i in attn_idx
             stack = params["attn_blocks"] if attn else params["blocks"]
             j = ai if attn else mi
-            bp = jax.tree.map(lambda p, j=j: p[j], stack)
             body = block
             if cfg.remat:
                 body = _remat(body, cfg, static_argnums=(1, 3, 4))
-            res, a = body(bp, cfg, res, attn, seq_ctx)
+            with jax.named_scope(scopes.LAYERS):
+                bp = jax.tree.map(lambda p, j=j: p[j], stack)
+                res, a = body(bp, cfg, res, attn, seq_ctx)
             aux_total = aux_total + a
             if attn:
                 ai += 1
@@ -502,9 +509,10 @@ def _backbone(
 
             if cfg.remat:
                 body = _remat(body, cfg)
-            (res, aux_total), _ = jax.lax.scan(
-                body, (res, aux_total), params["blocks"]
-            )
+            with jax.named_scope(scopes.LAYERS):
+                (res, aux_total), _ = jax.lax.scan(
+                    body, (res, aux_total), params["blocks"]
+                )
         else:
             def body(rs, bp):
                 rs, _ = block(bp, cfg, rs, False, seq_ctx)
@@ -512,7 +520,8 @@ def _backbone(
 
             if cfg.remat:
                 body = _remat(body, cfg)
-            res, _ = jax.lax.scan(body, res, params["blocks"])
+            with jax.named_scope(scopes.LAYERS):
+                res, _ = jax.lax.scan(body, res, params["blocks"])
 
     if num_last_tokens > 0:
         res = res[:, -num_last_tokens:]
@@ -565,22 +574,26 @@ def lm_loss(
         from mamba_distributed_tpu.ops.loss import blocked_cross_entropy
 
         res, aux = _backbone(params, cfg, input_ids, seq_ctx=seq_ctx)
-        ce = blocked_cross_entropy(
-            _final_norm(params, cfg, None, res),
-            _head_matrix(params, cfg),
-            targets,
-            n_blocks=cfg.loss_vocab_blocks,
-            compute_dtype=jnp.dtype(cfg.compute_dtype),
-        )
+        with jax.named_scope(scopes.LM_HEAD_LOSS):
+            ce = blocked_cross_entropy(
+                _final_norm(params, cfg, None, res),
+                _head_matrix(params, cfg),
+                targets,
+                n_blocks=cfg.loss_vocab_blocks,
+                compute_dtype=jnp.dtype(cfg.compute_dtype),
+            )
         aux = aux / (cfg.n_layer if cfg.moe_num_experts else 1)
     else:
         logits, aux = lm_forward(
             params, cfg, input_ids, seq_ctx=seq_ctx, return_aux=True
         )
-        lf = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(lf, axis=-1)
-        tgt = jnp.take_along_axis(lf, targets[..., None], axis=-1)[..., 0]
-        ce = jnp.mean(lse - tgt)
+        with jax.named_scope(scopes.LM_HEAD_LOSS):
+            lf = logits.astype(jnp.float32)
+            lse = jax.nn.logsumexp(lf, axis=-1)
+            tgt = jnp.take_along_axis(
+                lf, targets[..., None], axis=-1
+            )[..., 0]
+            ce = jnp.mean(lse - tgt)
     if cfg.moe_num_experts:
         return ce + cfg.moe_aux_weight * aux
     return ce
@@ -725,23 +738,27 @@ def lm_prefill(params: dict, cfg: ModelConfig, input_ids: jax.Array,
 
         def group(carry, xs):
             mblk, ablk = xs
-            carry, st_pre = jax.lax.scan(
-                mbody, carry, jax.tree.map(lambda x: x[:r], mblk)
-            )
+            with jax.named_scope(scopes.LAYERS):
+                carry, st_pre = jax.lax.scan(
+                    mbody, carry, jax.tree.map(lambda x: x[:r], mblk)
+                )
             hidden, residual, a_st = _block_fwd(
                 ablk, cfg, *carry, True, return_state=True
             )
-            carry, st_post = jax.lax.scan(
-                mbody, (hidden, residual), jax.tree.map(lambda x: x[r:], mblk)
-            )
+            with jax.named_scope(scopes.LAYERS):
+                carry, st_post = jax.lax.scan(
+                    mbody, (hidden, residual),
+                    jax.tree.map(lambda x: x[r:], mblk),
+                )
             m_st = jax.tree.map(
                 lambda a, b: jnp.concatenate([a, b], axis=0), st_pre, st_post
             )
             return carry, (m_st, to_pages(a_st))
 
-        (hidden, residual), (m_states, a_states) = jax.lax.scan(
-            group, (hidden, residual), (mstack, params["attn_blocks"])
-        )
+        with jax.named_scope(scopes.ATTN_LAYERS):
+            (hidden, residual), (m_states, a_states) = jax.lax.scan(
+                group, (hidden, residual), (mstack, params["attn_blocks"])
+            )
         state = {
             # (n_attn, period-1, ...) -> (n_mamba, ...), global layer order
             "blocks": jax.tree.map(
@@ -760,10 +777,13 @@ def lm_prefill(params: dict, cfg: ModelConfig, input_ids: jax.Array,
         for i in range(cfg.n_layer):
             attn = i in attn_idx
             stack = params["attn_blocks"] if attn else params["blocks"]
-            bp = jax.tree.map(lambda p, j=(ai if attn else mi): p[j], stack)
-            hidden, residual, st = _block_fwd(
-                bp, cfg, hidden, residual, attn, return_state=True
-            )
+            with jax.named_scope(scopes.ATTN_LAYERS):
+                bp = jax.tree.map(
+                    lambda p, j=(ai if attn else mi): p[j], stack
+                )
+                hidden, residual, st = _block_fwd(
+                    bp, cfg, hidden, residual, attn, return_state=True
+                )
             if attn:
                 a_states.append(to_pages(st))
                 ai += 1
@@ -792,9 +812,10 @@ def lm_prefill(params: dict, cfg: ModelConfig, input_ids: jax.Array,
             )
             return (hidden, residual), st
 
-        (hidden, residual), state_blocks = jax.lax.scan(
-            body, (hidden, residual), params["blocks"]
-        )
+        with jax.named_scope(scopes.LAYERS):
+            (hidden, residual), state_blocks = jax.lax.scan(
+                body, (hidden, residual), params["blocks"]
+            )
         state = {"blocks": state_blocks}
 
     logits = _final_logits(params, cfg, hidden[:, -1:], residual[:, -1:])
@@ -922,24 +943,27 @@ def _chunk_backbone(params: dict, cfg: ModelConfig, input_ids: jax.Array,
                 mblk, ablk, mst, akv = xs
                 pre = lambda x: jax.tree.map(lambda v: v[:r], x)
                 post = lambda x: jax.tree.map(lambda v: v[r:], x)
-                carry, new_pre = jax.lax.scan(
-                    body, carry, (pre(mblk), pre(mst))
-                )
+                with jax.named_scope(scopes.LAYERS):
+                    carry, new_pre = jax.lax.scan(
+                        body, carry, (pre(mblk), pre(mst))
+                    )
                 hidden, residual, new_kv = abody(ablk, *carry, akv)
-                carry, new_post = jax.lax.scan(
-                    body, (hidden, residual), (post(mblk), post(mst))
-                )
+                with jax.named_scope(scopes.LAYERS):
+                    carry, new_post = jax.lax.scan(
+                        body, (hidden, residual), (post(mblk), post(mst))
+                    )
                 new_m = jax.tree.map(
                     lambda a, b: jnp.concatenate([a, b], axis=0),
                     new_pre, new_post,
                 )
                 return carry, (new_m, new_kv)
 
-            (hidden, residual), (new_m, new_a) = jax.lax.scan(
-                group, (hidden, residual),
-                (mstack, params["attn_blocks"], mstate,
-                 state["attn_blocks"]),
-            )
+            with jax.named_scope(scopes.ATTN_LAYERS):
+                (hidden, residual), (new_m, new_a) = jax.lax.scan(
+                    group, (hidden, residual),
+                    (mstack, params["attn_blocks"], mstate,
+                     state["attn_blocks"]),
+                )
             new_blocks = jax.tree.map(
                 lambda x: x.reshape((-1,) + x.shape[2:]), new_m
             )
@@ -947,45 +971,49 @@ def _chunk_backbone(params: dict, cfg: ModelConfig, input_ids: jax.Array,
             attn_idx = set(cfg.attn_layer_idx)
             mi = ai = 0
             new_ms, new_as = [], []
-            for i in range(cfg.n_layer):
-                attn = i in attn_idx
-                if attn:
-                    bp = jax.tree.map(
-                        lambda p_, j=ai: p_[j], params["attn_blocks"]
-                    )
-                    akv = jax.tree.map(
-                        lambda s, j=ai: s[j], state["attn_blocks"]
-                    )
-                    hidden, residual, st = abody(bp, hidden, residual, akv)
-                    new_as.append(st)
-                    ai += 1
-                else:
-                    bp = jax.tree.map(
-                        lambda p_, j=mi: p_[j], params["blocks"]
-                    )
-                    st = jax.tree.map(
-                        lambda s, j=mi: s[j], state["blocks"]
-                    )
-                    hidden, residual, st = _block_fwd(
-                        bp, cfg, hidden, residual, False,
-                        return_state=True, token_mask=token_mask,
-                        initial_state=st,
-                    )
-                    new_ms.append(st)
-                    mi += 1
-            stack = lambda sts: jax.tree.map(
-                lambda *xs: jnp.stack(xs), *sts
-            )
-            new_blocks, new_a = stack(new_ms), stack(new_as)
+            with jax.named_scope(scopes.ATTN_LAYERS):
+                for i in range(cfg.n_layer):
+                    attn = i in attn_idx
+                    if attn:
+                        bp = jax.tree.map(
+                            lambda p_, j=ai: p_[j], params["attn_blocks"]
+                        )
+                        akv = jax.tree.map(
+                            lambda s, j=ai: s[j], state["attn_blocks"]
+                        )
+                        hidden, residual, st = abody(
+                            bp, hidden, residual, akv
+                        )
+                        new_as.append(st)
+                        ai += 1
+                    else:
+                        bp = jax.tree.map(
+                            lambda p_, j=mi: p_[j], params["blocks"]
+                        )
+                        st = jax.tree.map(
+                            lambda s, j=mi: s[j], state["blocks"]
+                        )
+                        hidden, residual, st = _block_fwd(
+                            bp, cfg, hidden, residual, False,
+                            return_state=True, token_mask=token_mask,
+                            initial_state=st,
+                        )
+                        new_ms.append(st)
+                        mi += 1
+                stack = lambda sts: jax.tree.map(
+                    lambda *xs: jnp.stack(xs), *sts
+                )
+                new_blocks, new_a = stack(new_ms), stack(new_as)
         return hidden, residual, {
             "blocks": new_blocks,
             "attn_blocks": new_a,
             "attn_meta": (tbl, lengths + n_real),
         }
 
-    (hidden, residual), state_blocks = jax.lax.scan(
-        body, (hidden, residual), (params["blocks"], state["blocks"])
-    )
+    with jax.named_scope(scopes.LAYERS):
+        (hidden, residual), state_blocks = jax.lax.scan(
+            body, (hidden, residual), (params["blocks"], state["blocks"])
+        )
     return hidden, residual, {"blocks": state_blocks}
 
 
@@ -1112,22 +1140,28 @@ def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
             mblk, ablk, mst, ast = xs
             pre = lambda x: jax.tree.map(lambda v: v[:r], x)
             post = lambda x: jax.tree.map(lambda v: v[r:], x)
-            carry, new_pre = jax.lax.scan(mbody, carry, (pre(mblk), pre(mst)))
+            with jax.named_scope(scopes.LAYERS):
+                carry, new_pre = jax.lax.scan(
+                    mbody, carry, (pre(mblk), pre(mst))
+                )
             hidden, residual, ast = _block_step(
                 ablk, cfg, *carry, ast, True, attn_ctx=attn_ctx
             )
-            carry, new_post = jax.lax.scan(
-                mbody, (hidden, residual), (post(mblk), post(mst))
-            )
+            with jax.named_scope(scopes.LAYERS):
+                carry, new_post = jax.lax.scan(
+                    mbody, (hidden, residual), (post(mblk), post(mst))
+                )
             new_m = jax.tree.map(
                 lambda a, b: jnp.concatenate([a, b], axis=0), new_pre, new_post
             )
             return carry, (new_m, ast)
 
-        (hidden, residual), (new_m, new_a) = jax.lax.scan(
-            group, (hidden, residual),
-            (mstack, params["attn_blocks"], mstate, state["attn_blocks"]),
-        )
+        with jax.named_scope(scopes.ATTN_LAYERS):
+            (hidden, residual), (new_m, new_a) = jax.lax.scan(
+                group, (hidden, residual),
+                (mstack, params["attn_blocks"], mstate,
+                 state["attn_blocks"]),
+            )
         new_state = {
             "blocks": jax.tree.map(
                 lambda x: x.reshape((-1,) + x.shape[2:]), new_m
@@ -1147,10 +1181,11 @@ def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
             else:
                 bp = jax.tree.map(lambda p, j=mi: p[j], params["blocks"])
                 st = jax.tree.map(lambda s, j=mi: s[j], state["blocks"])
-            hidden, residual, st = _block_step(
-                bp, cfg, hidden, residual, st, attn,
-                attn_ctx=attn_ctx if attn else None,
-            )
+            with jax.named_scope(scopes.ATTN_LAYERS):
+                hidden, residual, st = _block_step(
+                    bp, cfg, hidden, residual, st, attn,
+                    attn_ctx=attn_ctx if attn else None,
+                )
             if attn:
                 new_a.append(st)
                 ai += 1
@@ -1182,14 +1217,21 @@ def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
                 (hidden, residual), mesh, n_micro=n_micro,
             )
         else:
-            (hidden, residual), new_blocks = jax.lax.scan(
-                mbody, (hidden, residual), (params["blocks"], state["blocks"])
-            )
+            with jax.named_scope(scopes.LAYERS):
+                (hidden, residual), new_blocks = jax.lax.scan(
+                    mbody, (hidden, residual),
+                    (params["blocks"], state["blocks"]),
+                )
         new_state = {"blocks": new_blocks}
 
-    normed, _ = add_rms_norm(hidden, residual, params["norm_f"]["weight"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = _tied_logits(params, normed, compute_dtype)
-    else:
-        logits = linear(params["lm_head"], normed, compute_dtype).astype(jnp.float32)
+    with jax.named_scope(scopes.LM_HEAD_LOSS):
+        normed, _ = add_rms_norm(
+            hidden, residual, params["norm_f"]["weight"], cfg.norm_eps
+        )
+        if cfg.tie_embeddings:
+            logits = _tied_logits(params, normed, compute_dtype)
+        else:
+            logits = linear(
+                params["lm_head"], normed, compute_dtype
+            ).astype(jnp.float32)
     return logits.astype(jnp.float32), new_state

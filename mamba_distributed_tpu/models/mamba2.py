@@ -27,6 +27,7 @@ from mamba_distributed_tpu.models.common import (
     init_linear,
     linear,
 )
+from mamba_distributed_tpu.obs import scopes
 from mamba_distributed_tpu.ops.conv import causal_conv1d, causal_conv1d_update
 from mamba_distributed_tpu.ops.norm import rms_norm_gated
 from mamba_distributed_tpu.ops.ssd import ssd_chunked, ssd_state_update
@@ -121,20 +122,24 @@ def mamba2_mixer(
         seq_ctx, initial_conv_state, initial_ssm_state, return_final_state
     )
 
-    zxbcdt = linear(params["in_proj"], u, compute_dtype)
-    z, xBC, dt = _split_zxbcdt(zxbcdt, cfg)
+    with jax.named_scope(scopes.MIXER_IN_PROJ):
+        zxbcdt = linear(params["in_proj"], u, compute_dtype)
+        z, xBC, dt = _split_zxbcdt(zxbcdt, cfg)
 
-    if token_mask is not None:
-        if seq_ctx is not None:
-            raise ValueError("token_mask is a single-device prefill feature")
-        xBC = xBC * token_mask[..., None].astype(xBC.dtype)
+        if token_mask is not None:
+            if seq_ctx is not None:
+                raise ValueError(
+                    "token_mask is a single-device prefill feature"
+                )
+            xBC = xBC * token_mask[..., None].astype(xBC.dtype)
     if seq_ctx is not None:
         from mamba_distributed_tpu.parallel.seq_parallel import sp_conv1d
 
-        xBC, conv_state = sp_conv1d(
-            seq_ctx, xBC, params["conv"]["kernel"],
-            params["conv"].get("bias"), "silu",
-        )
+        with jax.named_scope(scopes.CONV):
+            xBC, conv_state = sp_conv1d(
+                seq_ctx, xBC, params["conv"]["kernel"],
+                params["conv"].get("bias"), "silu",
+            )
     else:
         xBC, conv_state = causal_conv1d(
             xBC,
@@ -145,41 +150,46 @@ def mamba2_mixer(
             return_final_state=True,
             impl=cfg.conv_impl,
         )
-    if token_mask is not None:
-        xBC = xBC * token_mask[..., None].astype(xBC.dtype)
-    x, B, C = _split_xbc(xBC, cfg)
+    # the scan's inputs (head split, softplus of dt) count as the scan's
+    with jax.named_scope(scopes.SSD):
+        if token_mask is not None:
+            xBC = xBC * token_mask[..., None].astype(xBC.dtype)
+        x, B, C = _split_xbc(xBC, cfg)
 
-    x = x.reshape(b, t, nh, cfg.headdim)
-    B = B.reshape(b, t, g, ds)
-    C = C.reshape(b, t, g, ds)
-    dtf = jax.nn.softplus(
-        dt.astype(jnp.float32) + params["dt_bias"][None, None, :]
-    )
-    A = -jnp.exp(params["A_log"])  # (nh,)
-    D = params["D"].reshape(nh, cfg.headdim) if cfg.d_has_hdim else params["D"]
+        x = x.reshape(b, t, nh, cfg.headdim)
+        B = B.reshape(b, t, g, ds)
+        C = C.reshape(b, t, g, ds)
+        dtf = jax.nn.softplus(
+            dt.astype(jnp.float32) + params["dt_bias"][None, None, :]
+        )
+        A = -jnp.exp(params["A_log"])  # (nh,)
+        D = (params["D"].reshape(nh, cfg.headdim) if cfg.d_has_hdim
+             else params["D"])
 
     if seq_ctx is not None:
         from mamba_distributed_tpu.parallel.seq_parallel import sp_ssd
 
-        y, ssm_state = sp_ssd(
-            seq_ctx, x, dtf, A, B, C, cfg.chunk_size, D,
-            compute_dtype=compute_dtype, ssm_impl=cfg.ssm_impl,
-        )
+        with jax.named_scope(scopes.SSD):
+            y, ssm_state = sp_ssd(
+                seq_ctx, x, dtf, A, B, C, cfg.chunk_size, D,
+                compute_dtype=compute_dtype, ssm_impl=cfg.ssm_impl,
+            )
     elif cfg.ssm_impl == "pallas":
         from mamba_distributed_tpu.ops.pallas import ssd_chunked_pallas
 
-        if initial_ssm_state is None and not return_final_state:
-            y = ssd_chunked_pallas(
-                x, dtf, A, B, C, chunk_size=cfg.chunk_size, D=D,
-                compute_dtype=compute_dtype,
-            )
-            ssm_state = None
-        else:
-            y, ssm_state = ssd_chunked_pallas(
-                x, dtf, A, B, C, chunk_size=cfg.chunk_size, D=D,
-                initial_state=initial_ssm_state, return_final_state=True,
-                compute_dtype=compute_dtype,
-            )
+        with jax.named_scope(scopes.SSD):
+            if initial_ssm_state is None and not return_final_state:
+                y = ssd_chunked_pallas(
+                    x, dtf, A, B, C, chunk_size=cfg.chunk_size, D=D,
+                    compute_dtype=compute_dtype,
+                )
+                ssm_state = None
+            else:
+                y, ssm_state = ssd_chunked_pallas(
+                    x, dtf, A, B, C, chunk_size=cfg.chunk_size, D=D,
+                    initial_state=initial_ssm_state,
+                    return_final_state=True, compute_dtype=compute_dtype,
+                )
     else:
         y, ssm_state = ssd_chunked(
             x, dtf, A, B, C,
@@ -193,12 +203,14 @@ def mamba2_mixer(
     # backward then never recomputes the SSD scan, the priciest part of
     # the block (models/lm.py:_remat)
     y = checkpoint_name(y, "mixer_out")
-    y = y.reshape(b, t, di)
-    y = rms_norm_gated(
-        y, z, params["norm"]["weight"], cfg.norm_eps,
-        group_size=di // g if g > 1 else None,
-    )
-    out = linear(params["out_proj"], y, compute_dtype)
+    with jax.named_scope(scopes.GATE_NORM):
+        y = y.reshape(b, t, di)
+        y = rms_norm_gated(
+            y, z, params["norm"]["weight"], cfg.norm_eps,
+            group_size=di // g if g > 1 else None,
+        )
+    with jax.named_scope(scopes.MIXER_OUT_PROJ):
+        out = linear(params["out_proj"], y, compute_dtype)
     if return_final_state:
         return out, (conv_state, ssm_state)
     return out
@@ -235,29 +247,34 @@ def mamba2_mixer_step(
     b, _ = u_t.shape
     compute_dtype = jnp.dtype(cfg.compute_dtype)
 
-    zxbcdt = linear(params["in_proj"], u_t, compute_dtype)
-    z, xBC, dt = _split_zxbcdt(zxbcdt, cfg)
+    with jax.named_scope(scopes.MIXER_IN_PROJ):
+        zxbcdt = linear(params["in_proj"], u_t, compute_dtype)
+        z, xBC, dt = _split_zxbcdt(zxbcdt, cfg)
 
     xBC, conv_state = causal_conv1d_update(
         xBC, conv_state, params["conv"]["kernel"], params["conv"].get("bias"),
         activation="silu",
     )
-    x, B, C = _split_xbc(xBC, cfg)
+    with jax.named_scope(scopes.SSD):
+        x, B, C = _split_xbc(xBC, cfg)
 
-    x = x.reshape(b, nh, cfg.headdim)
-    B = B.reshape(b, g, ds)
-    C = C.reshape(b, g, ds)
-    A = -jnp.exp(params["A_log"])
-    D = params["D"].reshape(nh, cfg.headdim) if cfg.d_has_hdim else params["D"]
+        x = x.reshape(b, nh, cfg.headdim)
+        B = B.reshape(b, g, ds)
+        C = C.reshape(b, g, ds)
+        A = -jnp.exp(params["A_log"])
+        D = (params["D"].reshape(nh, cfg.headdim) if cfg.d_has_hdim
+             else params["D"])
 
     y, ssm_state = ssd_state_update(
         ssm_state, x, dt.astype(jnp.float32), A, B, C, D,
         dt_bias=params["dt_bias"], dt_softplus=True,
     )
-    y = y.reshape(b, di)
-    y = rms_norm_gated(
-        y, z, params["norm"]["weight"], cfg.norm_eps,
-        group_size=di // g if g > 1 else None,
-    )
-    out = linear(params["out_proj"], y, compute_dtype)
+    with jax.named_scope(scopes.GATE_NORM):
+        y = y.reshape(b, di)
+        y = rms_norm_gated(
+            y, z, params["norm"]["weight"], cfg.norm_eps,
+            group_size=di // g if g > 1 else None,
+        )
+    with jax.named_scope(scopes.MIXER_OUT_PROJ):
+        out = linear(params["out_proj"], y, compute_dtype)
     return out, (conv_state, ssm_state)

@@ -44,13 +44,16 @@ from mamba_distributed_tpu.obs.sentinel import (
 )
 from mamba_distributed_tpu.obs.tracer import (
     NULL_TRACER,
+    AnnotatedTracer,
     SpanTracer,
+    annotated,
     append_jsonl,
     jsonable,
 )
 from mamba_distributed_tpu.obs.watchdog import CompileWatchdog
 
 __all__ = [
+    "AnnotatedTracer",
     "CompileWatchdog",
     "DivergenceError",
     "DivergenceSentinel",
@@ -60,6 +63,7 @@ __all__ = [
     "SpanTracer",
     "StreamingHistogram",
     "TickRegressionDetector",
+    "annotated",
     "append_jsonl",
     "export_chrome_trace",
     "jsonable",

@@ -288,3 +288,68 @@ class _NullTracer:
 
 
 NULL_TRACER = _NullTracer()
+
+
+class _AnnotatedSpan:
+    """A tracer's span and a profiler annotation, entered and left as one."""
+
+    __slots__ = ("_span", "_annotation")
+
+    def __init__(self, span, annotation):
+        self._span, self._annotation = span, annotation
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        return self._span.__enter__()
+
+    def __exit__(self, *exc):
+        try:
+            return self._span.__exit__(*exc)
+        finally:
+            self._annotation.__exit__(*exc)
+
+
+class AnnotatedTracer:
+    """Puts a tracer's spans on the profiler's clock as well.
+
+    ``span(name, **attrs)`` opens the wrapped tracer's span and a
+    ``jax.profiler.TraceAnnotation(name)``, so a ``jax.profiler`` capture of
+    the trainer or a serving worker shows ``serving_tick``, ``serving_admit``,
+    ``data_load`` ... on the host's line, above the device's operations.
+    Spans named ``step_span`` open a ``StepTraceAnnotation("train",
+    step_num=attrs["step"])`` instead: the profiler's per-step analysis keys
+    on it.  With no profiler session an annotation is a flag test (some
+    hundred ns; PERF.md has the measurement).  Everything else (``event``,
+    ``write``, ``ring_pull``, ``enabled`` ...) is the wrapped tracer's own.
+
+    ``annotated(tracer)`` is how ``Trainer`` and ``ServingEngine`` take
+    their tracer; it wraps once.  JAX is imported here, on first use, so
+    that ``obs`` stays importable by processes that must stay off it.
+    """
+
+    def __init__(self, inner, step_span: str | None = None):
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+        self._inner = inner
+        self._step_span = step_span
+        self._annotation, self._step_annotation = (
+            TraceAnnotation, StepTraceAnnotation)
+
+    def span(self, name: str, **attrs):
+        span = self._inner.span(name, **attrs)  # may raise: nothing is open
+        if name == self._step_span:
+            annotation = self._step_annotation(
+                "train", step_num=attrs.get("step", 0))
+        else:
+            annotation = self._annotation(name)
+        return _AnnotatedSpan(span, annotation)
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+
+def annotated(tracer, step_span: str | None = None):
+    """``tracer`` under an ``AnnotatedTracer`` (once, however often asked)."""
+    if isinstance(tracer, AnnotatedTracer):
+        return tracer
+    return AnnotatedTracer(tracer, step_span)

@@ -18,7 +18,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from mamba_distributed_tpu.obs import scopes
 
+
+@jax.named_scope(scopes.CONV)
 def causal_conv1d(
     x: jax.Array,
     weight: jax.Array,
@@ -87,6 +90,7 @@ def causal_conv1d(
     return y
 
 
+@jax.named_scope(scopes.CONV)
 def causal_conv1d_update(
     x_t: jax.Array,
     conv_state: jax.Array,

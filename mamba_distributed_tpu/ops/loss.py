@@ -22,6 +22,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from mamba_distributed_tpu.obs import scopes
+
 
 def _block_logits(normed, head_blk, compute_dtype):
     """One vocab block of the head matmul, with the dense path's dtype
@@ -78,11 +80,16 @@ def _forward_scan(normed, head, targets, n_blocks, compute_dtype):
     return m + jnp.log(s), tgt
 
 
+# the scope is entered in _fwd and _bwd themselves: a custom_vjp's rules
+# are traced apart from the call site, so its backward carries no name
+# unless it is given one here
+@jax.named_scope(scopes.LM_HEAD_LOSS)
 def _fwd(normed, head, targets, n_blocks, compute_dtype):
     lse, tgt = _forward_scan(normed, head, targets, n_blocks, compute_dtype)
     return jnp.mean(lse - tgt), (normed, head, targets, lse)
 
 
+@jax.named_scope(scopes.LM_HEAD_LOSS)
 def _bwd(n_blocks, compute_dtype, res, g):
     normed, head, targets, lse = res
     V, d = head.shape

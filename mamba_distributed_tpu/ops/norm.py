@@ -6,8 +6,9 @@ Equivalent of the reference dependency's fused Triton layernorm kernels
 On TPU we express the math in plain JAX and let XLA fuse the residual add,
 the normalization, and the neighbouring matmul prologue — elementwise
 chains like these are exactly what the XLA fusion pass exists for, so a
-hand-written Pallas kernel is deliberately not used unless a profile
-(scripts/profile_step.py) ever shows the fusion breaking.
+hand-written Pallas kernel is deliberately not used unless a traced
+benchmark run (``device time by scope``: ``gate_norm``, ``layers``) ever
+shows the fusion breaking.
 
 Matches the reference semantics: the residual stream is carried in fp32
 (``residual_in_fp32=True``), normalization statistics are computed in fp32,

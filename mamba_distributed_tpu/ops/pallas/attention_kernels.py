@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mamba_distributed_tpu.obs import scopes
 from mamba_distributed_tpu.ops.pallas.common import resolve_interpret
 
 _NEG_INF = float("-inf")
@@ -256,6 +257,7 @@ def _fa_fwd_impl(qt, kt, vt, offset, tk_valid, qb, kb, interpret):
                                  "arbitrary"),
         ),
         interpret=interpret,
+        name="fa_fwd",
     )(qt, kt, vt)
     return o, lse
 
@@ -291,6 +293,7 @@ def _fa_bwd_dq_call(qt, kt, vt, do, lse, dlt, offset, tk_valid, qb, kb,
         scratch_shapes=[pltpu.VMEM((qb, hd), jnp.float32)],
         compiler_params=seq_kv,
         interpret=interpret,
+        name="fa_bwd_dq",
     )(qt, kt, vt, do, lse, dlt)
 
 
@@ -331,6 +334,7 @@ def _fa_bwd_dkv_call(qt, kt, vt, do, lse, dlt, offset, tk_valid, qb, kb,
         ],
         compiler_params=seq_kv,
         interpret=interpret,
+        name="fa_bwd_dkv",
     )(qt, kt, vt, do, lse, dlt)
 
     # GQA group-sum of the per-q-head partials (rep == 1 is a no-op reshape)
@@ -437,6 +441,7 @@ def _fa_core_fwd(qt, kt, vt, offset, tk_valid, qb, kb, interpret):
     return o, (qt, kt, vt, o, lse)
 
 
+@jax.named_scope(scopes.ATTN_KERNEL)  # a custom_vjp's backward has no name
 def _fa_core_bwd(offset, tk_valid, qb, kb, interpret, res, do):
     qt, kt, vt, o, lse = res
     dq, dk, dv = _fa_bwd_impl(
@@ -690,6 +695,7 @@ def ragged_paged_decode_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="ragged_paged_decode_attention",
     )(*prefetch, qh, k_pages, v_pages)
     return out[:, :, :rep].reshape(S, nh, hd)
 
@@ -992,6 +998,7 @@ def ragged_paged_prefill_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="ragged_paged_prefill_attention",
     )(*prefetch, qh, kc, vc, k_pages, v_pages)
 
     o = out[:, :, :Q].reshape(b, nkv, c, rep, hd)

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mamba_distributed_tpu.obs import scopes
 from mamba_distributed_tpu.ops.pallas.common import resolve_interpret
 from mamba_distributed_tpu.ops.scan import _divisor_chunk
 from mamba_distributed_tpu.ops.ssd import cumsum_mxu, state_passing
@@ -265,6 +266,7 @@ def _ssd_pallas_fwd_impl(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="ssd_fused_fwd",
     )(cells["x"], cells["dt"], cells["a"], cells["at"], cells["e"],
       cells["w"], gamma_cells, cells["B"], cells["C"], h0)
 
@@ -458,6 +460,7 @@ def _ssd_pallas_bwd_impl(
         out_specs=st_spec,
         compiler_params=_PARALLEL3,
         interpret=interpret,
+        name="ssd_chunk_states",
     )(cells["x"], cells["w"], cells["B"])
     prev_states, _ = state_passing(states, chunk_decay, initial_state)
 
@@ -507,6 +510,7 @@ def _ssd_pallas_bwd_impl(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="ssd_fused_bwd",
     )(cells["x"], cells["dt"], cells["a"], cells["at"], cells["e"],
       cells["d"], gamma_cells, cells["B"], cells["C"], prev_states, dyr,
       dfin)
@@ -578,6 +582,7 @@ def _core_fwd(
     return out, (x, dt, A, B, C, initial_state)
 
 
+@jax.named_scope(scopes.SSD)  # a custom_vjp's backward has no name
 def _core_bwd(chunk_size, compute_dtype, interpret, return_final_state, res, ct):
     """Pallas backward (see the backward section above)."""
     x, dt, A, B, C, initial_state = res

@@ -33,6 +33,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from mamba_distributed_tpu.obs import scopes
+
 
 def cumsum_mxu(x: jax.Array, axis: int = -1, reverse: bool = False) -> jax.Array:
     """Inclusive (reverse-)cumsum as a triangular matmul.
@@ -129,6 +131,7 @@ def ssd_seq(
     return y
 
 
+@jax.named_scope(scopes.CHUNK_LOCAL)
 def chunk_local(
     x: jax.Array,
     dt: jax.Array,
@@ -223,6 +226,7 @@ def chunk_local(
 _STATE_PASSING_EINSUM_MAX_NC = 256
 
 
+@jax.named_scope(scopes.STATE_PASSING)
 def state_passing(
     states: jax.Array,
     chunk_decay: jax.Array,
@@ -295,6 +299,7 @@ def state_passing(
     return prev_states, final_state
 
 
+@jax.named_scope(scopes.COMBINE_CHUNK_OUTPUTS)
 def combine_chunk_outputs(
     y_diag: jax.Array,
     off_ctx: tuple[jax.Array, jax.Array],
@@ -332,6 +337,7 @@ def combine_chunk_outputs(
     return y.astype(x.dtype)
 
 
+@jax.named_scope(scopes.SSD)
 def ssd_chunked(
     x: jax.Array,
     dt: jax.Array,
@@ -365,6 +371,7 @@ def ssd_chunked(
     return y
 
 
+@jax.named_scope(scopes.SSD)
 def ssd_state_update(
     ssm_state: jax.Array,
     x_t: jax.Array,

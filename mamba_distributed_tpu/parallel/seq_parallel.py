@@ -27,8 +27,9 @@ exchange (only the cheap final ``combine_chunk_outputs`` consumes both),
 so the XLA scheduler is free to run the ppermute chain concurrently with
 the local matmuls; nothing in the program order forces the exchange onto
 the critical path.  Whether the scheduler actually hides the (tiny,
-O(d_state)) exchange is a hardware-profile question — measure with
-``scripts/profile_step.py`` on a seq-sharded config before tuning
+O(d_state)) exchange is a hardware-profile question — measure it in a
+device trace of a seq-sharded config (the exposed collective time, as
+``benchmark/readers/trace_exposed_share.py`` reads it) before tuning
 further.
 """
 

@@ -66,7 +66,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from mamba_distributed_tpu.config import ModelConfig
 from mamba_distributed_tpu.inference.bucketing import next_pow2_bucket, pad_to_bucket
-from mamba_distributed_tpu.obs import NULL_TRACER, StreamingHistogram
+from mamba_distributed_tpu.obs import (
+    NULL_TRACER,
+    StreamingHistogram,
+    annotated,
+    scopes,
+)
 from mamba_distributed_tpu.inference.generate import vocab_pad_mask
 from mamba_distributed_tpu.models.attention import attention_page_count
 from mamba_distributed_tpu.models.lm import (
@@ -97,7 +102,10 @@ from mamba_distributed_tpu.serving.scheduler import (
     check_tenant_quota,
 )
 from mamba_distributed_tpu.utils.metrics import ServingMetrics
-from mamba_distributed_tpu.utils.platform import describe_devices
+from mamba_distributed_tpu.utils.platform import (
+    describe_devices,
+    key_compile_cache_by_scopes,
+)
 
 # Python-side-effect trace counters (one bump per jit trace) — the
 # bucketing exists to bound these; tests/test_serving.py pins them (the
@@ -222,15 +230,16 @@ def _tick(params: dict, pool: dict, tbl=None, lengths=None, *,
         # nothing, and its parked scan carry must survive the tick
         live = meta["active"] & ~meta["done"] & ~meta["prefilling"]
         has_eos = meta["eos_id"] >= 0
-        keys = jax.vmap(jax.random.fold_in)(meta["key"], meta["step"])
-        vals, idx = jax.lax.top_k(pool["logits"] + pad_mask, k_max)
-        vals = jnp.where(col < meta["top_k"][:, None], vals, -jnp.inf)
-        # per-row categorical: same bits as generate's batch-1 draw
-        choice = jax.vmap(
-            lambda k, v, t: jax.random.categorical(k, v / t)
-        )(keys, vals, meta["temperature"])
-        tok = jnp.take_along_axis(idx, choice[:, None], axis=1)[:, 0]
-        tok = jnp.where(meta["done"] & has_eos, meta["eos_id"], tok)
+        with jax.named_scope(scopes.SAMPLE):
+            keys = jax.vmap(jax.random.fold_in)(meta["key"], meta["step"])
+            vals, idx = jax.lax.top_k(pool["logits"] + pad_mask, k_max)
+            vals = jnp.where(col < meta["top_k"][:, None], vals, -jnp.inf)
+            # per-row categorical: same bits as generate's batch-1 draw
+            choice = jax.vmap(
+                lambda k, v, t: jax.random.categorical(k, v / t)
+            )(keys, vals, meta["temperature"])
+            tok = jnp.take_along_axis(idx, choice[:, None], axis=1)[:, 0]
+            tok = jnp.where(meta["done"] & has_eos, meta["eos_id"], tok)
         if hybrid:
             state_in = {**pool["state"], "attn_meta": (tbl, lengths)}
             logits, state = lm_step(params, cfg, state_in, tok,
@@ -248,15 +257,16 @@ def _tick(params: dict, pool: dict, tbl=None, lengths=None, *,
         # Only the conv+SSM "blocks" subtree has a per-slot axis; the
         # attention page pool is protected by write_mask instead.
         hold = meta["prefilling"]
-        blocks = jax.tree.map(
-            lambda new, old: jnp.where(
-                hold.reshape((1, -1) + (1,) * (new.ndim - 2)), old, new
-            ),
-            state["blocks"],
-            pool["state"]["blocks"],
-        )
-        state = {**state, "blocks": blocks}
-        logits = jnp.where(hold[:, None], pool["logits"], logits)
+        with jax.named_scope(scopes.POOL_SELECT):
+            blocks = jax.tree.map(
+                lambda new, old: jnp.where(
+                    hold.reshape((1, -1) + (1,) * (new.ndim - 2)), old, new
+                ),
+                state["blocks"],
+                pool["state"]["blocks"],
+            )
+            state = {**state, "blocks": blocks}
+            logits = jnp.where(hold[:, None], pool["logits"], logits)
         step = meta["step"] + live.astype(jnp.int32)
         done = meta["done"] | (
             live & ((has_eos & (tok == meta["eos_id"])) | (step >= meta["max_new"]))
@@ -268,9 +278,13 @@ def _tick(params: dict, pool: dict, tbl=None, lengths=None, *,
         }
         return (new_pool, lengths), (tok, live, done)
 
-    (pool, _), (tokens, emitted, done) = jax.lax.scan(
-        one, (pool, lengths), None, length=steps
-    )
+    # the sub-step scan carries the whole pool (the slots' recurrent state;
+    # hybrid: the KV pages too), so what it moves around its body is filed
+    # as a layer scan's own
+    with jax.named_scope(scopes.ATTN_LAYERS if hybrid else scopes.LAYERS):
+        (pool, _), (tokens, emitted, done) = jax.lax.scan(
+            one, (pool, lengths), None, length=steps
+        )
     return pool, tokens, emitted, done
 
 
@@ -469,6 +483,7 @@ class ServingEngine:
                                 model_shards=cfg.serving_model_shards,
                                 stage_shards=cfg.serving_stage_shards)
         self.mesh = mesh
+        key_compile_cache_by_scopes()  # before the first program compiles
         # stderr: the bench scripts keep stdout for their one JSON line
         print(f"serving engine: {describe_devices(mesh)}", file=sys.stderr,
               flush=True)
@@ -813,6 +828,16 @@ class ServingEngine:
         self.results: dict[int, GenerationResult] = {}
 
     # ------------------------------------------------------------- admission
+
+    @property
+    def tracer(self):
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        # the engine's spans go on the profiler's clock too
+        # (obs/tracer.AnnotatedTracer); wrapped once, here
+        self._tracer = annotated(tracer)
 
     def submit(self, request: GenerationRequest) -> int:
         """Queue a request; returns its request_id."""
@@ -1430,6 +1455,8 @@ class ServingEngine:
         # per-request ITL histogram rides in the finish record so
         # obs_report.py can merge per-token percentiles across requests
         tracked.t_admit = t_admit
+        if full_hit or plan is None:
+            tracked.t_prefill_done = t_admit  # nothing left to prefill
         tracked.itl_hist = StreamingHistogram()
         self.metrics.record_queue_wait(t_admit - tracked.t_submit)
         tracked.slot = slot
@@ -1516,6 +1543,7 @@ class ServingEngine:
                 self._seed_spec(tracked, logits)
                 self._prefill_queue.remove(slot)
                 tracked.status = RequestStatus.DECODE
+                tracked.t_prefill_done = time.perf_counter()
                 # a partial hit seeded prefill_seeded_tokens of this
                 # prompt from the cache — report only the COMPUTED
                 # share (the seeded share is already accounted as
@@ -2760,7 +2788,14 @@ class ServingEngine:
             if t.status is RequestStatus.DECODE
         )
         t0 = time.perf_counter()
+        # ``occupied`` counts residents, ``live`` the slots this tick
+        # decodes; ``prefill_tokens`` is what the prefill phase dispatched
+        # since the last tick (chunk lanes plus one-shot prompt tokens)
         with self.tracer.span("serving_tick", occupied=occupied,
+                              live=len(live_slots),
+                              prefill_tokens=(
+                                  self._pending_chunk_tokens
+                                  + self._pending_oneshot_real_tokens),
                               traces=live_traces):
             if self.spec:
                 # speculative draft-verify tick: one lm_verify_chunk
@@ -2803,6 +2838,16 @@ class ServingEngine:
                     # live sub-step, exactly what `emitted` marks
                     self._kv_len += emitted.sum(axis=0).astype(np.int32)
         t_now = time.perf_counter()
+        with self.tracer.span("serving_emit"):
+            return self._emit(tokens, emitted, done, t0, t_now, occupied,
+                              width, live_traces, bubble_lanes)
+
+    def _emit(self, tokens, emitted, done, t0: float, t_now: float,
+              occupied: int, width, live_traces, bubble_lanes: int
+              ) -> list[TokenEvent]:
+        """``step()``'s host work once the tick's arrays are on the host
+        (span ``serving_emit``): token events, latency stamps, evictions,
+        the finished requests' records, the tick's record."""
         dt = t_now - t0
 
         events: list[TokenEvent] = []
@@ -2837,6 +2882,18 @@ class ServingEngine:
             if tracked.t_first_token is None:
                 tracked.t_first_token = t_now
                 self.metrics.record_ttft(t_now - tracked.t_submit)
+                # TTFT split where it is spent; the three sum to it
+                t_admit = tracked.t_admit or tracked.t_submit
+                t_prefilled = tracked.t_prefill_done or t_admit
+                self.tracer.event(
+                    "serving_first_token", request=tracked.request_id,
+                    trace=tracked.trace_id,
+                    prompt_tokens=int(len(tracked.request.prompt_ids)),
+                    chunks=(tracked.plan.n_chunks if tracked.plan else 0),
+                    queue_wait_ms=(t_admit - tracked.t_submit) * 1000,
+                    prefill_wait_ms=(t_prefilled - t_admit) * 1000,
+                    first_tick_wait_ms=(t_now - t_prefilled) * 1000,
+                )
                 if self.prefix_cache is not None:
                     # TTFT split hit-vs-miss: the cache's whole point is
                     # this delta (summary()["prefix_cache"])

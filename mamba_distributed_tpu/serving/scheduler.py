@@ -168,6 +168,10 @@ class _Tracked:
     # (obs/: per-request serving telemetry; docs/OBSERVABILITY.md) ---
     t_submit: float = 0.0  # stamped by FCFSScheduler.submit
     t_admit: float | None = None  # slot granted, prefill dispatched
+    # the request's LAST prefill program dispatched (the one-shot prefill,
+    # or the last chunk grant): between t_admit and here the request waits
+    # in the chunk queue, from here to t_first_token for the tick
+    t_prefill_done: float | None = None
     t_first_token: float | None = None  # first decode token on host
     t_last_token: float | None = None  # most recent token on host
     # per-request ITL histogram (StreamingHistogram), created at admit;

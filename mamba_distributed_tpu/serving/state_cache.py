@@ -89,6 +89,7 @@ import jax.numpy as jnp
 
 from mamba_distributed_tpu.config import ModelConfig
 from mamba_distributed_tpu.models.lm import init_lm_blocks_state
+from mamba_distributed_tpu.obs import scopes
 
 
 def page_shard_ranges(
@@ -337,6 +338,7 @@ def _set_row(arr: jax.Array, slot: jax.Array, value) -> jax.Array:
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@jax.named_scope(scopes.POOL_SELECT)
 def insert(
     pool: dict,
     slot: jax.Array,
@@ -577,6 +579,7 @@ def scatter_slots(rows: dict, compact: dict, inv: jax.Array,
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@jax.named_scope(scopes.POOL_SELECT)
 def evict(pool: dict, slot: jax.Array) -> dict:
     """Free ``slot``: mark it empty.  The stale state/logits stay in
     place — the next ``insert`` overwrites them, and the decode tick
@@ -592,6 +595,7 @@ def evict(pool: dict, slot: jax.Array) -> dict:
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@jax.named_scope(scopes.POOL_SELECT)
 def stash_prefill(
     pool: dict,
     slot: jax.Array,
@@ -632,6 +636,7 @@ def stash_prefill(
 
 
 @jax.jit
+@jax.named_scope(scopes.POOL_SELECT)
 def read_state(pool: dict, slot: jax.Array):
     """Slice ``slot``'s batch-1 state pytree back out (resume a stashed
     prefill at the next budget grant).  NOT donated — the pool lives on."""
@@ -644,6 +649,7 @@ def read_state(pool: dict, slot: jax.Array):
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@jax.named_scope(scopes.POOL_SELECT)
 def finish_prefill(pool: dict, slot: jax.Array, state: dict,
                    logits: jax.Array) -> dict:
     """Complete a chunked prefill: write the final carry + last logits and

@@ -18,6 +18,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from mamba_distributed_tpu.config import TrainConfig
 from mamba_distributed_tpu.models import lm_loss
 from mamba_distributed_tpu.models.lm import lm_loss_pipelined
+from mamba_distributed_tpu.obs import scopes
 from mamba_distributed_tpu.parallel.sharding import batch_sharding
 
 # Python-side-effect trace counters (one bump per jit trace), same idiom
@@ -107,15 +108,19 @@ def make_train_step(
                 gsum, lsum = carry
                 xb, yb = xs
                 l, g = jax.value_and_grad(loss_fn)(params, xb, yb)
-                return (jax.tree.map(jnp.add, gsum, g), lsum + l), None
+                with jax.named_scope(scopes.OPTIMIZER):  # accumulation
+                    gsum = jax.tree.map(jnp.add, gsum, g)
+                return (gsum, lsum + l), None
 
             zeros = jax.tree.map(jnp.zeros_like, params)
             (gsum, lsum), _ = jax.lax.scan(micro, (zeros, 0.0), (x, y))
-            grads = jax.tree.map(lambda g: g / accum, gsum)
+            with jax.named_scope(scopes.OPTIMIZER):
+                grads = jax.tree.map(lambda g: g / accum, gsum)
             loss = lsum / accum
-        grad_norm = optax.global_norm(grads)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        with jax.named_scope(scopes.OPTIMIZER):
+            grad_norm = optax.global_norm(grads)
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
         if freeze is not None:
             new_params = jax.tree.map(
                 lambda frozen, new, old: old if frozen else new,

@@ -29,6 +29,7 @@ from mamba_distributed_tpu.obs import (
     DivergenceError,
     DivergenceSentinel,
     SpanTracer,
+    annotated,
 )
 from mamba_distributed_tpu.parallel.mesh import build_mesh
 from mamba_distributed_tpu.parallel.sharding import (
@@ -39,7 +40,10 @@ from mamba_distributed_tpu.training.optimizer import lr_schedule, make_optimizer
 from mamba_distributed_tpu.training.train_step import make_eval_step, make_train_step
 from mamba_distributed_tpu.utils.flops import flops_per_token, peak_flops_per_chip
 from mamba_distributed_tpu.utils.metrics import MetricsLogger
-from mamba_distributed_tpu.utils.platform import describe_devices
+from mamba_distributed_tpu.utils.platform import (
+    describe_devices,
+    key_compile_cache_by_scopes,
+)
 
 
 class Trainer:
@@ -52,6 +56,7 @@ class Trainer:
         decode_fn=None,
     ):
         self.cfg = cfg
+        key_compile_cache_by_scopes()  # before the first program compiles
         self.mesh = build_mesh(cfg.mesh, devices)
         self.master = jax.process_index() == 0
         self.verbose = verbose and self.master
@@ -174,6 +179,17 @@ class Trainer:
 
     # ------------------------------------------------------------------
 
+    @property
+    def tracer(self):
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        # whoever hands the trainer a tracer (the constructor, a benchmark)
+        # gets its spans on the profiler's clock too, the step's span as
+        # the profiler's step (obs/tracer.AnnotatedTracer)
+        self._tracer = annotated(tracer, step_span="train_step")
+
     def _global_batch(self, accum: int, loader) -> tuple[jax.Array, jax.Array]:
         xs, ys = [], []
         for _ in range(accum):
@@ -253,32 +269,39 @@ class Trainer:
                 self.params, self.opt_state, loss, grad_norm = out[:4]
                 jax.block_until_ready(loss)
             dt = time.time() - t0
-            # host scalars, fetched once: the logger and the sentinel both
-            # consume these — the sentinel adds zero extra device syncs
-            loss_f, grad_norm_f = float(loss), float(grad_norm)
-            overflow = int(out[4]) if self._overflow_on else None
-            tok_per_sec = tokens_per_step / dt
-            mfu = mfu_hw = None
-            if self._peak is not None:
-                mfu = self._flops_per_token_model * tok_per_sec / self._peak
-                mfu_hw = self._flops_per_token * tok_per_sec / self._peak
-            self.logger.train_step(
-                step, loss_f, float(self.schedule(step)), grad_norm_f,
-                dt, tok_per_sec, mfu, mfu_hw,
-            )
-            if self.sentinel is not None and self.sentinel.observe_step(
-                step, loss_f, grad_norm_f, overflow=overflow,
-                step_ms=round(dt * 1000, 2),
-            ):
-                if cfg.telemetry.halt_on_divergence:
-                    where = (self.sentinel.dumped_to
-                             or "written by process 0")  # non-master has
-                    raise DivergenceError(  # no dump path of its own
-                        f"non-finite loss/grad_norm at step {step} "
-                        f"(loss={loss_f}, grad_norm={grad_norm_f}); flight "
-                        f"record: {where}"
-                    )
+            with self.tracer.span("train_log", step=step):
+                self._log_step(step, out, loss, grad_norm, dt,
+                               tokens_per_step)
             self.step += 1
+
+    def _log_step(self, step, out, loss, grad_norm, dt, tokens_per_step):
+        """The loop's tail after ``train_step`` (span ``train_log``): the
+        scalar fetches, the logger's record, the sentinel."""
+        # host scalars, fetched once: the logger and the sentinel both
+        # consume these — the sentinel adds zero extra device syncs
+        loss_f, grad_norm_f = float(loss), float(grad_norm)
+        overflow = int(out[4]) if self._overflow_on else None
+        tok_per_sec = tokens_per_step / dt
+        mfu = mfu_hw = None
+        if self._peak is not None:
+            mfu = self._flops_per_token_model * tok_per_sec / self._peak
+            mfu_hw = self._flops_per_token * tok_per_sec / self._peak
+        self.logger.train_step(
+            step, loss_f, float(self.schedule(step)), grad_norm_f,
+            dt, tok_per_sec, mfu, mfu_hw,
+        )
+        if self.sentinel is not None and self.sentinel.observe_step(
+            step, loss_f, grad_norm_f, overflow=overflow,
+            step_ms=round(dt * 1000, 2),
+        ):
+            if self.cfg.telemetry.halt_on_divergence:
+                where = (self.sentinel.dumped_to
+                         or "written by process 0")  # non-master has
+                raise DivergenceError(  # no dump path of its own
+                    f"non-finite loss/grad_norm at step {step} "
+                    f"(loss={loss_f}, grad_norm={grad_norm_f}); flight "
+                    f"record: {where}"
+                )
 
     def sample(self, num_return: int = 4, max_new_tokens: int = 32,
                top_k: int = 50):

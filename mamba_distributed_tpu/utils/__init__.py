@@ -1,4 +1,4 @@
-"""Shared utilities: FLOPs accounting, metrics logging, profiling."""
+"""Shared utilities: FLOPs accounting, metrics logging."""
 
 from mamba_distributed_tpu.utils.flops import flops_per_token, peak_flops_per_chip
 from mamba_distributed_tpu.utils.metrics import MetricsLogger

@@ -40,7 +40,28 @@ def configure_compile_cache() -> str:
             "jax_compilation_cache_dir", os.path.join(CACHE_ROOT, "jax")
         )
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    key_compile_cache_by_scopes()
     return env_dir or jax.config.jax_compilation_cache_dir
+
+
+def key_compile_cache_by_scopes() -> None:
+    """Make an operation's names part of the persistent cache's key.
+
+    By default the cache keys a program by its HLO with the debug info
+    stripped, and a hit loads the executable *with the names it was
+    compiled under*: after a scope is added or renamed
+    (``obs/scopes.py``) every device trace would go on showing the old
+    ``op_name``s until the cache is emptied (seen on the chip: a train
+    step compiled before the scopes existed was found again and 100 % of
+    its time read as unscoped).  JAX's own switch for this: the key then
+    holds names, files and lines, so a checkout at another path, or an
+    edit that moves lines, compiles once more.  ``Trainer`` and
+    ``ServingEngine`` call this as they are built, so whoever builds them
+    without an entry point (the benchmark) gets traces that can be read.
+    """
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def describe_devices(mesh=None) -> str:

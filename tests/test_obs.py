@@ -233,20 +233,6 @@ def test_null_tracer_is_noop(tmp_path):
     assert list(tmp_path.iterdir()) == []
 
 
-# ------------------------------------------------- StepTimer (satellite)
-
-
-@pytest.mark.fast
-def test_step_timer_stop_without_start_warns():
-    from mamba_distributed_tpu.utils.profiling import StepTimer
-
-    timer = StepTimer()
-    with pytest.warns(RuntimeWarning, match="without start"):
-        assert timer.stop() == 0.0
-    timer.start()
-    assert timer.stop() >= 0.0  # normal path unaffected
-
-
 # ------------------------------------------- flight recorder + sentinel
 
 
