@@ -1053,10 +1053,11 @@ def init_lm_state(cfg: ModelConfig, batch: int, max_len: int = 0):
 
 
 def _block_step(bp, cfg: ModelConfig, hidden, residual, st, attn: bool,
-                attn_ctx=None):
+                attn_ctx=None, state_mask=None):
     """One decode-step block (shared by the scan and unrolled paths).
     ``attn_ctx = (page_table, lengths, write_mask)`` is the layer-shared
-    paged-KV metadata (attention layers only)."""
+    paged-KV metadata (attention layers only); ``state_mask`` is
+    ``lm_step``'s, for the Mamba layers' conv + SSM carry."""
     compute_dtype = jnp.dtype(cfg.compute_dtype)
     normed, residual = add_rms_norm(
         hidden, residual, bp["norm"]["weight"], cfg.norm_eps,
@@ -1071,7 +1072,9 @@ def _block_step(bp, cfg: ModelConfig, hidden, residual, st, attn: bool,
         mix_step = (
             mamba2_mixer_step if cfg.ssm_layer == "mamba2" else mamba1_mixer_step
         )
-        hidden, st = mix_step(bp["mixer"], cfg, normed, *st)
+        hidden, st = mix_step(
+            bp["mixer"], cfg, normed, *st, state_mask=state_mask
+        )
     if cfg.d_intermediate > 0:
         normed, residual = add_rms_norm(
             hidden, residual, bp["norm2"]["weight"], cfg.norm_eps,
@@ -1087,7 +1090,8 @@ def _block_step(bp, cfg: ModelConfig, hidden, residual, st, attn: bool,
 
 
 def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
-            write_mask: jax.Array | None = None, pipeline=None):
+            write_mask: jax.Array | None = None, pipeline=None,
+            state_mask: jax.Array | None = None):
     """One decode step.  token (b,) int32 -> (logits (b, V), new state).
 
     ``write_mask`` (b,) bool (hybrid stacks only) marks rows whose paged
@@ -1096,6 +1100,19 @@ def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
     keeps dead/empty/prefilling slots from touching live pages while
     still computing the whole batch in one trace.  ``None`` (generate's
     decode loop) writes every row.
+
+    ``state_mask`` (b,) bool marks rows whose conv + SSM carry
+    (``state["blocks"]``) advances; the others get theirs back bit for
+    bit, out of the update's own write (ops/ssd.ssd_state_update), so no
+    caller has to select over the stacked state afterwards.  The serving
+    tick passes ``~prefilling``: a slot parked mid-chunked-prefill holds a
+    real scan carry that the next chunk resumes from.  It is NOT ``live``
+    like ``write_mask``: an empty or finished slot's rows are garbage the
+    next insert overwrites and may advance freely, while a stray KV write
+    could land in a page that now belongs to someone else.  The held
+    rows' logits mean nothing (the caller keeps its own).  ``None``
+    (generate's decode loop, the drafter) advances every row, with the
+    values a mask of all True gives.
 
     ``pipeline`` (pure-SSM stacks only) is ``(mesh, n_micro)``: the
     layer scan runs as a GPipe-microbatched schedule over the 3-D
@@ -1115,7 +1132,8 @@ def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
     def mbody(carry, xs):
         h, rs = carry
         bp, st = xs
-        h, rs, st = _block_step(bp, cfg, h, rs, st, False)
+        h, rs, st = _block_step(bp, cfg, h, rs, st, False,
+                                state_mask=state_mask)
         return (h, rs), st
 
     if cfg.attn_layer_idx:
@@ -1185,6 +1203,7 @@ def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
                 hidden, residual, st = _block_step(
                     bp, cfg, hidden, residual, st, attn,
                     attn_ctx=attn_ctx if attn else None,
+                    state_mask=state_mask,
                 )
             if attn:
                 new_a.append(st)
@@ -1207,20 +1226,47 @@ def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
 
             mesh, n_micro = pipeline
 
+            # the mask is one more per-lane leaf of the activations (None:
+            # no leaf), so each microbatch's rows travel the stages with it
             def pbody(act, bp, st):
-                h, rs = act
-                h, rs, st = _block_step(bp, cfg, h, rs, st, False)
-                return (h, rs), st
+                h, rs, mask = act
+                h, rs, st = _block_step(bp, cfg, h, rs, st, False,
+                                        state_mask=mask)
+                return (h, rs, mask), st
 
-            (hidden, residual), new_blocks = pipelined_decode_layers(
+            (hidden, residual, _), new_blocks = pipelined_decode_layers(
                 pbody, params["blocks"], state["blocks"],
-                (hidden, residual), mesh, n_micro=n_micro,
+                (hidden, residual, state_mask), mesh, n_micro=n_micro,
             )
         else:
+            # the stacked state rides the layer loop's CARRY: layer i's
+            # rows are sliced out, stepped and written back at i, so a
+            # caller that carries and donates the pool (the serving tick)
+            # updates one buffer in place from entry to exit, where scanned
+            # inputs and outputs would be two pool-sized buffers
+            def cbody(carry, xs):
+                h, rs, blocks = carry
+                bp, i = xs
+                st = jax.tree.map(
+                    lambda s: jax.lax.dynamic_index_in_dim(
+                        s, i, 0, keepdims=False
+                    ),
+                    blocks,
+                )
+                h, rs, st = _block_step(bp, cfg, h, rs, st, False,
+                                        state_mask=state_mask)
+                blocks = jax.tree.map(
+                    lambda s, new: jax.lax.dynamic_update_index_in_dim(
+                        s, new, i, 0
+                    ),
+                    blocks, st,
+                )
+                return (h, rs, blocks), None
+
             with jax.named_scope(scopes.LAYERS):
-                (hidden, residual), new_blocks = jax.lax.scan(
-                    mbody, (hidden, residual),
-                    (params["blocks"], state["blocks"]),
+                (hidden, residual, new_blocks), _ = jax.lax.scan(
+                    cbody, (hidden, residual, state["blocks"]),
+                    (params["blocks"], jnp.arange(cfg.n_layer)),
                 )
         new_state = {"blocks": new_blocks}
 

@@ -192,8 +192,10 @@ def mamba1_mixer_step(
     u_t: jax.Array,
     conv_state: jax.Array,
     ssm_state: jax.Array,
+    state_mask: jax.Array | None = None,
 ):
-    """O(1) single-token decode step for Mamba-1."""
+    """O(1) single-token decode step for Mamba-1.  ``state_mask`` as in
+    ``mamba2_mixer_step``: False rows keep both states bit for bit."""
     di = cfg.d_inner
     ds = cfg.effective_d_state
     dtr = cfg.effective_dt_rank
@@ -204,7 +206,7 @@ def mamba1_mixer_step(
 
     x, conv_state = causal_conv1d_update(
         x, conv_state, params["conv"]["kernel"], params["conv"].get("bias"),
-        activation="silu",
+        activation="silu", state_mask=state_mask,
     )
     x_db = linear(params["x_proj"], x, compute_dtype)
     dt = x_db[..., :dtr]
@@ -220,6 +222,7 @@ def mamba1_mixer_step(
         ssm_state, x, dt, A, B, C,
         D=params["D"], z_t=z,
         dt_bias=params["dt_proj"]["bias"], dt_softplus=True,
+        state_mask=state_mask,
     )
     out = linear(params["out_proj"], y, compute_dtype)
     return out, (conv_state, ssm_state)

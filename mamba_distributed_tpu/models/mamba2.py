@@ -236,12 +236,15 @@ def mamba2_mixer_step(
     u_t: jax.Array,
     conv_state: jax.Array,
     ssm_state: jax.Array,
+    state_mask: jax.Array | None = None,
 ):
     """O(1) single-token decode step.
 
     u_t (b, d_model) -> (y_t (b, d_model), (conv_state, ssm_state)).
     Numerically matches the full-sequence path token-for-token (the decode
-    parity test pins this).
+    parity test pins this).  ``state_mask`` (b,) bool: rows where it is
+    False keep both states bit for bit (``lm_step`` says who and why);
+    ``None`` advances every row.
     """
     di, ds, g, nh, _, conv_dim = _dims(cfg)
     b, _ = u_t.shape
@@ -253,7 +256,7 @@ def mamba2_mixer_step(
 
     xBC, conv_state = causal_conv1d_update(
         xBC, conv_state, params["conv"]["kernel"], params["conv"].get("bias"),
-        activation="silu",
+        activation="silu", state_mask=state_mask,
     )
     with jax.named_scope(scopes.SSD):
         x, B, C = _split_xbc(xBC, cfg)
@@ -267,7 +270,7 @@ def mamba2_mixer_step(
 
     y, ssm_state = ssd_state_update(
         ssm_state, x, dt.astype(jnp.float32), A, B, C, D,
-        dt_bias=params["dt_bias"], dt_softplus=True,
+        dt_bias=params["dt_bias"], dt_softplus=True, state_mask=state_mask,
     )
     with jax.named_scope(scopes.GATE_NORM):
         y = y.reshape(b, di)

@@ -32,7 +32,7 @@ ATTN_KERNEL = "attn_kernel"  # the attention itself, Pallas or lax
 KV_WRITE = "kv_write"  # the lax scatter of K/V into the page pool
 ATTN_OUT = "attn_out"
 LM_HEAD_LOSS = "lm_head_loss"  # final norm, head, loss (blocked: fwd and bwd)
-POOL_SELECT = "pool_select"  # the tick's select over parked carries; insert/evict/stash
+POOL_SELECT = "pool_select"  # the tick's hold of parked slots' logits; insert/evict/stash
 SAMPLE = "sample"  # the tick's top-k and draw
 OPTIMIZER = "optimizer"  # gradient accumulation, clip, AdamW, apply
 

@@ -97,6 +97,7 @@ def causal_conv1d_update(
     weight: jax.Array,
     bias: jax.Array | None = None,
     activation: str | None = "silu",
+    state_mask: jax.Array | None = None,
 ):
     """O(1) single-token conv step for recurrent decode.
 
@@ -106,6 +107,8 @@ def causal_conv1d_update(
       x_t: (batch, dim) current-token input.
       conv_state: (batch, width-1, dim) previous inputs (oldest first).
       weight: (dim, width); bias: optional (dim,).
+      state_mask: optional (batch,) bool; rows where it is False get their
+        ``conv_state`` back unchanged (``None`` advances every row).
 
     Returns:
       (y_t of shape (batch, dim), new_conv_state).
@@ -122,4 +125,6 @@ def causal_conv1d_update(
     elif activation is not None:
         raise ValueError(f"unsupported activation: {activation}")
     new_state = window[:, 1:, :]
+    if state_mask is not None:
+        new_state = jnp.where(state_mask[:, None, None], new_state, conv_state)
     return y.astype(x_t.dtype), new_state

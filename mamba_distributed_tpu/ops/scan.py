@@ -209,13 +209,20 @@ def selective_state_update(
     z_t: jax.Array | None = None,
     dt_bias: jax.Array | None = None,
     dt_softplus: bool = True,
+    state_mask: jax.Array | None = None,
 ):
     """O(1)-per-token recurrent step for decode (Mamba-1 shapes).
 
     Equivalent of ``mamba_ssm/ops/triton/selective_state_update.py``.
 
     ssm_state (b, d, n); x_t/dt_t (b, d); A (d, n); B_t/C_t (b, n).
-    Returns (y_t (b, d), new_state).
+    Returns (y_t (b, d), new_state).  ``state_mask`` (b,) bool: rows where
+    it is False get their state back unchanged (``None`` advances every
+    row), as in ``ops/ssd.ssd_state_update``.  The other rows' values are
+    the unmasked call's op for op; compiled, the CPU backend contracts one
+    of this update's two elementwise products into the add and the select
+    can change which, so there they may differ in the last bit
+    (tests/test_prefill.py::test_lm_step_state_mask_holds_rows).
     """
     hf = ssm_state.astype(jnp.float32)
     xf = x_t.astype(jnp.float32)
@@ -227,6 +234,8 @@ def selective_state_update(
     dA = jnp.exp(dtf[:, :, None] * A.astype(jnp.float32)[None])
     dBu = (dtf * xf)[:, :, None] * B_t.astype(jnp.float32)[:, None, :]
     h = hf * dA + dBu
+    if state_mask is not None:
+        h = jnp.where(state_mask[:, None, None], h, hf)
     y = jnp.einsum("bdn,bn->bd", h, C_t.astype(jnp.float32))
     if D is not None:
         y = y + xf * D.astype(jnp.float32)[None]

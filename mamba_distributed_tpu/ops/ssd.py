@@ -382,12 +382,18 @@ def ssd_state_update(
     D: jax.Array | None = None,
     dt_bias: jax.Array | None = None,
     dt_softplus: bool = True,
+    state_mask: jax.Array | None = None,
 ):
     """O(1)-per-token recurrent step for decode (Mamba-2 shapes).
 
     Equivalent of ``selective_state_update`` applied to the multi-head SSD
     state.  ssm_state (b, h, p, n); x_t (b, h, p); dt_t (b, h);
     B_t/C_t (b, g, n).  Returns (y_t (b, h, p), new_state).
+
+    ``state_mask`` (b,) bool: rows where it is False get their state back
+    unchanged, from the expression that writes the others' new one (their
+    ``y_t`` is then read from the old state and means nothing).  ``None``
+    advances every row.
     """
     b, h, p, n = ssm_state.shape
     sf = ssm_state.astype(jnp.float32)
@@ -401,6 +407,8 @@ def ssd_state_update(
     Ch = _expand_groups(C_t[:, None], h)[:, 0].astype(jnp.float32)
     decay = jnp.exp(dtf * A.astype(jnp.float32)[None])  # (b, h)
     s = sf * decay[:, :, None, None] + jnp.einsum("bhp,bhn,bh->bhpn", xf, Bh, dtf)
+    if state_mask is not None:
+        s = jnp.where(state_mask[:, None, None, None], s, sf)
     y = jnp.einsum("bhpn,bhn->bhp", s, Ch)
     if D is not None:
         Df = D.astype(jnp.float32)

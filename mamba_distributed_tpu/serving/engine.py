@@ -163,6 +163,13 @@ def _tick(params: dict, pool: dict, tbl=None, lengths=None, *,
     lengths advance from ``emitted`` (bit-equal: both count live
     sub-steps), so nothing metadata-shaped needs fetching.
 
+    The conv + SSM carry of a slot parked mid-chunked-prefill survives
+    the tick through ``lm_step``'s ``state_mask`` (``~prefilling``): the
+    update returns those rows unchanged, and the pure-SSM layer loop
+    carries the stacked pool, so the donated pool is one buffer updated
+    in place from entry to exit — no select and no copy over the
+    (L, S, ...) leaves.  Only the (S, V) logits are selected here.
+
     Mirrors generate()'s decode loop exactly: sample from the carried
     logits with key fold_in(key, step), then lm_step.  Slots that hit
     their eos keep feeding it forward (same as generate's eos_id path);
@@ -240,33 +247,27 @@ def _tick(params: dict, pool: dict, tbl=None, lengths=None, *,
             )(keys, vals, meta["temperature"])
             tok = jnp.take_along_axis(idx, choice[:, None], axis=1)[:, 0]
             tok = jnp.where(meta["done"] & has_eos, meta["eos_id"], tok)
+        # empty/done slots may compute garbage freely (masked, overwritten
+        # by the next insert), but a prefilling slot's rows hold a REAL
+        # carry: lm_step's state_mask keeps them inside the update itself,
+        # so nothing pool-sized is selected or copied here.  Only the
+        # conv+SSM "blocks" subtree has a per-slot axis; the attention
+        # page pool is protected by write_mask instead.
+        advance = ~meta["prefilling"]
         if hybrid:
             state_in = {**pool["state"], "attn_meta": (tbl, lengths)}
             logits, state = lm_step(params, cfg, state_in, tok,
-                                    write_mask=live)
+                                    write_mask=live, state_mask=advance)
             lengths = state["attn_meta"][1]
             state = {k: v for k, v in state.items() if k != "attn_meta"}
         else:
             logits, state = lm_step(
                 params, cfg, pool["state"], tok,
                 pipeline=((mesh, n_micro) if n_micro else None),
+                state_mask=advance,
             )
-        # empty/done slots may compute garbage freely (masked, overwritten
-        # by the next insert), but a prefilling slot's rows hold a REAL
-        # carry — keep them (select per (L, S, ...) leaf on the S axis).
-        # Only the conv+SSM "blocks" subtree has a per-slot axis; the
-        # attention page pool is protected by write_mask instead.
-        hold = meta["prefilling"]
         with jax.named_scope(scopes.POOL_SELECT):
-            blocks = jax.tree.map(
-                lambda new, old: jnp.where(
-                    hold.reshape((1, -1) + (1,) * (new.ndim - 2)), old, new
-                ),
-                state["blocks"],
-                pool["state"]["blocks"],
-            )
-            state = {**state, "blocks": blocks}
-            logits = jnp.where(hold[:, None], pool["logits"], logits)
+            logits = jnp.where(advance[:, None], logits, pool["logits"])
         step = meta["step"] + live.astype(jnp.int32)
         done = meta["done"] | (
             live & ((has_eos & (tok == meta["eos_id"])) | (step >= meta["max_new"]))

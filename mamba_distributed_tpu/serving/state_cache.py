@@ -49,7 +49,9 @@ Chunked prefill (serving/prefill.py) adds partial-prefill residency: a
 half-prefilled request occupies its slot with its scan carry —
 ``stash_prefill`` parks the carry + request meta with
 ``prefilling=True`` (the decode tick treats the slot as not-live and
-must NOT overwrite its state rows), ``read_state`` slices the carry
+must NOT overwrite its state rows: it passes ``~prefilling`` as
+``lm_step``'s ``state_mask``, and the update returns those rows
+unchanged), ``read_state`` slices the carry
 back out to resume at the next budget grant, and ``finish_prefill``
 writes the final state + logits and flips ``prefilling`` off, making
 the slot decodable.
@@ -611,7 +613,8 @@ def stash_prefill(
     slot (``active=True``) with its chunk-scan carry and its sampling
     meta, but ``prefilling=True`` keeps it out of the decode tick — the
     tick masks it from sampling AND from state writes (a tick's
-    ``lm_step`` over the whole pool must not clobber the carry).  The
+    ``lm_step`` over the whole pool must not clobber the carry:
+    ``state_mask``).  The
     slot's stale logits are left in place (masked; ``finish_prefill``
     writes the real ones).  Idempotent — re-stashing after more chunks
     just overwrites the carry."""

@@ -186,6 +186,31 @@ def test_pipelined_decode_layers_unit_parity():
         lm_step(params, cfg, state, tok, pipeline=(mesh, 3))
 
 
+def test_pipelined_decode_holds_masked_rows():
+    """``lm_step(state_mask=)`` under the GPipe clock: the mask travels
+    the stages with its lanes, so held rows keep their carries bit for
+    bit and the rest get what the same clock computes unmasked."""
+    cfg = tiny_cfg()
+    params = init_lm_params(jax.random.PRNGKey(0), cfg)
+    state = init_lm_state(cfg, 4)
+    tok = jnp.asarray([3, 9, 27, 41], jnp.int32)
+    _, state = lm_step(params, cfg, state, tok)  # a non-zero carry
+    mask = np.array([True, False, False, True])
+    pipeline = (serving_mesh(1, model_shards=1, stage_shards=2), 2)
+    ref_logits, ref_state = lm_step(params, cfg, state, tok,
+                                    pipeline=pipeline)
+    logits, new_state = lm_step(params, cfg, state, tok, pipeline=pipeline,
+                                state_mask=jnp.asarray(mask))
+    assert np.array_equal(np.asarray(logits)[mask],
+                          np.asarray(ref_logits)[mask])
+    for old, new, want in zip(*map(jax.tree.leaves,
+                                   (state, new_state, ref_state))):
+        old, new, want = map(np.asarray, (old, new, want))
+        assert np.array_equal(new[:, ~mask], old[:, ~mask])
+        assert np.array_equal(new[:, mask], want[:, mask])
+        assert not np.array_equal(want[:, ~mask], old[:, ~mask])
+
+
 # --------------------------------------------------------------- parity
 
 

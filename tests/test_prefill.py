@@ -347,6 +347,145 @@ def test_stash_survives_tick():
     assert np.asarray(pool["meta"]["active"]).tolist() == [True, True]
 
 
+_HYBRID = dict(attn_num_heads=4, attn_num_kv_heads=2, kv_page_tokens=8,
+               kv_slot_tokens=64)
+
+
+@pytest.mark.parametrize("layer,extra", [
+    ("mamba2", {}),
+    ("mamba1", {}),
+    ("mamba2", dict(n_layer=6, attn_layer_idx=(1, 4), **_HYBRID)),  # periodic
+    ("mamba1", dict(n_layer=6, attn_layer_idx=(1, 4), **_HYBRID)),
+    ("mamba2", dict(n_layer=4, attn_layer_idx=(0, 3), **_HYBRID)),  # unrolled
+], ids=["mamba2", "mamba1", "mamba2-hybrid", "mamba1-hybrid",
+        "mamba2-hybrid-aperiodic"])
+def test_lm_step_state_mask_holds_rows(layer, extra):
+    """``lm_step(state_mask=)``: a held row's conv + SSM carry comes back
+    bit-equal to the input; every other row's carry and logits are the
+    unmasked call's, bit for bit — on each layer-loop path.
+
+    Mamba-1's update adds two elementwise products, and the CPU backend
+    contracts one of them into the add — WHICH one depends on the fusion
+    around them, so its compiled masked and unmasked programs differ in
+    the last bit.  Its compiled run is held to that, and the bit-for-bit
+    claim is checked op by op (``disable_jit``), where nothing contracts.
+    Mamba-2's single elementwise product leaves no choice: exact compiled."""
+    from mamba_distributed_tpu.models.lm import init_lm_state, lm_step
+
+    cfg = dataclasses.replace(tiny_cfg(layer), **extra)
+    params = init_lm_params(jax.random.PRNGKey(0), cfg)
+    state = init_lm_state(cfg, 3, 16)
+    for i in range(2):  # a non-trivial carry in every row
+        _, state = lm_step(params, cfg, state,
+                           jnp.asarray([3 + i, 9 + i, 27 + i], jnp.int32))
+    tok = jnp.asarray([5, 11, 41], jnp.int32)
+    mask = np.array([True, False, True])
+    leaves = lambda st: [np.asarray(x) for x in jax.tree.leaves(st["blocks"])]
+
+    def check(same):
+        ref_logits, ref = lm_step(params, cfg, state, tok)
+        logits, got = lm_step(params, cfg, state, tok,
+                              state_mask=jnp.asarray(mask))
+        same(np.asarray(logits)[mask], np.asarray(ref_logits)[mask])
+        for old, new, want in zip(leaves(state), leaves(got), leaves(ref)):
+            assert new.dtype == old.dtype and new.shape == old.shape
+            np.testing.assert_array_equal(new[:, ~mask], old[:, ~mask])
+            same(new[:, mask], want[:, mask])
+            assert not np.array_equal(want[:, ~mask], old[:, ~mask])
+        # all True is None: the values generate()'s decode loop computes
+        _, full = lm_step(params, cfg, state, tok,
+                          state_mask=jnp.ones((3,), bool))
+        for new, want in zip(leaves(full), leaves(ref)):
+            same(new, want)
+
+    if layer == "mamba2":
+        check(np.testing.assert_array_equal)
+    else:
+        check(lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5,
+                                                      atol=1e-7))
+        with jax.disable_jit():
+            check(np.testing.assert_array_equal)
+
+
+def test_parked_slot_resumes_to_solo_stream():
+    """A slot parked mid-prefill over several ticks — beside a live
+    neighbour and a slot that finishes and goes dead inside those ticks
+    — resumes to the stream of a solo generate() that was never parked,
+    and so do the neighbours."""
+    cfg = tiny_cfg()  # budget 16 == one chunk a step
+    params = init_lm_params(jax.random.PRNGKey(0), cfg)
+    prompts = {"live": rand_prompt(6, seed=2), "dead": rand_prompt(4, seed=3),
+               "long": rand_prompt(75, seed=4)}
+    budgets = {"live": 14, "dead": 2, "long": 6}
+    keys = {n: jax.random.PRNGKey(50 + i) for i, n in enumerate(prompts)}
+    eng = ServingEngine(params, cfg, capacity=3, tokens_per_tick=3)
+    ids = {n: eng.submit(GenerationRequest(
+        prompt_ids=prompts[n], max_new_tokens=budgets[n], key=keys[n]))
+        for n in ("live", "dead", "long")}
+    parked_ticks = 0
+    while eng.pending:
+        eng.step()
+        long_ = [t for t in eng._slots.values()
+                 if t.request_id == ids["long"]]
+        if long_ and long_[0].status is RequestStatus.PREFILL:
+            # a tick ran over the parked carry, with the live slot
+            # decoding and the dead one done (then evicted: empty)
+            assert 0 < long_[0].chunks_done < long_[0].plan.n_chunks
+            assert ids["live"] in {t.request_id for t in eng._slots.values()}
+            parked_ticks += 1
+    assert parked_ticks >= 3
+    assert len(eng.results[ids["dead"]].new_tokens) == 2
+    for name in prompts:
+        got = eng.results[ids[name]].new_tokens.tolist()
+        want = solo(params, cfg, prompts[name], keys[name],
+                    max_new_tokens=budgets[name])
+        assert got == want, f"request {name} diverged: {got} vs {want}"
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in its params."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk_eqns(sub)
+
+
+def test_tick_updates_the_pool_in_place():
+    """Structure of the pure-SSM tick, by count: no ``select_n`` produces
+    a stacked (L, S, ...) pool leaf, and the layer loop holds the stacked
+    blocks in its carry, never among its scanned inputs or outputs (either
+    is a second pool-sized buffer on the device)."""
+    from mamba_distributed_tpu.serving import engine as engine_mod
+
+    L, S, steps = 3, 5, 2
+    cfg = dataclasses.replace(tiny_cfg(), n_layer=L)
+    dparams = cast_decode_params(
+        init_lm_params(jax.random.PRNGKey(0), cfg), cfg=cfg)
+    pool = init_pool(cfg, capacity=S)
+    leaf_shapes = {x.shape for x in jax.tree.leaves(pool["state"]["blocks"])}
+    assert len(leaf_shapes) == 2 and all(s[:2] == (L, S) for s in leaf_shapes)
+    jaxpr = jax.make_jaxpr(
+        lambda p, q: engine_mod._tick(p, q, cfg=cfg, k_max=5, steps=steps)
+    )(dparams, pool)
+    shape = lambda v: getattr(v.aval, "shape", None)
+    layer_loops = 0
+    for eqn in _walk_eqns(jaxpr.jaxpr):
+        if eqn.primitive.name == "select_n":
+            assert shape(eqn.outvars[0]) not in leaf_shapes, eqn
+        if eqn.primitive.name == "scan" and eqn.params["length"] == L:
+            layer_loops += 1
+            nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+            carry = {shape(v) for v in eqn.invars[nc:nc + nk]}
+            scanned = ({shape(v) for v in eqn.invars[nc + nk:]}
+                       | {shape(v) for v in eqn.outvars[nk:]})
+            assert leaf_shapes <= carry
+            assert not leaf_shapes & scanned
+    assert layer_loops == 1
+
+
 def test_failed_chunk_requeues_and_frees_slot(monkeypatch):
     """A chunk step that raises mid-prefill must free the slot, evict the
     stash, and requeue the request from chunk 0 (same contract as the
