@@ -34,10 +34,7 @@ if CHECKOUT not in sys.path:
 def train_controls(cell, seed, precision, faults, devices):
     """The control and the faults of a training cell, each compared with the
     float32 reference as the program is."""
-    import jax
-
-    from benchmark import harness
-    from benchmark.reference import init as ref_init
+    from benchmark import harness, reference
     from benchmark.reference import train as ref_train
     from benchmark.traffic import tokens as traffic_tokens
 
@@ -50,9 +47,10 @@ def train_controls(cell, seed, precision, faults, devices):
         rows *= int(argv[argv.index("--mesh-data") + 1])
     batches = traffic_tokens.step_batches(shard, int(w["warm_steps"]),
                                           t["grad_accum_steps"], rows, t["seq_len"])
-    params0 = jax.jit(lambda k: ref_init.init_params(k, m))(ref_init.seed_key(seed))
+    mod, dtype = reference.of(cell.config), reference.params_dtype(cell.config)
+    params0 = ref_train.initial_params(mod, seed, m, dtype)
     rb = int(w.get("reference_row_block", 4))
-    ref = ref_train.first_steps(params0, m, t, batches, row_block=rb,
+    ref = ref_train.first_steps(mod, params0, m, t, batches, row_block=rb,
                                 devices=devices)
     n = rows * t["grad_accum_steps"]
     out = {}
@@ -67,7 +65,7 @@ def train_controls(cell, seed, precision, faults, devices):
         elif f == "frozen":
             jobs["fault_state_unchanged"] = dict(frozen=True)
     for name, kw in jobs.items():
-        got = ref_train.first_steps(params0, m, t, batches, row_block=rb,
+        got = ref_train.first_steps(mod, params0, m, t, batches, row_block=rb,
                                     devices=devices, **kw)
         vals = ref_train.compare(got, ref)
         vals.pop("_where")
@@ -125,11 +123,15 @@ def main() -> int:
     p.add_argument("--manifest", default=os.path.join(CHECKOUT, "BENCHMARK.json"),
                    help="a manifest that lists the cell: a candidate cell is "
                         "read here before BENCHMARK.json takes it")
+    p.add_argument("--data-root", default=None,
+                   help="where that manifest's configs/ and workloads/ are "
+                        "(default: benchmark/)")
     args = p.parse_args()
     from benchmark import harness
 
-    cell, devices, kind = harness.open_cell(args.workload, args.manifest,
-                                            harness.BENCH_DIR, require_tpu=True)
+    cell, devices, kind = harness.open_cell(
+        args.workload, args.manifest, args.data_root or harness.BENCH_DIR,
+        require_tpu=True)
     faults = [f for f in args.faults.split(",") if f]
     for seed in (int(s) for s in args.seeds.split(",")):
         rec = read_seed(cell, kind, devices, seed, args.seconds, args.control,

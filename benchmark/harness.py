@@ -123,6 +123,14 @@ def memory_peak_bytes(devices) -> int:
     return int(max(peaks))
 
 
+def print_memory(devices, when: str) -> None:
+    """The peak so far and what is held now, on the fullest device: a
+    process's peak never falls, so where it rose is read from the lines."""
+    held = max((d.memory_stats() or {}).get("bytes_in_use", 0) for d in devices)
+    print(f"memory with {when}: peak {memory_peak_bytes(devices)} bytes, "
+          f"in use {int(held)}", flush=True)
+
+
 def release():
     """Drop compiled programs and whatever the caller no longer refers to."""
     import gc
@@ -188,6 +196,17 @@ class _Span:
 
 
 # ------------------------------------------------------------ compiles
+
+
+def trace_counts(modules) -> dict:
+    """{"<module>.<program>": traces so far} over the ``TRACE_COUNTS`` of the
+    program modules named (dotted paths)."""
+    out = {}
+    for name in modules:
+        mod = importlib.import_module(name)
+        for k, v in mod.TRACE_COUNTS.items():
+            out[f"{name.rsplit('.', 1)[-1]}.{k}"] = v
+    return out
 
 
 class CompileWatch:

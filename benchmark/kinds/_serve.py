@@ -8,8 +8,9 @@ the ``step()`` that produced it returns.
 ``correct``: once the window has closed, every request drained,
 ``memory_peak_bytes`` read and the engine freed, a sample of the finished
 *greedy* requests, drawn from ``--seed`` with the longest in it, is run once
-through ``benchmark/reference`` (float32, the whole sequence at once, no cache):
-``logit_gap`` is the widest gap by which a served token's reference logit lies
+through the configuration's reference (``benchmark.reference.of``: float32,
+the whole sequence at once, no cache, each layer's weights drawn from the
+seed as the walk reaches it): ``logit_gap`` is the widest gap by which a served token's reference logit lies
 below the reference's best at its position.  ``incomplete`` counts requests
 that never finished or returned another number of tokens than asked.
 """
@@ -24,10 +25,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark import flops, harness
-from benchmark.reference import init as ref_init
-from benchmark.reference import model as ref_model
-from benchmark.reference.train import freeze
+from benchmark import harness, reference
+
+# the program modules whose TRACE_COUNTS a serving window watches, where the
+# configuration's file names no others (``trace_count_modules``)
+TRACE_COUNT_MODULES = ("mamba_distributed_tpu.serving.engine",
+                       "mamba_distributed_tpu.serving.prefill",
+                       "mamba_distributed_tpu.ops.pallas.attention_kernels")
 
 
 @dataclasses.dataclass
@@ -44,16 +48,9 @@ class Sent:
     done: bool = False
 
 
-def program_counters():
-    from mamba_distributed_tpu.ops.pallas import attention_kernels
-    from mamba_distributed_tpu.serving import engine as engine_mod
-    from mamba_distributed_tpu.serving import prefill as prefill_mod
-
-    out = {}
-    for mod in (engine_mod, prefill_mod, attention_kernels):
-        for k, v in mod.TRACE_COUNTS.items():
-            out[f"{mod.__name__.rsplit('.', 1)[-1]}.{k}"] = v
-    return out
+def program_counters(cell):
+    return harness.trace_counts(
+        cell.config.get("trace_count_modules", TRACE_COUNT_MODULES))
 
 
 def build_engine(cell, seed, devices, spans):
@@ -68,7 +65,10 @@ def build_engine(cell, seed, devices, spans):
         cfg = dc.replace(cfg, **cell.config["serving"])
     harness.check_config(m, cfg, cell.name)
     harness.check_config(cell.config.get("serving", {}), cfg, cell.name)
-    params = jax.jit(lambda k: ref_init.init_params(k, m))(ref_init.seed_key(seed))
+    ref, dtype = reference.of(cell.config), reference.params_dtype(cell.config)
+    params = jax.jit(lambda k: ref.init_params(k, m, dtype))(
+        reference.seed_key(seed))
+    harness.print_memory(devices, "the weights made")
     engine = ServingEngine(params, cfg, tracer=spans, retain_results=False,
                            **cell.workload["engine"])
     del params  # the engine keeps its own decode-layout copy
@@ -135,15 +135,6 @@ def drain(engine, by_id, seconds=60.0):
 # ------------------------------------------------------------ correct
 
 
-@functools.partial(jax.jit, static_argnames=("m_items", "precision"))
-def served_logits(params, ids, pos, m_items, precision):
-    """ids (1, t), pos (k,) -> reference logits (k, V) at ``pos``."""
-    m = dict(m_items)
-    h = ref_model.hidden_states(params, m, ids, precision)
-    h = ref_model.rms_norm(h[0, pos], params["norm_f"]["weight"], m["norm_eps"])
-    return ref_model.mm(h, params["embedding"].T, precision)
-
-
 def sample_for_check(sent: list, seed: int, n: int) -> list:
     """Finished greedy requests: the longest, then others drawn from the
     seed."""
@@ -158,12 +149,13 @@ def sample_for_check(sent: list, seed: int, n: int) -> list:
     return [longest] + pick
 
 
-def logit_gaps(sample, m, seed, control=None, pad_to=256, positions=64):
+def logit_gaps(sample, config, seed, control=None, pad_to=256, positions=64):
     """(program's widest gap, control's widest gap or None, tokens judged).
     Sequences are padded to a multiple of ``pad_to`` and the served positions
     to a multiple of ``positions``, so that few shapes compile."""
-    params = jax.jit(lambda k: ref_init.init_params(k, m))(ref_init.seed_key(seed))
-    key = freeze(m)
+    logits = functools.partial(
+        reference.of(config).served_logits, reference.seed_key(seed),
+        config["model"], reference.params_dtype(config))
     worst, worst_control, judged = 0.0, (0.0 if control else None), 0
     for s in sample:
         seq = np.concatenate([s.prompt, np.asarray(s.tokens, np.int32)])
@@ -174,15 +166,15 @@ def logit_gaps(sample, m, seed, control=None, pad_to=256, positions=64):
         k = -(-n_out // positions) * positions
         pos = np.full((k,), len(s.prompt) - 1, np.int32)
         pos[:n_out] = len(s.prompt) - 1 + np.arange(n_out)
-        ref = np.asarray(served_logits(params, jnp.asarray(ids),
-                                       jnp.asarray(pos), key, "f32"))[:n_out]
+        ref = np.asarray(logits(jnp.asarray(ids), jnp.asarray(pos),
+                                precision="f32"))[:n_out]
         served = np.asarray(s.tokens)
         best = ref.max(axis=1)
         worst = max(worst, float((best - ref[np.arange(n_out), served]).max()))
         judged += n_out
         if control:
-            low = np.asarray(served_logits(params, jnp.asarray(ids),
-                                           jnp.asarray(pos), key, control))[:n_out]
+            low = np.asarray(logits(jnp.asarray(ids), jnp.asarray(pos),
+                                    precision=control))[:n_out]
             first = low.argmax(axis=1)
             worst_control = max(worst_control, float(
                 (best - ref[np.arange(n_out), first]).max()))
@@ -208,7 +200,7 @@ def finish(cell, seed, devices, engine_box, cfg, sent, spans, t0, t1, counters0,
     engine, so that it is freed here, before the reference runs."""
     engine = engine_box.pop()
     m, w = cell.config["model"], cell.workload
-    counters1 = program_counters()
+    counters1 = program_counters(cell)
     window_compiles = watch.report(
         t0, t1, {k: counters1[k] - counters0[k] for k in counters1})
     watch.close()
@@ -226,7 +218,7 @@ def finish(cell, seed, devices, engine_box, cfg, sent, spans, t0, t1, counters0,
     sample = sample_for_check([s for s in sent if s.times and s.times[-1] >= t0],
                               seed, int(w["check_requests"]))
     gap, control_gap, judged = logit_gaps(
-        sample, m, seed, control, int(w.get("check_pad_tokens", 256)),
+        sample, cell.config, seed, control, int(w.get("check_pad_tokens", 256)),
         int(w.get("check_pad_positions", 64)))
     finished = [s for s in sent if s.done]
     incomplete = sum(1 for s in sent
@@ -238,13 +230,15 @@ def finish(cell, seed, devices, engine_box, cfg, sent, spans, t0, t1, counters0,
     print(f"reference: {len(sample)} greedy requests, {judged} served tokens, "
           f"in {time.perf_counter() - t_ref:.1f} s; control {control}: "
           f"{control_gap}", flush=True)
+    harness.print_memory(devices, "the reference run")
 
     # model FLOPs of every prompt and output token the window processed
     ctx = lambda s: (len(s.prompt) + len(s.tokens)) / 2
     in_window = lambda s: sum(1 for x in s.times if t0 <= x < t1)
     model_flops = 0.0
+    forward_flops = reference.of(cell.config).forward_flops_per_token
     for s in sent:
-        f = flops.forward_flops_per_token(m, ctx(s))
+        f = forward_flops(m, ctx(s))
         prefilled = len(s.prompt) if s.times and t0 <= s.times[0] < t1 else 0
         model_flops += f * (prefilled + in_window(s))
     return {

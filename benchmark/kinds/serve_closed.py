@@ -34,7 +34,7 @@ def run(cell, seed, seconds, trace, devices, t_process, control=None):
     clients = int(w["mix"]["clients"])
     _serve.warm_up(engine, cell, vocab, population)
     tw = harness.TraceWindow.of(cell, trace)
-    counters0 = _serve.program_counters()
+    counters0 = _serve.program_counters(cell)
     watch = harness.CompileWatch()
 
     sent, by_id = [], {}
@@ -65,7 +65,7 @@ def run(cell, seed, seconds, trace, devices, t_process, control=None):
         if t0 is None:
             if finished and now - t_ramp >= ramp:
                 t0 = now  # these completions lie before the window
-                counters0 = _serve.program_counters()
+                counters0 = _serve.program_counters(cell)
         elif (finished and now - t0 >= seconds) or now - t0 >= seconds + 15.0:
             t1 = now  # these completions lie inside it
             break

@@ -34,7 +34,7 @@ def run(cell, seed, seconds, trace, devices, t_process, control=None):
     prepared = [_serve.make_request(s, vocab) for s in specs]
     _serve.warm_up(engine, cell, vocab, specs)
     tw = harness.TraceWindow.of(cell, trace)
-    counters0 = _serve.program_counters()
+    counters0 = _serve.program_counters(cell)
     watch = harness.CompileWatch()
 
     sent, by_id, lateness = [], {}, []

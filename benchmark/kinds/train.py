@@ -10,8 +10,9 @@ closes the window at the first boundary past ``--seconds``.
 
 ``correct`` compares those first steps (losses; the first clipped gradient
 read from Adam's first moment after one step; the parameters' change after
-``warm_steps``) with ``benchmark/reference`` on the same weights and rows,
-once the window has closed and the trainer is freed.
+``warm_steps``) with the configuration's reference (``benchmark.reference.of``)
+on the same weights and rows, once the window has closed and the trainer is
+freed.
 """
 
 from __future__ import annotations
@@ -21,10 +22,13 @@ import os
 import sys
 import time
 
-from benchmark import flops, harness
-from benchmark.reference import init as ref_init
+from benchmark import harness, reference
 from benchmark.reference import train as ref_train
 from benchmark.traffic import tokens as traffic_tokens
+
+# the program modules whose TRACE_COUNTS a training window watches, where the
+# configuration's file names no others (``trace_count_modules``)
+TRACE_COUNT_MODULES = ("mamba_distributed_tpu.training.train_step",)
 
 
 class WindowClosed(Exception):
@@ -41,6 +45,13 @@ def _find_mu(opt_state):
             if found is not None:
                 return found
     return None
+
+
+def init_of(cell):
+    """``key -> the configuration's weights``, in the dtype its file states."""
+    ref, dtype = reference.of(cell.config), reference.params_dtype(cell.config)
+    m = cell.config["model"]
+    return lambda k: ref.init_params(k, m, dtype)
 
 
 def build_trainer(cell, seed, data_dir, log_dir, devices):
@@ -62,18 +73,18 @@ def build_trainer(cell, seed, data_dir, log_dir, devices):
     harness.check_config(t, cfg, cell.name)
     trainer = Trainer(cfg, devices=devices)
     # the benchmark's weights, in the trainer's own layout and placement
-    want = jax.eval_shape(lambda k: ref_init.init_params(k, m), jax.random.PRNGKey(0))
+    init = init_of(cell)
+    want = jax.eval_shape(init, jax.random.PRNGKey(0))
     have = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                         trainer.params)
     if jax.tree.structure(want) != jax.tree.structure(have) or any(
             a.shape != b.shape or a.dtype != b.dtype for a, b in
             zip(jax.tree.leaves(want), jax.tree.leaves(have))):
         raise harness.Refused(f"{cell.name}: the program's parameter tree is "
-                              f"not the one benchmark/reference/init.py makes")
+                              f"not the one the configuration's reference makes")
     pshard = jax.tree.map(lambda a: a.sharding, trainer.params)
     trainer.params = None
-    trainer.params = jax.jit(lambda k: ref_init.init_params(k, m),
-                             out_shardings=pshard)(ref_init.seed_key(seed))
+    trainer.params = jax.jit(init, out_shardings=pshard)(reference.seed_key(seed))
     return trainer, cfg
 
 
@@ -88,16 +99,17 @@ def run(cell, seed, seconds, trace, devices, t_process):
     shards = traffic_tokens.write_shards(
         data_dir, seed, m["vocab_size"], int(w["train_shard_tokens"]),
         int(w["val_shard_tokens"]))
-    from mamba_distributed_tpu.training import train_step as train_step_mod
-
+    ref, init = reference.of(cell.config), init_of(cell)
+    counters = lambda: harness.trace_counts(
+        cell.config.get("trace_count_modules", TRACE_COUNT_MODULES))
     trainer, cfg = build_trainer(cell, seed, data_dir, log_dir, devices)
     rows = cfg.micro_batch_size * cfg.data_parallel_size
     accum = cfg.grad_accum_steps
     b1 = t["adam_b1"]
     grad_of_mu = jax.jit(lambda mu: ref_train.leaf_norms(
-        jax.tree.map(lambda x: x / (1 - b1), mu)))
-    delta_of = jax.jit(lambda p, k: ref_train.leaf_norms(jax.tree.map(
-        jnp.subtract, p, ref_init.init_params(k, m))))
+        jax.tree.map(lambda x: x / (1 - b1), mu), ref.STACKED))
+    delta_of = jax.jit(lambda p, k: ref_train.leaf_norms(
+        jax.tree.map(jnp.subtract, p, init(k)), ref.STACKED))
     tw = harness.TraceWindow.of(cell, trace)
     st = {"bounds": [], "grad": None, "delta": None, "t0": None, "t1": None,
           "traces0": None}
@@ -110,8 +122,8 @@ def run(cell, seed, seconds, trace, devices, t_process):
             st["grad"] = grad_of_mu(_find_mu(trainer.opt_state))
         if k == warm:
             st["delta"] = jax.block_until_ready(
-                delta_of(trainer.params, ref_init.seed_key(seed)))
-            st["traces0"] = dict(train_step_mod.TRACE_COUNTS)
+                delta_of(trainer.params, reference.seed_key(seed)))
+            st["traces0"] = counters()
             st["t0"] = time.perf_counter()
             return
         if k > warm:
@@ -138,9 +150,9 @@ def run(cell, seed, seconds, trace, devices, t_process):
     steps = len(st["bounds"])
     print(f"window: {steps} steps of {cfg.total_batch_size} tokens in "
           f"{t1 - t0:.3f} s", flush=True)
+    traces1 = counters()
     window_compiles = watch.report(
-        t0, t1, {k: train_step_mod.TRACE_COUNTS[k] - v
-                 for k, v in st["traces0"].items()})
+        t0, t1, {k: traces1[k] - v for k, v in st["traces0"].items()})
     watch.close()
     peak = harness.memory_peak_bytes(devices)
     print(f"memory_peak_bytes {peak} (as the backend reports it)", flush=True)
@@ -157,19 +169,20 @@ def run(cell, seed, seconds, trace, devices, t_process):
     harness.release()
 
     t_ref = time.perf_counter()
-    params0 = jax.jit(lambda k: ref_init.init_params(k, m))(ref_init.seed_key(seed))
+    params0 = ref_train.initial_params(
+        ref, seed, m, reference.params_dtype(cell.config))
     batches = traffic_tokens.step_batches(shards["train"], warm, accum, rows,
                                           t["seq_len"])
-    ref = ref_train.first_steps(params0, m, t, batches,
-                                row_block=int(w.get("reference_row_block", 4)),
-                                devices=devices)
-    values = ref_train.compare(prog, ref)
+    steps_ref = ref_train.first_steps(
+        ref, params0, m, t, batches,
+        row_block=int(w.get("reference_row_block", 4)), devices=devices)
+    values = ref_train.compare(prog, steps_ref)
     where = values.pop("_where")
     values["window_compiles"] = float(window_compiles)
     ok, compared = harness.judge(values, w["limits"])
     print(f"reference: {warm} steps in {time.perf_counter() - t_ref:.1f} s; "
-          f"losses program {prog['losses']} reference {ref['losses']}; "
-          f"first grad norm reference {ref['grad_norm']:.4f}", flush=True)
+          f"losses program {prog['losses']} reference {steps_ref['losses']}; "
+          f"first grad norm reference {steps_ref['grad_norm']:.4f}", flush=True)
 
     tokens = steps * cfg.total_batch_size
     return {
@@ -180,5 +193,5 @@ def run(cell, seed, seconds, trace, devices, t_process):
         "memory_peak_bytes": peak, "spans": spans, "trace_window": tw,
         "window": (t0, t1), "tokens": tokens, "chips": len(devices),
         "device_kind": devices[0].device_kind, "platform": devices[0].platform,
-        "model_flops": tokens * flops.train_flops_per_token(m, t["seq_len"]),
+        "model_flops": tokens * ref.train_flops_per_token(m, t["seq_len"]),
     }

@@ -6,7 +6,8 @@ causal softmax attention with rotate-half RoPE; prenorm RMSNorm residual
 blocks; a tied LM head; mean cross-entropy).  It imports nothing of the
 program (``mamba_distributed_tpu``): it reads the benchmark's own weights
 (``reference/init.py``) by their names and a configuration as a plain dict
-(``benchmark/configs/<name>.json``, key ``model``).
+(``benchmark/configs/<name>.json``, key ``model``).  ``reference/mamba2.py``
+is the module the kinds find it through.
 
 No kernels, no cache, no chunked scan, no paging: every position is computed
 from the whole sequence.  ``mm`` is the only place a matrix product is formed,
@@ -204,17 +205,21 @@ def _segments(m: dict):
     return out
 
 
+def block(mixer, bp, m, h, precision):
+    """One prenorm residual block: ``h + mixer(norm(h))``."""
+    u = rms_norm(h, bp["norm"]["weight"], m["norm_eps"])
+    return h + mixer(bp["mixer"], m, u, precision)
+
+
 def hidden_states(params, m, ids, precision="f32", remat=False):
     """ids (b, t) -> the residual stream after the last block, (b, t, d)."""
     h = params["embedding"][ids]
 
     def mamba_block(h, bp):
-        u = rms_norm(h, bp["norm"]["weight"], m["norm_eps"])
-        return h + mamba2_mixer(bp["mixer"], m, u, precision), None
+        return block(mamba2_mixer, bp, m, h, precision), None
 
     def attn_block(h, bp):
-        u = rms_norm(h, bp["norm"]["weight"], m["norm_eps"])
-        return h + attention_mixer(bp["mixer"], m, u, precision)
+        return block(attention_mixer, bp, m, h, precision)
 
     if remat:
         mamba_block = jax.checkpoint(mamba_block)

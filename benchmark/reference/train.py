@@ -11,6 +11,10 @@ beside nothing else on one chip.
 What is compared with the program (``compare``): each step's loss; per leaf —
 a stacked block leaf counts once per layer — the norm of the first clipped
 gradient and the norm of the parameters' change after the steps.
+
+Nothing here knows an architecture: the loss and the names of the tree's
+stacked groups are the configuration's reference module's (``ref``, found by
+``benchmark.reference.of``).
 """
 
 from __future__ import annotations
@@ -21,9 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark.reference import model as ref_model
-
-STACKED = ("blocks", "attn_blocks")
+from benchmark.reference import freeze, seed_key
 
 
 # ------------------------------------------------------------ leaves
@@ -33,14 +35,14 @@ def _path_name(path) -> str:
     return "/".join(str(getattr(k, "key", k)) for k in path)
 
 
-def leaf_norms(tree) -> dict:
-    """{leaf name: norms}: one norm per layer for a stacked leaf (shape
-    (L,)), one for any other (shape ()).  Traceable."""
+def leaf_norms(tree, stacked) -> dict:
+    """{leaf name: norms}: one norm per layer for a leaf of the groups
+    ``stacked`` (shape (L,)), one for any other (shape ()).  Traceable."""
     out = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         name = _path_name(path)
         x = leaf.astype(jnp.float32)
-        if name.split("/")[0] in STACKED:
+        if name.split("/")[0] in stacked:
             out[name] = jnp.sqrt(jnp.sum(x * x, axis=tuple(range(1, x.ndim))))
         else:
             out[name] = jnp.sqrt(jnp.sum(x * x))
@@ -60,11 +62,11 @@ def flat_norms(norms: dict) -> dict:
     return out
 
 
-def decay_mask(tree):
+def decay_mask(tree, stacked):
     """True where the recipe decays: matrices, per layer."""
     def one(path, leaf):
-        stacked = _path_name(path).split("/")[0] in STACKED
-        return leaf.ndim - (1 if stacked else 0) >= 2
+        layered = _path_name(path).split("/")[0] in stacked
+        return leaf.ndim - (1 if layered else 0) >= 2
     return jax.tree_util.tree_map_with_path(one, tree)
 
 
@@ -78,15 +80,9 @@ def learning_rate(step: int, t: dict) -> float:
     return t["max_lr"] * (step + 1.0) / t["warmup_steps"]
 
 
-def freeze(m: dict) -> tuple:
-    """A configuration dict as a hashable static argument."""
-    return tuple(sorted((k, tuple(v) if isinstance(v, list) else v)
-                        for k, v in m.items()))
-
-
-@functools.partial(jax.jit, static_argnames=("m_items", "precision"))
-def _block_grad(params, ids, targets, m_items, precision):
-    return jax.value_and_grad(ref_model.loss_sum)(
+@functools.partial(jax.jit, static_argnames=("ref", "m_items", "precision"))
+def _block_grad(params, ids, targets, ref, m_items, precision):
+    return jax.value_and_grad(ref.loss_sum)(
         params, dict(m_items), ids, targets, precision)
 
 
@@ -108,8 +104,8 @@ def spread_rows(devices):
     return NamedSharding(mesh, P("rows")), NamedSharding(mesh, P())
 
 
-def step_gradient(params, m, x, y, precision="f32", row_block=4, rows=None,
-                  devices=None):
+def step_gradient(ref, params, m, x, y, precision="f32", row_block=4,
+                  rows=None, devices=None):
     """Mean loss and its gradient over a step's rows.  x, y (accum, B, T)
     int arrays.  ``rows`` (a slice of the flattened accum*B rows) plants the
     faults "part of the batch left out, the mean taken over the rest"."""
@@ -124,35 +120,44 @@ def step_gradient(params, m, x, y, precision="f32", row_block=4, rows=None,
     total, grads = 0.0, None
     for lo in range(0, ids.shape[0], row_block):
         l, g = _block_grad(params, put(ids[lo:lo + row_block]),
-                           put(tgt[lo:lo + row_block]), key, precision)
+                           put(tgt[lo:lo + row_block]), ref, key, precision)
         total = total + l
         grads = g if grads is None else _tree_add(grads, g)
     n = ids.size
     return total / n, jax.tree.map(lambda g: g / n, grads)
 
 
-@functools.partial(jax.jit, static_argnames=("b1", "b2", "eps", "wd", "clip"),
-                   donate_argnums=(0, 2, 3))
-def _adamw(params, grads, mu, nu, lr, count, *, b1, b2, eps, wd, clip):
+@functools.partial(jax.jit, donate_argnums=(0, 2, 3), static_argnames=(
+    "stacked", "b1", "b2", "eps", "wd", "clip"))
+def _adamw(params, grads, mu, nu, lr, count, *, stacked, b1, b2, eps, wd, clip):
     gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(grads)))
     scale = jnp.where(gnorm < clip, 1.0, clip / gnorm)
     g = jax.tree.map(lambda x: x * scale, grads)
     mu = jax.tree.map(lambda a, x: b1 * a + (1 - b1) * x, mu, g)
     nu = jax.tree.map(lambda a, x: b2 * a + (1 - b2) * x * x, nu, g)
     c1, c2 = 1 - b1 ** count, 1 - b2 ** count
-    mask = decay_mask(params)
+    mask = decay_mask(params, stacked)
 
     def upd(p, a, b, decays):
         u = (a / c1) / (jnp.sqrt(b / c2) + eps)
         return p - lr * (u + (wd * p if decays else 0.0))
 
     params = jax.tree.map(upd, params, mu, nu, mask)
-    return params, mu, nu, gnorm, leaf_norms(g)
+    return params, mu, nu, gnorm, leaf_norms(g, stacked)
 
 
-def first_steps(params0, m, t, batches, precision="f32", row_block=4,
+def initial_params(ref, seed: int, m: dict, dtype):
+    """The configuration's weights as the reference's steps read them: the
+    tree of ``--seed`` in ``dtype``, raised back to float32."""
+    return jax.jit(lambda k: jax.tree.map(
+        lambda a: a.astype(jnp.float32), ref.init_params(k, m, dtype)))(
+            seed_key(seed))
+
+
+def first_steps(ref, params0, m, t, batches, precision="f32", row_block=4,
                 rows=None, frozen=False, devices=None):
-    """Run ``len(batches)`` steps from ``params0`` (not consumed).
+    """Run ``len(batches)`` steps of the reference module ``ref``'s loss from
+    the float32 tree ``params0`` (not consumed).
 
     Returns {"losses": [...], "grad_norm": first global norm, "grad": flat
     per-leaf norms of the first clipped gradient, "delta": flat per-leaf norms
@@ -167,20 +172,20 @@ def first_steps(params0, m, t, batches, precision="f32", row_block=4,
     nu = jax.tree.map(jnp.zeros_like, params0)
     out = {"losses": []}
     for step, (x, y) in enumerate(batches):
-        loss, grads = step_gradient(params, m, x, y, precision, row_block, rows,
-                                    devices)
+        loss, grads = step_gradient(ref, params, m, x, y, precision, row_block,
+                                    rows, devices)
         out["losses"].append(float(loss))
         # _adamw consumes its parameters; a frozen step keeps them
         new, mu, nu, gnorm, gleaf = _adamw(
             jax.tree.map(jnp.copy, params) if frozen else params, grads, mu, nu, learning_rate(step, t), step + 1.0,
-            b1=t["adam_b1"], b2=t["adam_b2"], eps=t["adam_eps"],
+            stacked=tuple(ref.STACKED), b1=t["adam_b1"], b2=t["adam_b2"], eps=t["adam_eps"],
             wd=t["weight_decay"], clip=t["grad_clip"])
         if step == 0:
             out["grad_norm"] = float(gnorm)
             out["grad"] = flat_norms(gleaf)
         params = params if frozen else new
-    delta = jax.jit(lambda a, b: leaf_norms(jax.tree.map(jnp.subtract, a, b)))(
-        params, params0)
+    delta = jax.jit(lambda a, b: leaf_norms(
+        jax.tree.map(jnp.subtract, a, b), ref.STACKED))(params, params0)
     out["delta"] = flat_norms(delta)
     return out
 

@@ -1,13 +1,17 @@
 """Compile each cell's step programs for a described ``v5e:2x2`` at the real
 sizes, without a chip, and print the compiler's memory analysis.
 
-  JAX_PLATFORMS=cpu python benchmark/rehearse.py [--only train|tick|chunk|dp4|reference]
+  JAX_PLATFORMS=cpu python benchmark/rehearse.py \
+      [--only train|tick|chunk|dp4|weights|reference|gradient] [--config <file>]
 
 What it compiles: the train step at B 8 (one chip) and under a four-device
 data mesh; the decode tick at the chat cell's capacity (``mamba2-280m``) and
 the chunk step and tick at ``kv_slot_tokens`` 8192 and the long-document
 cell's capacity (``hybrid-280m``; at capacity 32 the tick needs 19.3 GiB and
-is refused, at 16 it needs 10.1 GiB); the reference's row-block gradient.  Nothing runs, so it says nothing about
+is refused, at 16 it needs 10.1 GiB); and, of the configuration ``--config``
+names (``configs/mamba2-280m.json`` unless given; any file of that form, a
+scratch one too), making its weights in the dtype it states, one request of
+its serving reference, and its training reference's row-block gradient.  Nothing runs, so it says nothing about
 results or times: a compile that passes is not a chip run.  The topology is
 described inside ``main``, never at import.
 """
@@ -141,24 +145,91 @@ def serving(devices, cell_name, what):
         _report(f"{cell_name} chunk step ({chunk} tokens)", compiled, t0)
 
 
-def reference(devices, rows=4):
+def _config(path):
+    """(file, its reference module, its ``model``, its stated weight dtype)."""
+    from benchmark import harness, reference
+
+    c = harness.load_json(path)
+    return c, reference.of(c), c["model"], reference.params_dtype(c)
+
+
+def _key(one):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
+
+
+def weights(devices, config_path):
+    """Making a configuration's weights in the dtype its file states: the
+    peak has to read as the finished tree and one layer's float32."""
+    import jax
+    import numpy as np
+    from jax.sharding import SingleDeviceSharding
+
+    c, ref, m, dtype = _config(config_path)
+    one = SingleDeviceSharding(devices[0])
+    make = jax.jit(lambda k: ref.init_params(k, m, dtype))
+    tree = jax.eval_shape(make, _key(one))
+    layer = max((sum(int(np.prod(a.shape[1:])) for a in jax.tree.leaves(g))
+                 for name, g in tree.items() if name in ref.STACKED), default=0)
+    whole = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(tree))
+    print(f"{c['name']}: {whole / 1e9:.3f} B parameters in {dtype}; the largest "
+          f"layer of a stacked group has {layer / 1e9:.3f} B, "
+          f"{4 * layer / 2**30:.2f} GiB in float32", flush=True)
+    t0 = time.time()
+    _report(f"{c['name']} weights in {dtype}", make.lower(_key(one)).compile(), t0)
+
+
+def reference(devices, config_path, tokens, positions):
+    """One request of the serving reference (``served_logits``): ``tokens``
+    padded tokens, logits at ``positions`` of them.  The walk is made over
+    shapes, each of its programs compiled where it is first called; a layer's
+    weights are drawn in one program and read in the next, so the walk's peak
+    (its largest program beside what it holds from first layer to last) does
+    not grow with the depth."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from benchmark import harness
-    from benchmark.reference import init as ref_init
+    c, ref, m, dtype = _config(config_path)
+    one = SingleDeviceSharding(devices[0])
+    seen = {}
+
+    def jit(fn):
+        jitted = jax.jit(fn)
+
+        def call(*args):
+            if fn not in seen:
+                t0 = time.time()
+                seen[fn] = jitted.lower(*args).compile()
+                _report(f"  {fn.__name__}", seen[fn], t0)
+            return _sds(jax.eval_shape(fn, *args), one)
+        return call
+
+    ids = jax.ShapeDtypeStruct((1, tokens), jnp.int32, sharding=one)
+    pos = jax.ShapeDtypeStruct((positions,), jnp.int32, sharding=one)
+    print(f"{c['name']} serving reference, one request of {tokens} tokens "
+          f"({m['n_layer']} layers):", flush=True)
+    ref.served_logits(_key(one), m, dtype, ids, pos, "f32", jit=jit)
+
+
+def gradient(devices, config_path, rows=4):
+    """The training reference's gradient of one block of rows."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
     from benchmark.reference import train as ref_train
 
-    c = harness.load_json(os.path.join(harness.BENCH_DIR, "configs", "mamba2-280m.json"))
-    m = c["model"]
+    c, ref, m, _ = _config(config_path)
     one = SingleDeviceSharding(devices[0])
-    params = _sds(jax.eval_shape(lambda k: ref_init.init_params(k, m),
+    params = _sds(jax.eval_shape(lambda k: ref.init_params(k, m, "float32"),
                                  jax.random.PRNGKey(0)), one)
     ids = jax.ShapeDtypeStruct((rows, c["train"]["seq_len"]), jnp.int32, sharding=one)
     t0 = time.time()
     compiled = ref_train._block_grad.lower(
-        params, ids, ids, ref_train.freeze(m), "f32").compile()
+        params, ids, ids, ref, ref_train.freeze(m), "f32").compile()
     _report(f"reference gradient of {rows} rows x {c['train']['seq_len']}",
             compiled, t0)
 
@@ -166,6 +237,11 @@ def reference(devices, rows=4):
 def main():
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--only", default=None)
+    p.add_argument("--config", default=os.path.join(
+        CHECKOUT, "benchmark", "configs", "mamba2-280m.json"),
+        help="the configuration file of the weights, reference and gradient jobs")
+    p.add_argument("--tokens", type=int, default=1024,
+                   help="the padded length of the reference's one request")
     args = p.parse_args()
     # the program asks jax.default_backend() which attention to take, and sees
     # the CPU here: "0" is its lever for the chip-free TPU lowering (the Pallas
@@ -176,7 +252,9 @@ def main():
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     devices = list(topo.devices)
     jobs = {
-        "reference": lambda: reference(devices),
+        "weights": lambda: weights(devices, args.config),
+        "reference": lambda: reference(devices, args.config, args.tokens, 64),
+        "gradient": lambda: gradient(devices, args.config),
         "train": lambda: train_step(devices, "train-mamba2-280m-1chip"),
         "tick": lambda: serving(devices, "serve-mamba2-280m-chat", "tick"),
         "chunk": lambda: (serving(devices, "serve-hybrid-280m-longdoc", "chunk"),
