@@ -340,9 +340,13 @@ def gather_kv_pages(k_pages: jax.Array, v_pages: jax.Array,
                     live_pages: jax.Array | None = None,
                     k_scale: jax.Array | None = None,
                     v_scale: jax.Array | None = None,
-                    dtype=None):
+                    dtype=None, layer=None):
     """Reassemble each row's logical KV view: (P, nkv, pg, hd) head-major
-    pages + (b, W) table -> (b, W*pg, nkv, hd).  The lax fallback path —
+    pages + (b, W) table -> (b, W*pg, nkv, hd).  With ``layer`` (an int
+    or a traced scalar) the pages are the whole (A, P, nkv, pg, hd) pool
+    and the gather reads that layer's pages out of it, so no slice of
+    the pool is ever made; the scales stay the layer's own (P, nkv).
+    The lax fallback path —
     the Pallas ragged kernels (ops/pallas/attention_kernels.py) walk the
     table in-kernel instead of materializing this (and read the
     head-major pages without the axis move this gather folds in).
@@ -364,7 +368,7 @@ def gather_kv_pages(k_pages: jax.Array, v_pages: jax.Array,
     rows dequantize with the trash scale (finite garbage, masked as
     above)."""
     b, W = page_table.shape
-    _, nkv, pg, hd = k_pages.shape
+    nkv, pg, hd = k_pages.shape[-3:]
     if live_pages is not None:
         page_table = jnp.where(
             jnp.arange(W)[None, :] < live_pages[:, None], page_table, 0
@@ -373,7 +377,8 @@ def gather_kv_pages(k_pages: jax.Array, v_pages: jax.Array,
         dtype = jnp.float32
 
     def gather(pages, scales):
-        x = pages[page_table]                            # (b, W, nkv, pg, hd)
+        # (b, W, nkv, pg, hd)
+        x = pages[page_table] if layer is None else pages[layer, page_table]
         if scales is not None:
             x = x.astype(dtype) * scales[page_table][
                 ..., None, None].astype(dtype)
@@ -407,13 +412,28 @@ def _sdpa_positions(q, k, v, qpos):
     return out.reshape(b, tq, nh, hd).astype(q.dtype)
 
 
+def _layer_scales(scales, layer):
+    """One attention layer's (P, nkv) scales out of the (A, P, nkv) leaf
+    (int8 pools; 32 KB at the benchmark's pool, where a layer of pages
+    is 67 MB and is never sliced)."""
+    return jax.lax.dynamic_index_in_dim(scales, layer, 0, keepdims=False)
+
+
 def attention_mixer_step(params: dict, cfg: ModelConfig, u_t: jax.Array,
-                         kv, page_table: jax.Array, lengths: jax.Array,
+                         kv, layer, page_table: jax.Array,
+                         lengths: jax.Array,
                          write_mask: jax.Array | None = None):
     """Single-token decode against the paged KV cache.
 
-    u_t (b, d); kv = (k_pages, v_pages) — or the int8 4-tuple with the
-    per-(page, kv-head) scales; page_table (b, W); lengths (b,)
+    u_t (b, d); kv = (k_pages, v_pages) — the WHOLE page pools
+    (A, P, nkv, pg, hd), every attention layer's, as the caller's layer
+    loop carries them — or the int8 4-tuple with the (A, P, nkv)
+    per-(page, kv-head) scales; ``layer`` — this layer's index into
+    them, a traced scalar under a scan or an int when unrolled: the one
+    row a slot writes is scattered into the pool at ``[layer, phys, :,
+    off]`` and the kernel addresses the layer by index, so nothing the
+    size of the pool, or of a layer of it, is made on the way;
+    page_table (b, W); lengths (b,)
     — the row's token count BEFORE this step (the new token lands at
     cache position ``lengths[r]``).  ``write_mask`` (b,) bool routes
     masked rows' KV writes to the trash page and is how the serving tick
@@ -427,7 +447,8 @@ def attention_mixer_step(params: dict, cfg: ModelConfig, u_t: jax.Array,
     with the chunk path and mirrored by the kernels.  Masked rows'
     page AND scale writes land on the trash page as before.
 
-    Returns (y (b, d), kv') with kv' the same arity as ``kv``.
+    Returns (y (b, d), kv') with kv' the same arity as ``kv``: the whole
+    pools again, this layer's rows written.
     """
     nh, nkv, hd, rot = _attn_dims(cfg)
     b, _ = u_t.shape
@@ -468,8 +489,8 @@ def attention_mixer_step(params: dict, cfg: ModelConfig, u_t: jax.Array,
         def qwrite(pages, scales, row):
             # row (b, nkv, hd): requantize the whole target page under
             # the updated scale, insert the fresh row at ``off``
-            old_q = pages[phys]                       # (b, nkv, pg, hd)
-            old_s = scales[phys]                      # (b, nkv)
+            old_q = pages[layer, phys]                # (b, nkv, pg, hd)
+            old_s = scales[layer, phys]               # (b, nkv)
             has_prior = (off > 0)[:, None]            # page holds this
             # sequence's earlier tokens iff the write offset is interior
             amax = jnp.max(jnp.abs(row.astype(jnp.float32)), axis=-1)
@@ -481,21 +502,30 @@ def attention_mixer_step(params: dict, cfg: ModelConfig, u_t: jax.Array,
             onehot = jnp.arange(pg)[None, :] == off[:, None]   # (b, pg)
             page = jnp.where(onehot[:, None, :, None],
                              q_row[:, :, None, :], req)
-            return (pages.at[phys].set(page.astype(pages.dtype)),
-                    scales.at[phys].set(new_s))
+            return (pages.at[layer, phys].set(page.astype(pages.dtype)),
+                    scales.at[layer, phys].set(new_s))
 
         with jax.named_scope(scopes.KV_WRITE):
             k_pages, k_scale = qwrite(k_pages, k_scale, k[:, 0])
             v_pages, v_scale = qwrite(v_pages, v_scale, v[:, 0])
     else:
-        # head-major pages: the token offset sits one axis past the
-        # heads, so the (b,) phys/off pair scatters a (b, nkv, hd) row
-        # block per write
+        # one (hd,) row per (slot, kv head), scattered into the carried
+        # pool at [layer, phys, head, off].  Row by row, not a (nkv, hd)
+        # block per slot: a window over the head axis, which is not the
+        # pool's minor one, makes the TPU compiler re-lay the whole pool
+        # out around the scatter (and back for the kernel, 2 x 537 MB a
+        # layer at the benchmark's hybrid); a window of the minor axis
+        # alone is written in place, in the kernels' own row-major layout
         with jax.named_scope(scopes.KV_WRITE):
-            k_pages = k_pages.at[phys, :, off].set(
+            hh = jnp.arange(nkv)[None, :]
+            k_pages = k_pages.at[layer, phys[:, None], hh, off[:, None]].set(
                 k[:, 0].astype(k_pages.dtype))
-            v_pages = v_pages.at[phys, :, off].set(
+            v_pages = v_pages.at[layer, phys[:, None], hh, off[:, None]].set(
                 v[:, 0].astype(v_pages.dtype))
+    if quant:
+        ks, vs = _layer_scales(k_scale, layer), _layer_scales(v_scale, layer)
+    else:
+        ks = vs = None
 
     from mamba_distributed_tpu.ops.pallas.common import resolve_attn_impl
 
@@ -511,9 +541,9 @@ def attention_mixer_step(params: dict, cfg: ModelConfig, u_t: jax.Array,
             # (int8 pools: dequant fused into the page walk via the
             # prefetched scales)
             out = ragged_paged_decode_attention(
-                q[:, 0], k_pages, v_pages, page_table,
+                q[:, 0], k_pages, v_pages, layer, page_table,
                 jnp.minimum(qpos + 1, W * pg),
-                k_scale=k_scale, v_scale=v_scale,
+                k_scale=ks, v_scale=vs,
             )[:, None]
         else:
             # tokens readable after the write = qpos + 1 per row: gather
@@ -522,7 +552,7 @@ def attention_mixer_step(params: dict, cfg: ModelConfig, u_t: jax.Array,
             # too
             kk, vv = gather_kv_pages(
                 k_pages, v_pages, page_table, (qpos + pg) // pg,
-                k_scale=k_scale, v_scale=v_scale, dtype=compute_dtype,
+                k_scale=ks, v_scale=vs, dtype=compute_dtype, layer=layer,
             )
             out = _sdpa_positions(q, kk, vv, qpos[:, None])
     with jax.named_scope(scopes.ATTN_OUT):
@@ -582,13 +612,18 @@ def _chunk_page_scales(k, v, real, page_table, lengths, n_real,
 
 
 def attention_mixer_chunk(params: dict, cfg: ModelConfig, u: jax.Array,
-                          kv, page_table: jax.Array, lengths: jax.Array,
+                          kv, layer, page_table: jax.Array,
+                          lengths: jax.Array,
                           token_mask: jax.Array | None = None):
     """One prefill CHUNK against the paged cache: write the chunk's real
     tokens' K/V into this row's pages at positions [lengths, lengths +
     n_real), then attend every chunk query over the page view (prefix +
     the freshly written chunk — intra-chunk causality falls out of the
     per-position bound).
+
+    ``kv`` is the whole page pools (the int8 4-tuple with the scales) and
+    ``layer`` this layer's index into them, as in
+    ``attention_mixer_step``: the pools come back whole, written in place.
 
     u (b, c, d); token_mask (b, c) {0,1} marks real tokens — the pad is
     a LEFT prefix (serving/prefill.chunk_inputs), so real token j of the
@@ -643,9 +678,15 @@ def attention_mixer_chunk(params: dict, cfg: ModelConfig, u: jax.Array,
             k = apply_rope(k, angles)
 
     if quant:
+        ks_old = _layer_scales(k_scale, layer)
+        vs_old = _layer_scales(v_scale, layer)
         ks_new, vs_new, takes = _chunk_page_scales(
-            k, v, real, page_table, lengths, c - pad, k_scale, v_scale, pg
+            k, v, real, page_table, lengths, c - pad, ks_old, vs_old, pg
         )
+        k_scale = jax.lax.dynamic_update_index_in_dim(
+            k_scale, ks_new, layer, 0)
+        v_scale = jax.lax.dynamic_update_index_in_dim(
+            v_scale, vs_new, layer, 0)
 
     from mamba_distributed_tpu.ops.pallas.common import resolve_attn_impl
 
@@ -657,14 +698,13 @@ def attention_mixer_chunk(params: dict, cfg: ModelConfig, u: jax.Array,
         # the kernel writes the chunk's K/V into the pages itself
         with jax.named_scope(scopes.ATTN_KERNEL):
             out, k_pages, v_pages = ragged_paged_prefill_attention(
-                q, k, v, k_pages, v_pages, page_table, lengths, c - pad,
+                q, k, v, k_pages, v_pages, layer, page_table, lengths,
+                c - pad,
                 **({} if not quant else dict(
-                    k_scale_old=k_scale, v_scale_old=v_scale,
+                    k_scale_old=ks_old, v_scale_old=vs_old,
                     k_scale_new=ks_new, v_scale_new=vs_new,
                 )),
             )
-        if quant:
-            k_scale, v_scale = ks_new, vs_new
     elif quant:
         from mamba_distributed_tpu.ops.quant import kv_quantize, kv_requant
 
@@ -690,7 +730,7 @@ def attention_mixer_chunk(params: dict, cfg: ModelConfig, u: jax.Array,
         def merge(pages, old_scales, new_scales, x):
             # requantize window pages under their new scales, then
             # scatter the chunk's quantized rows into the flat view
-            old_q = pages[wtbl]                       # (b, Wc, nkv, pg, hd)
+            old_q = pages[layer, wtbl]                # (b, Wc, nkv, pg, hd)
             old_s = old_scales[wtbl]                  # (b, Wc, nkv)
             new_s = new_scales[wtbl]
             ratio = jnp.where(has_prior[..., None], old_s / new_s, 0.0)
@@ -705,18 +745,18 @@ def attention_mixer_chunk(params: dict, cfg: ModelConfig, u: jax.Array,
             merged = jnp.moveaxis(
                 view[:, :-1].reshape(b, Wc, pg, nkv, hd), 2, 3
             )
-            return pages.at[dst].set(merged.astype(pages.dtype))
+            return pages.at[layer, dst].set(merged.astype(pages.dtype))
 
         with jax.named_scope(scopes.KV_WRITE):
-            k_pages = merge(k_pages, k_scale, ks_new, k)
-            v_pages = merge(v_pages, v_scale, vs_new, v)
-        k_scale, v_scale = ks_new, vs_new
+            k_pages = merge(k_pages, ks_old, ks_new, k)
+            v_pages = merge(v_pages, vs_old, vs_new, v)
         tokens = jnp.minimum(lengths + (c - pad), W * pg)
         with jax.named_scope(scopes.ATTN_KERNEL):
             kk, vv = gather_kv_pages(
                 k_pages, v_pages, page_table,
                 jnp.maximum((tokens + pg - 1) // pg, 1),
-                k_scale=k_scale, v_scale=v_scale, dtype=compute_dtype,
+                k_scale=ks_new, v_scale=vs_new, dtype=compute_dtype,
+                layer=layer,
             )
             out = _sdpa_positions(
                 q, kk, vv, jnp.minimum(posc, W * pg - 1))
@@ -729,8 +769,10 @@ def attention_mixer_chunk(params: dict, cfg: ModelConfig, u: jax.Array,
         # head-major pages: the (b, c) phys/off pair scatters
         # (b, c, nkv, hd) blocks one axis past the heads
         with jax.named_scope(scopes.KV_WRITE):
-            k_pages = k_pages.at[phys, :, off].set(k.astype(k_pages.dtype))
-            v_pages = v_pages.at[phys, :, off].set(v.astype(v_pages.dtype))
+            k_pages = k_pages.at[layer, phys, :, off].set(
+                k.astype(k_pages.dtype))
+            v_pages = v_pages.at[layer, phys, :, off].set(
+                v.astype(v_pages.dtype))
         # live extent after this chunk's write = prefix + its real
         # tokens; pages past it gather as trash (fully masked), so the
         # chunk's fallback cost tracks live tokens, not table width
@@ -740,7 +782,7 @@ def attention_mixer_chunk(params: dict, cfg: ModelConfig, u: jax.Array,
         with jax.named_scope(scopes.ATTN_KERNEL):
             kk, vv = gather_kv_pages(
                 k_pages, v_pages, page_table,
-                jnp.maximum((tokens + pg - 1) // pg, 1),
+                jnp.maximum((tokens + pg - 1) // pg, 1), layer=layer,
             )
             out = _sdpa_positions(
                 q, kk, vv, jnp.minimum(posc, W * pg - 1))

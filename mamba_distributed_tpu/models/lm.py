@@ -220,9 +220,10 @@ def _block_fwd(block_params, cfg, hidden, residual, attn: bool, seq_ctx=None,
     only) zeroes the mixer's scan inputs at left-pad positions
     (inference/bucketing.py).  ``initial_state`` (chunked prefill) is the
     ``(conv_state, ssm_state)`` carry from the previous chunk for SSM
-    mixers, or ``((k_pages, v_pages), page_table, lengths)`` for
-    attention mixers — the paged KV cache the chunk writes into
-    (lm_prefill_chunk).
+    mixers, or ``((k_pages, v_pages), layer, page_table, lengths)`` for
+    attention mixers — the whole paged KV pool and this layer's index
+    into it; the chunk writes the layer's pages in place and the state
+    returned is the whole pool again (lm_prefill_chunk).
     With a MoE model (``cfg.moe_num_experts > 0``) the non-state form
     returns ``(hidden, residual, aux)`` — the layer's load-balance loss
     term.
@@ -245,12 +246,12 @@ def _block_fwd(block_params, cfg, hidden, residual, attn: bool, seq_ctx=None,
     if attn:
         if initial_state is not None:
             # chunked prefill: resume against the paged KV cache —
-            # initial_state = ((k_pages, v_pages), page_table, lengths);
-            # the mask'd pad prefix is handled inside (pad keys are never
-            # written to pages, so nothing can attend them)
-            kv, page_table, lengths = initial_state
+            # initial_state = ((k_pages, v_pages), layer, page_table,
+            # lengths); the mask'd pad prefix is handled inside (pad keys
+            # are never written to pages, so nothing can attend them)
+            kv, layer, page_table, lengths = initial_state
             hidden, state = attention_mixer_chunk(
-                block_params["mixer"], cfg, normed, kv, page_table,
+                block_params["mixer"], cfg, normed, kv, layer, page_table,
                 lengths, token_mask=token_mask,
             )
         elif token_mask is not None:
@@ -851,6 +852,9 @@ def lm_prefill_chunk(params: dict, cfg: ModelConfig, input_ids: jax.Array,
     n_real) and attends over the page view (models/attention.
     attention_mixer_chunk), so a hybrid prompt's pages fill as chunks
     land and the serving engine can interleave them with decode ticks.
+    The page pool rides the layer loop's carry whole and comes back
+    whole (``_hybrid_layers``): under the chunk step's donation the
+    pages are written in place.
 
     Returns (last_logits (b, V) fp32, new state) — same contract as
     ``lm_prefill``.
@@ -893,6 +897,107 @@ def lm_verify_chunk(params: dict, cfg: ModelConfig, input_ids: jax.Array,
     return logits.astype(jnp.float32), new_state
 
 
+def _layer_of(stacked, i):
+    """Layer ``i``'s leaves of a layer-stacked state (``i`` an int, or a
+    traced scalar under a scan)."""
+    return jax.tree.map(
+        lambda s: jax.lax.dynamic_index_in_dim(s, i, 0, keepdims=False),
+        stacked,
+    )
+
+
+def _with_layer(stacked, i, new):
+    """``stacked`` with layer ``i``'s leaves replaced by ``new``: on a
+    buffer the caller carries through its loop and donates, an in-place
+    write of that layer's rows."""
+    return jax.tree.map(
+        lambda s, n: jax.lax.dynamic_update_index_in_dim(s, n, i, 0),
+        stacked, new,
+    )
+
+
+def _hybrid_layers(params: dict, cfg: ModelConfig, hidden, residual, state,
+                   mamba_block, attn_block):
+    """The hybrid stack's layer loop of the two stateful steps
+    (``lm_step``, ``_chunk_backbone``), periodic (scanned by group) or
+    not (unrolled) alike.
+
+    ``mamba_block(bp, h, rs, st) -> (h, rs, st')`` runs one Mamba block
+    from its layer's ``(conv, ssm)`` state; ``attn_block(bp, h, rs, kv,
+    a) -> (h, rs, kv')`` runs one attention block against the WHOLE page
+    pool ``kv`` at layer index ``a`` and returns the whole pool.
+
+    Both stacked states, ``state["blocks"]`` and ``state["attn_blocks"]``,
+    ride the loop's CARRY, a layer addressed by its index (traced in the
+    scan, an int when unrolled): a Mamba layer's rows are sliced out,
+    stepped and written back where they were, the page pool is never
+    sliced at all.  So a caller that carries and donates the state (the
+    serving tick, the chunk step) has ONE buffer of each from entry to
+    exit.  Scanned in and out, the pool would be sliced a layer at a
+    time, stacked into a second pool and copied back into the caller's
+    carry, on every sub-step and chunk (2 x 537 MB of pages at the
+    benchmark's hybrid, five eighths of its device time).
+
+    Returns (hidden, residual, blocks', attn_blocks').
+    """
+    blocks, akv = state["blocks"], state["attn_blocks"]
+
+    def mamba(carry, xs):
+        h, rs, blocks = carry
+        bp, i = xs
+        h, rs, st = mamba_block(bp, h, rs, _layer_of(blocks, i))
+        return (h, rs, _with_layer(blocks, i, st)), None
+
+    if (per := _hybrid_period(cfg)) is not None:
+        p, r = per
+
+        def group(carry, xs):
+            h, rs, blocks, akv = carry
+            mblk, ablk, g = xs
+            first = g * (p - 1)  # the group's first Mamba layer
+            with jax.named_scope(scopes.LAYERS):
+                (h, rs, blocks), _ = jax.lax.scan(
+                    mamba, (h, rs, blocks),
+                    (jax.tree.map(lambda v: v[:r], mblk),
+                     first + jnp.arange(r)),
+                )
+            h, rs, akv = attn_block(ablk, h, rs, akv, g)
+            with jax.named_scope(scopes.LAYERS):
+                (h, rs, blocks), _ = jax.lax.scan(
+                    mamba, (h, rs, blocks),
+                    (jax.tree.map(lambda v: v[r:], mblk),
+                     first + r + jnp.arange(p - 1 - r)),
+                )
+            return (h, rs, blocks, akv), None
+
+        with jax.named_scope(scopes.ATTN_LAYERS):
+            (hidden, residual, blocks, akv), _ = jax.lax.scan(
+                group, (hidden, residual, blocks, akv),
+                (_group_mamba_stack(params, cfg, p), params["attn_blocks"],
+                 jnp.arange(len(cfg.attn_layer_idx))),
+            )
+    else:
+        attn_idx = set(cfg.attn_layer_idx)
+        mi = ai = 0
+        with jax.named_scope(scopes.ATTN_LAYERS):
+            for i in range(cfg.n_layer):
+                if i in attn_idx:
+                    bp = jax.tree.map(
+                        lambda p_, j=ai: p_[j], params["attn_blocks"]
+                    )
+                    hidden, residual, akv = attn_block(
+                        bp, hidden, residual, akv, ai
+                    )
+                    ai += 1
+                else:
+                    bp = jax.tree.map(lambda p_, j=mi: p_[j], params["blocks"])
+                    (hidden, residual, blocks), _ = mamba(
+                        (hidden, residual, blocks), (bp, mi)
+                    )
+                    mi += 1
+    return hidden, residual, blocks, akv
+
+
 def _chunk_backbone(params: dict, cfg: ModelConfig, input_ids: jax.Array,
                     state, token_mask: jax.Array | None = None):
     """Shared body of ``lm_prefill_chunk``/``lm_verify_chunk``: embed ->
@@ -923,87 +1028,22 @@ def _chunk_backbone(params: dict, cfg: ModelConfig, input_ids: jax.Array,
                 (token_mask > 0.5).astype(jnp.int32), axis=1
             )
 
-        def abody(ablk, h, rs, akv):
+        def mamba_block(bp, h, rs, st):
             return _block_fwd(
-                ablk, cfg, h, rs, True, return_state=True,
+                bp, cfg, h, rs, False, return_state=True,
+                token_mask=token_mask, initial_state=st,
+            )
+
+        def attn_block(bp, h, rs, akv, a):
+            return _block_fwd(
+                bp, cfg, h, rs, True, return_state=True,
                 token_mask=token_mask,
-                initial_state=(akv, tbl, lengths),
+                initial_state=(akv, a, tbl, lengths),
             )
 
-        if (per := _hybrid_period(cfg)) is not None:
-            p, r = per
-            n_attn = len(cfg.attn_layer_idx)
-            mstack = _group_mamba_stack(params, cfg, p)
-            mstate = jax.tree.map(
-                lambda s: s.reshape((n_attn, p - 1) + s.shape[1:]),
-                state["blocks"],
-            )
-
-            def group(carry, xs):
-                mblk, ablk, mst, akv = xs
-                pre = lambda x: jax.tree.map(lambda v: v[:r], x)
-                post = lambda x: jax.tree.map(lambda v: v[r:], x)
-                with jax.named_scope(scopes.LAYERS):
-                    carry, new_pre = jax.lax.scan(
-                        body, carry, (pre(mblk), pre(mst))
-                    )
-                hidden, residual, new_kv = abody(ablk, *carry, akv)
-                with jax.named_scope(scopes.LAYERS):
-                    carry, new_post = jax.lax.scan(
-                        body, (hidden, residual), (post(mblk), post(mst))
-                    )
-                new_m = jax.tree.map(
-                    lambda a, b: jnp.concatenate([a, b], axis=0),
-                    new_pre, new_post,
-                )
-                return carry, (new_m, new_kv)
-
-            with jax.named_scope(scopes.ATTN_LAYERS):
-                (hidden, residual), (new_m, new_a) = jax.lax.scan(
-                    group, (hidden, residual),
-                    (mstack, params["attn_blocks"], mstate,
-                     state["attn_blocks"]),
-                )
-            new_blocks = jax.tree.map(
-                lambda x: x.reshape((-1,) + x.shape[2:]), new_m
-            )
-        else:
-            attn_idx = set(cfg.attn_layer_idx)
-            mi = ai = 0
-            new_ms, new_as = [], []
-            with jax.named_scope(scopes.ATTN_LAYERS):
-                for i in range(cfg.n_layer):
-                    attn = i in attn_idx
-                    if attn:
-                        bp = jax.tree.map(
-                            lambda p_, j=ai: p_[j], params["attn_blocks"]
-                        )
-                        akv = jax.tree.map(
-                            lambda s, j=ai: s[j], state["attn_blocks"]
-                        )
-                        hidden, residual, st = abody(
-                            bp, hidden, residual, akv
-                        )
-                        new_as.append(st)
-                        ai += 1
-                    else:
-                        bp = jax.tree.map(
-                            lambda p_, j=mi: p_[j], params["blocks"]
-                        )
-                        st = jax.tree.map(
-                            lambda s, j=mi: s[j], state["blocks"]
-                        )
-                        hidden, residual, st = _block_fwd(
-                            bp, cfg, hidden, residual, False,
-                            return_state=True, token_mask=token_mask,
-                            initial_state=st,
-                        )
-                        new_ms.append(st)
-                        mi += 1
-                stack = lambda sts: jax.tree.map(
-                    lambda *xs: jnp.stack(xs), *sts
-                )
-                new_blocks, new_a = stack(new_ms), stack(new_as)
+        hidden, residual, new_blocks, new_a = _hybrid_layers(
+            params, cfg, hidden, residual, state, mamba_block, attn_block
+        )
         return hidden, residual, {
             "blocks": new_blocks,
             "attn_blocks": new_a,
@@ -1055,17 +1095,19 @@ def init_lm_state(cfg: ModelConfig, batch: int, max_len: int = 0):
 def _block_step(bp, cfg: ModelConfig, hidden, residual, st, attn: bool,
                 attn_ctx=None, state_mask=None):
     """One decode-step block (shared by the scan and unrolled paths).
-    ``attn_ctx = (page_table, lengths, write_mask)`` is the layer-shared
-    paged-KV metadata (attention layers only); ``state_mask`` is
-    ``lm_step``'s, for the Mamba layers' conv + SSM carry."""
+    ``attn_ctx = (page_table, lengths, write_mask, layer)`` is the
+    layer-shared paged-KV metadata plus this layer's index into the page
+    pool (attention layers only: ``st`` is then the whole pool, and comes
+    back whole); ``state_mask`` is ``lm_step``'s, for the Mamba layers'
+    conv + SSM carry."""
     compute_dtype = jnp.dtype(cfg.compute_dtype)
     normed, residual = add_rms_norm(
         hidden, residual, bp["norm"]["weight"], cfg.norm_eps,
     )
     if attn:
-        page_table, lengths, write_mask = attn_ctx
+        page_table, lengths, write_mask, layer = attn_ctx
         hidden, st = attention_mixer_step(
-            bp["mixer"], cfg, normed, st, page_table, lengths,
+            bp["mixer"], cfg, normed, st, layer, page_table, lengths,
             write_mask=write_mask,
         )
     else:
@@ -1124,98 +1166,40 @@ def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
     each lane's per-layer op sequence is unchanged, only the
     (layer-group, lane-block) execution order moves.  ``None`` (every
     non-pipelined caller) is the exact status quo.
+
+    The stacked state (``state["blocks"]``; hybrids: the KV page pool
+    ``state["attn_blocks"]`` too) rides every layer loop's carry and each
+    layer is addressed by its index, so a caller that carries and donates
+    the state (the serving tick) has one buffer of each from entry to
+    exit: the new state is the old one written in place.
     """
     compute_dtype = jnp.dtype(cfg.compute_dtype)
     hidden = _embed(params, token, compute_dtype)
     residual = None
 
-    def mbody(carry, xs):
-        h, rs = carry
-        bp, st = xs
-        h, rs, st = _block_step(bp, cfg, h, rs, st, False,
-                                state_mask=state_mask)
-        return (h, rs), st
-
     if cfg.attn_layer_idx:
         tbl, lengths = state["attn_meta"]
-        attn_ctx = (tbl, lengths, write_mask)
         adv = (
             jnp.ones_like(lengths) if write_mask is None
             else write_mask.astype(lengths.dtype)
         )
-        new_meta = (tbl, lengths + adv)
-
-    if cfg.attn_layer_idx and (per := _hybrid_period(cfg)) is not None:
-        p, r = per
         residual = jnp.zeros_like(hidden, dtype=jnp.float32)
-        mstack = _group_mamba_stack(params, cfg, p)
-        mstate = jax.tree.map(
-            lambda s: s.reshape((len(cfg.attn_layer_idx), p - 1) + s.shape[1:]),
-            state["blocks"],
+
+        def mamba_block(bp, h, rs, st):
+            return _block_step(bp, cfg, h, rs, st, False,
+                               state_mask=state_mask)
+
+        def attn_block(bp, h, rs, akv, a):
+            return _block_step(bp, cfg, h, rs, akv, True,
+                               attn_ctx=(tbl, lengths, write_mask, a))
+
+        hidden, residual, new_blocks, new_a = _hybrid_layers(
+            params, cfg, hidden, residual, state, mamba_block, attn_block
         )
-
-        def group(carry, xs):
-            mblk, ablk, mst, ast = xs
-            pre = lambda x: jax.tree.map(lambda v: v[:r], x)
-            post = lambda x: jax.tree.map(lambda v: v[r:], x)
-            with jax.named_scope(scopes.LAYERS):
-                carry, new_pre = jax.lax.scan(
-                    mbody, carry, (pre(mblk), pre(mst))
-                )
-            hidden, residual, ast = _block_step(
-                ablk, cfg, *carry, ast, True, attn_ctx=attn_ctx
-            )
-            with jax.named_scope(scopes.LAYERS):
-                carry, new_post = jax.lax.scan(
-                    mbody, (hidden, residual), (post(mblk), post(mst))
-                )
-            new_m = jax.tree.map(
-                lambda a, b: jnp.concatenate([a, b], axis=0), new_pre, new_post
-            )
-            return carry, (new_m, ast)
-
-        with jax.named_scope(scopes.ATTN_LAYERS):
-            (hidden, residual), (new_m, new_a) = jax.lax.scan(
-                group, (hidden, residual),
-                (mstack, params["attn_blocks"], mstate,
-                 state["attn_blocks"]),
-            )
         new_state = {
-            "blocks": jax.tree.map(
-                lambda x: x.reshape((-1,) + x.shape[2:]), new_m
-            ),
+            "blocks": new_blocks,
             "attn_blocks": new_a,
-            "attn_meta": new_meta,
-        }
-    elif cfg.attn_layer_idx:
-        attn_idx = set(cfg.attn_layer_idx)
-        mi = ai = 0
-        new_m, new_a = [], []
-        for i in range(cfg.n_layer):
-            attn = i in attn_idx
-            if attn:
-                bp = jax.tree.map(lambda p, j=ai: p[j], params["attn_blocks"])
-                st = jax.tree.map(lambda s, j=ai: s[j], state["attn_blocks"])
-            else:
-                bp = jax.tree.map(lambda p, j=mi: p[j], params["blocks"])
-                st = jax.tree.map(lambda s, j=mi: s[j], state["blocks"])
-            with jax.named_scope(scopes.ATTN_LAYERS):
-                hidden, residual, st = _block_step(
-                    bp, cfg, hidden, residual, st, attn,
-                    attn_ctx=attn_ctx if attn else None,
-                    state_mask=state_mask,
-                )
-            if attn:
-                new_a.append(st)
-                ai += 1
-            else:
-                new_m.append(st)
-                mi += 1
-        stack = lambda states: jax.tree.map(lambda *xs: jnp.stack(xs), *states)
-        new_state = {
-            "blocks": stack(new_m),
-            "attn_blocks": stack(new_a),
-            "attn_meta": new_meta,
+            "attn_meta": (tbl, lengths + adv),
         }
     else:
         residual = jnp.zeros_like(hidden, dtype=jnp.float32)
@@ -1247,21 +1231,9 @@ def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
             def cbody(carry, xs):
                 h, rs, blocks = carry
                 bp, i = xs
-                st = jax.tree.map(
-                    lambda s: jax.lax.dynamic_index_in_dim(
-                        s, i, 0, keepdims=False
-                    ),
-                    blocks,
-                )
-                h, rs, st = _block_step(bp, cfg, h, rs, st, False,
-                                        state_mask=state_mask)
-                blocks = jax.tree.map(
-                    lambda s, new: jax.lax.dynamic_update_index_in_dim(
-                        s, new, i, 0
-                    ),
-                    blocks, st,
-                )
-                return (h, rs, blocks), None
+                h, rs, st = _block_step(bp, cfg, h, rs, _layer_of(blocks, i),
+                                        False, state_mask=state_mask)
+                return (h, rs, _with_layer(blocks, i, st)), None
 
             with jax.named_scope(scopes.LAYERS):
                 (hidden, residual, new_blocks), _ = jax.lax.scan(

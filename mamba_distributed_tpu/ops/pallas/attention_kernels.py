@@ -524,11 +524,20 @@ def flash_sdpa_causal(
 TRACE_COUNTS = {"ragged_decode": 0, "ragged_prefill": 0}
 
 
+def _layer_operand(layer) -> jax.Array:
+    """The pool's layer index as the (1,) int32 scalar-prefetch operand
+    both ragged kernels' K/V index maps read."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def _rpa_kernel(
-    tbl_ref, len_ref, *rest,
+    layer_ref, tbl_ref, len_ref, *rest,
     nw: int, pg: int, sm_scale: float, quant: bool = False,
 ):
     """One (slot, kv-head, page) cell of the ragged decode forward.
+
+    ``layer_ref`` (the pool's layer index) is read by the index maps
+    alone: the K/V blocks arrive as that layer's (1, 1, pg, hd) tiles.
 
     ``quant`` (int8 page pools): two extra scalar-prefetched (P, nkv)
     f32 scale arrays ride between the metadata and the tensor refs; the
@@ -615,6 +624,7 @@ def ragged_paged_decode_attention(
     q: jax.Array,
     k_pages: jax.Array,
     v_pages: jax.Array,
+    layer,
     page_table: jax.Array,
     kv_len: jax.Array,
     k_scale: jax.Array | None = None,
@@ -624,14 +634,19 @@ def ragged_paged_decode_attention(
     """Paged decode attention with per-row lengths.
 
     q (S, nh, hd) — one query token per slot; k_pages/v_pages
-    (P, nkv, page, hd) — the shared HEAD-MAJOR page pool (page 0 =
-    trash); page_table (S, W) int32; kv_len (S,) int32 — tokens readable
+    (A, P, nkv, page, hd) — the WHOLE head-major page pool, every
+    attention layer's pages (page 0 of each layer = trash); ``layer``
+    — which of the A layers to read, an int or a traced int32 scalar: it
+    rides the scalar-prefetch block beside the page table and the K/V
+    index maps address ``(layer, tbl[s, j], h)``, so a caller's layer
+    loop hands over the pool it carries and never slices it;
+    page_table (S, W) int32; kv_len (S,) int32 — tokens readable
     per row (INCLUDING any token written this step).  Returns
     (S, nh, hd).
 
-    ``k_scale``/``v_scale`` (int8 pools: (P, nkv) f32, one symmetric
-    scale per (physical page, kv head)) ride the scalar-prefetch channel
-    next to the page table, and the kernel dequantizes each visited
+    ``k_scale``/``v_scale`` (int8 pools: THIS layer's (P, nkv) f32, one
+    symmetric scale per (physical page, kv head)) ride the scalar-prefetch
+    channel next to the page table, and the kernel dequantizes each visited
     int8 tile in-register — the per-page scalar folds into the score
     multiply (K) and the accumulator update (V), so page-walk HBM
     traffic is the int8 bytes and nothing widened ever round-trips.
@@ -646,7 +661,7 @@ def ragged_paged_decode_attention(
     TRACE_COUNTS["ragged_decode"] += 1
     quant = k_scale is not None
     S, nh, hd = q.shape
-    P, nkv, pg, _ = k_pages.shape
+    _, P, nkv, pg, _ = k_pages.shape
     W = page_table.shape[1]
     if nh % nkv:
         raise ValueError(f"num_heads {nh} not a multiple of kv heads {nkv}")
@@ -657,20 +672,23 @@ def ragged_paged_decode_attention(
     qh = q.reshape(S, nkv, rep, hd)
     if R8 != rep:
         qh = jnp.pad(qh, ((0, 0), (0, 0), (0, R8 - rep), (0, 0)))
-    # the pool is STORED head-major (P, nkv, pg, hd), so KV blocks are
-    # (1, 1, pg, hd) — Mosaic's last-two-dims tiling — addressed straight
-    # off the table: no per-call transpose of the pool on the hot path
+    # the pool is STORED head-major (A, P, nkv, pg, hd), so KV blocks are
+    # (1, 1, pg, hd) — Mosaic's last-two-dims tiling — under a squeezed
+    # layer dimension, addressed straight off the layer index and the
+    # table: no per-call slice or transpose of the pool on the hot path
 
     grid = (S, nkv, W)
     # index maps take the grid ids plus EVERY scalar-prefetch operand
-    # (2 plain, 4 with the int8 scales) — *pf absorbs the difference
+    # (3 plain, 5 with the int8 scales) — *pf absorbs the difference
     q_spec = pl.BlockSpec(
-        (1, 1, R8, hd), lambda s, h, j, tbl, *pf: (s, h, 0, 0)
+        (1, 1, R8, hd), lambda s, h, j, *pf: (s, h, 0, 0)
     )
     kv_spec = pl.BlockSpec(
-        (1, 1, pg, hd), lambda s, h, j, tbl, *pf: (tbl[s, j], h, 0, 0)
+        (None, 1, 1, pg, hd),
+        lambda s, h, j, lyr, tbl, *pf: (lyr[0], tbl[s, j], h, 0, 0),
     )
-    prefetch = (page_table.astype(jnp.int32), kv_len.astype(jnp.int32))
+    prefetch = (_layer_operand(layer), page_table.astype(jnp.int32),
+                kv_len.astype(jnp.int32))
     if quant:
         prefetch += (k_scale.astype(jnp.float32),
                      v_scale.astype(jnp.float32))
@@ -723,11 +741,14 @@ def ragged_paged_decode_attention(
 
 
 def _rpp_kernel(
-    tbl_ref, len_ref, creal_ref, *rest,
+    layer_ref, tbl_ref, len_ref, creal_ref, *rest,
     nw: int, pg: int, c: int, rep: int, sm_scale: float,
     quant: bool = False,
 ):
     """One (row, kv-head, page) cell of the fused prefill forward.
+
+    ``layer_ref`` (the pool's layer index) is read by the index maps
+    alone, as in ``_rpa_kernel``.
 
     ``quant`` (int8 page pools): four extra scalar-prefetched (P, nkv)
     f32 scale arrays — OLD and NEW for K and V.  The NEW scales are
@@ -874,6 +895,7 @@ def ragged_paged_prefill_attention(
     v_chunk: jax.Array,
     k_pages: jax.Array,
     v_pages: jax.Array,
+    layer,
     page_table: jax.Array,
     lengths: jax.Array,
     chunk_real: jax.Array,
@@ -888,25 +910,30 @@ def ragged_paged_prefill_attention(
 
     q (b, c, nh, hd) — RoPE'd chunk queries; k_chunk/v_chunk
     (b, c, nkv, hd) — the chunk's RoPE'd K/V (left-pad prefix rows are
-    ignored); k_pages/v_pages (P, nkv, pg, hd) — the shared HEAD-MAJOR
-    page pool (page 0 = trash); page_table (b, W) int32; lengths (b,)
+    ignored); k_pages/v_pages (A, P, nkv, pg, hd) — the WHOLE head-major
+    page pool (page 0 of each layer = trash) and ``layer`` — the layer
+    this call reads and writes, an int or a traced int32 scalar that
+    rides the scalar-prefetch block (``ragged_paged_decode_attention``
+    says how); page_table (b, W) int32; lengths (b,)
     int32 — tokens cached per row BEFORE this chunk; chunk_real (b,)
     int32 — real tokens in this chunk (c - left pad).  Real token i of
     the chunk lands at absolute position ``lengths[r] + i - pad`` and
     every query attends positions ``[0, its own position]`` — the causal
     rule over prefix + fresh chunk.
 
-    Int8 page pools pass the four (P, nkv) f32 scale arrays — OLD and
-    NEW per K/V, the NEW ones pre-planned by
+    Int8 page pools pass this layer's four (P, nkv) f32 scale arrays — OLD
+    and NEW per K/V, the NEW ones pre-planned by
     ``models/attention._chunk_page_scales`` (the caller scatters them
     into its scale arrays; this kernel only READS scales) — and the
     fused write quantizes the chunk's K/V before the one-hot merge
     while old rows requantize under the grown scale; the attend runs
     on the dequantized merged tile.
 
-    Returns (o (b, c, nh, hd), k_pages', v_pages').  The page-pool
-    outputs alias their inputs (in-place under the chunk step's state
-    donation).  Numerics match the lax fallback (scatter + gather +
+    Returns (o (b, c, nh, hd), k_pages', v_pages'): the whole pools, of
+    which only ``layer``'s owned pages (and its trash page) changed.  The
+    page-pool outputs alias their inputs, so a caller that carries the
+    pool through its layer loop and donates it (the chunk step) has one
+    buffer from entry to exit.  Numerics match the lax fallback (scatter + gather +
     ``models/attention._sdpa_positions``; int8: requant-merge +
     dequantizing gather) to fp tolerance; one jit trace covers every
     (lengths, chunk_real) mix at a fixed (b, c, W) layout
@@ -917,7 +944,7 @@ def ragged_paged_prefill_attention(
     TRACE_COUNTS["ragged_prefill"] += 1
     quant = k_scale_old is not None
     b, c, nh, hd = q.shape
-    P, nkv, pg, _ = k_pages.shape
+    _, P, nkv, pg, _ = k_pages.shape
     W = page_table.shape[1]
     if nh % nkv:
         raise ValueError(f"num_heads {nh} not a multiple of kv heads {nkv}")
@@ -940,29 +967,31 @@ def ragged_paged_prefill_attention(
 
     grid = (b, nkv, W)
     # index maps take the grid ids plus EVERY scalar-prefetch operand
-    # (3 plain, 7 with the int8 scale arrays) — *pf absorbs the extras
+    # (4 plain, 8 with the int8 scale arrays) — *pf absorbs the extras
     q_spec = pl.BlockSpec(
-        (1, 1, Q8, hd), lambda r, h, j, tbl, *pf: (r, h, 0, 0)
+        (1, 1, Q8, hd), lambda r, h, j, *pf: (r, h, 0, 0)
     )
     c_spec = pl.BlockSpec(
-        (1, 1, C8, hd), lambda r, h, j, tbl, *pf: (r, h, 0, 0)
+        (1, 1, C8, hd), lambda r, h, j, *pf: (r, h, 0, 0)
     )
+    # the layer dimension is squeezed: the kernel sees (1, 1, pg, hd)
     kv_in_spec = pl.BlockSpec(
-        (1, 1, pg, hd), lambda r, h, j, tbl, *pf: (tbl[r, j], h, 0, 0)
+        (None, 1, 1, pg, hd),
+        lambda r, h, j, lyr, tbl, *pf: (lyr[0], tbl[r, j], h, 0, 0),
     )
 
-    def kv_out_idx(r, h, j, tbl, ln, cr, *pf):
+    def kv_out_idx(r, h, j, lyr, tbl, ln, cr, *pf):
         # only the one cell owning a chunk-written page may flush to it;
         # everything else (pure-prefix pages, pages past the extent)
-        # flushes its block to the trash page — whose content is garbage
-        # by design and never read
+        # flushes its block to the layer's trash page — whose content is
+        # garbage by design and never read
         takes_write = (j * pg + pg > ln[r]) & (j * pg < ln[r] + cr[r])
-        return (jnp.where(takes_write, tbl[r, j], 0), h, 0, 0)
+        return (lyr[0], jnp.where(takes_write, tbl[r, j], 0), h, 0, 0)
 
-    kv_out_spec = pl.BlockSpec((1, 1, pg, hd), kv_out_idx)
+    kv_out_spec = pl.BlockSpec((None, 1, 1, pg, hd), kv_out_idx)
 
-    prefetch = (page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-                chunk_real.astype(jnp.int32))
+    prefetch = (_layer_operand(layer), page_table.astype(jnp.int32),
+                lengths.astype(jnp.int32), chunk_real.astype(jnp.int32))
     if quant:
         prefetch += (k_scale_old.astype(jnp.float32),
                      k_scale_new.astype(jnp.float32),
@@ -991,8 +1020,8 @@ def ragged_paged_prefill_attention(
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
         ],
         # the page-pool inputs (last two operands after the scalar
-        # prefetch block) alias the page-pool outputs: the write is in
-        # place under donation
+        # prefetch block) alias the page-pool outputs, all layers of
+        # them: the write is in place under donation
         input_output_aliases={npre + 3: 1, npre + 4: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),

@@ -165,10 +165,15 @@ def _tick(params: dict, pool: dict, tbl=None, lengths=None, *,
 
     The conv + SSM carry of a slot parked mid-chunked-prefill survives
     the tick through ``lm_step``'s ``state_mask`` (``~prefilling``): the
-    update returns those rows unchanged, and the pure-SSM layer loop
-    carries the stacked pool, so the donated pool is one buffer updated
-    in place from entry to exit — no select and no copy over the
-    (L, S, ...) leaves.  Only the (S, V) logits are selected here.
+    update returns those rows unchanged, and every layer loop of
+    ``lm_step`` (the pure-SSM scan; the hybrid's group scan and unrolled
+    loop) carries the stacked pool, so the donated pool is one buffer
+    updated in place from entry to exit — no select and no copy over the
+    (L, S, ...) leaves.  The hybrid's KV page pool rides the same carry:
+    a slot's one-token row is scattered into it and the decode kernel
+    reads a layer of it by index, so nothing the size of the pool, or of
+    a layer's pages, is sliced, stacked or copied around the kernels.
+    Only the (S, V) logits are selected here.
 
     Mirrors generate()'s decode loop exactly: sample from the carried
     logits with key fold_in(key, step), then lm_step.  Slots that hit

@@ -156,8 +156,10 @@ def prefill_chunk(
     both drivers pass the same cast output, which is what makes their
     chunk computations bit-identical.  ``state`` is donated: for hybrid
     stacks it carries the (large) paged KV pool through every chunk, and
-    the donation lets XLA write pages in place instead of copying the
-    pool per chunk.
+    since the layer loop carries the pool whole and the kernel addresses
+    a layer of it by index (models/lm._hybrid_layers), the donation lets
+    XLA write pages in place: no slice, stacked copy or write-back of
+    the pool is made per chunk.
 
     ``mesh`` (static; a 2-D ``serving_mesh`` with ``model > 1``, else
     None) re-asserts the tensor-parallel weight layout inside the jit —

@@ -58,10 +58,16 @@ the slot decodable.
 
 HYBRID stacks (``attn_layer_idx`` non-empty) pool too: the attention KV
 lives in a fixed PAGE pool — per-layer HEAD-MAJOR ``(P, nkv, page, hd)``
-page arrays under ``state["attn_blocks"]`` (page 0 is a reserved trash
+page arrays, stacked ``(A, P, nkv, page, hd)`` under
+``state["attn_blocks"]`` (page 0 of each layer is a reserved trash
 page; head-major is the Pallas kernels' native block layout, so the
-decode/prefill page walks read pages without any per-call transpose)
-— while the page table and per-slot lengths stay HOST-side on the
+decode/prefill page walks read pages without any per-call transpose).
+The tick and the chunk step carry the stacked pool whole through their
+layer loops and hand it, with a layer index, to the kernels
+(models/lm._hybrid_layers): on the hot path it is never sliced by
+layer, and with the state donated it is one buffer from a program's
+entry to its exit.  The helpers below (``copy_page``, ``read_pages``,
+``write_pages``) run between ticks — while the page table and per-slot lengths stay HOST-side on the
 engine (they change only between ticks, and the tick takes them as
 plain array arguments).  With ``cfg.kv_page_dtype="int8"`` each layer's
 tuple grows per-(page, kv-head) f32 scale arrays ``(A, P, nkv)``

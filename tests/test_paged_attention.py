@@ -25,6 +25,39 @@ from mamba_distributed_tpu.ops.pallas.attention_kernels import (
 
 pytestmark = pytest.mark.pallas
 
+# The kernels take the WHOLE (A, P, nkv, pg, hd) pool and a layer index.
+# The cases below are built on one layer's pages: ``decode_at`` and
+# ``prefill_at`` plant them as layer LAYER of a three-layer pool whose
+# other layers are poison, so a read of the wrong layer shows in every
+# case, and hand the indexed layer's pages back.
+LAYER, LAYERS = 1, 3
+
+
+def pool_of(pages, layer=LAYER):
+    poison = 77 if pages.dtype == jnp.int8 else 3e4
+    pool = jnp.full((LAYERS,) + pages.shape, poison, pages.dtype)
+    return pool.at[layer].set(pages)
+
+
+def decode_at(q, kp, vp, tbl, kv_len, layer=LAYER, **kw):
+    return ragged_paged_decode_attention(
+        q, pool_of(kp, layer), pool_of(vp, layer), layer, tbl, kv_len, **kw
+    )
+
+
+def prefill_at(q, kc, vc, kp, vp, tbl, lens, creal, layer=LAYER, **kw):
+    o, kpool, vpool = ragged_paged_prefill_attention(
+        q, kc, vc, pool_of(kp, layer), pool_of(vp, layer), layer, tbl, lens,
+        creal, **kw
+    )
+    # the write is the indexed layer's alone (trash page excepted)
+    for pool, pages in ((kpool, kp), (vpool, vp)):
+        for a in set(range(LAYERS)) - {layer}:
+            np.testing.assert_array_equal(
+                np.asarray(pool[a, 1:]), np.asarray(pool_of(pages)[a, 1:])
+            )
+    return o, kpool[layer], vpool[layer]
+
 
 def paged_case(rng, S=4, nh=8, nkv=2, hd=32, pg=8, W=4, P=17,
                dtype=jnp.float32, seed_lens=None):
@@ -55,8 +88,7 @@ def lax_ref(q, k_pages, v_pages, tbl, kv_len):
 ])
 def test_ragged_kernel_matches_lax(rng, shapes):
     q, kp, vp, tbl, kv_len = paged_case(rng, **shapes)
-    got = ragged_paged_decode_attention(q, kp, vp, tbl, kv_len,
-                                        interpret=True)
+    got = decode_at(q, kp, vp, tbl, kv_len, interpret=True)
     ref = lax_ref(q, kp, vp, tbl, kv_len)
     live = np.asarray(kv_len) > 0
     np.testing.assert_allclose(
@@ -72,8 +104,7 @@ def test_ragged_kernel_ignores_pages_past_length(rng):
     output — the ragged skip really skips (also proves a recycled page
     can't leak into a slot whose table no longer names it)."""
     q, kp, vp, tbl, kv_len = paged_case(rng, seed_lens=[5, 9, 12, 3])
-    base = ragged_paged_decode_attention(q, kp, vp, tbl, kv_len,
-                                         interpret=True)
+    base = decode_at(q, kp, vp, tbl, kv_len, interpret=True)
     pg = kp.shape[2]
     npg = np.array(kp)
     nvg = np.array(vp)
@@ -83,7 +114,7 @@ def test_ragged_kernel_ignores_pages_past_length(rng):
                 npg[np.asarray(tbl)[s, j]] = 1e9
                 nvg[np.asarray(tbl)[s, j]] = -1e9
     # in-page positions past kv_len inside the LAST live page too
-    poisoned = ragged_paged_decode_attention(
+    poisoned = decode_at(
         q, jnp.asarray(npg), jnp.asarray(nvg), tbl, kv_len, interpret=True
     )
     np.testing.assert_array_equal(np.asarray(base), np.asarray(poisoned))
@@ -138,7 +169,7 @@ def test_ragged_kernel_one_trace_across_occupancies(rng):
     q, kp, vp, tbl, _ = paged_case(rng)
 
     fn = jax.jit(
-        lambda q, kp, vp, tbl, ln: ragged_paged_decode_attention(
+        lambda q, kp, vp, tbl, ln: decode_at(
             q, kp, vp, tbl, ln, interpret=True
         )
     )
@@ -153,15 +184,17 @@ def test_ragged_kernel_tpu_lowering(rng):
     the scalar-prefetched page-table index map."""
     S, nh, nkv, hd, pg, W, P = 8, 8, 2, 64, 16, 4, 33
     q = jnp.zeros((S, nh, hd), jnp.bfloat16)
-    kp = jnp.zeros((P, nkv, pg, hd), jnp.bfloat16)
+    kp = jnp.zeros((LAYERS, P, nkv, pg, hd), jnp.bfloat16)
     tbl = jnp.zeros((S, W), jnp.int32)
     ln = jnp.zeros((S,), jnp.int32)
 
-    def f(q, kp, vp, tbl, ln):
-        return ragged_paged_decode_attention(q, kp, vp, tbl, ln,
+    # the layer index traced, as the group scan hands it over
+    def f(q, kp, vp, a, tbl, ln):
+        return ragged_paged_decode_attention(q, kp, vp, a, tbl, ln,
                                              interpret=False)
 
-    exp = jax.export.export(jax.jit(f), platforms=["tpu"])(q, kp, kp, tbl, ln)
+    exp = jax.export.export(jax.jit(f), platforms=["tpu"])(
+        q, kp, kp, jnp.int32(LAYER), tbl, ln)
     assert exp.platforms == ("tpu",)
 
 
@@ -185,17 +218,17 @@ def test_attention_step_kernel_path_matches_lax(rng, monkeypatch):
     cfg_p = ModelConfig(**kw, attn_impl="pallas")
     params = init_attention_params(rng, cfg_x)
     b = 3
-    kv = init_attention_state(cfg_x, b, 32)
+    kv = jax.tree.map(pool_of, init_attention_state(cfg_x, b, 32))
     tbl, _ = attention_page_meta(cfg_x, b, 32)
     lengths = jnp.asarray([0, 5, 12], jnp.int32)
     u = jax.random.normal(jax.random.fold_in(rng, 1), (b, 64), jnp.float32)
     # seed the caches identically through a few lax steps first
     for i in range(3):
-        y_x, kv = attention_mixer_step(params, cfg_x, u + i, kv, tbl,
+        y_x, kv = attention_mixer_step(params, cfg_x, u + i, kv, LAYER, tbl,
                                        lengths + i)
-    y_ref, kv_ref = attention_mixer_step(params, cfg_x, u, kv, tbl,
+    y_ref, kv_ref = attention_mixer_step(params, cfg_x, u, kv, LAYER, tbl,
                                          lengths + 3)
-    y_pal, kv_pal = attention_mixer_step(params, cfg_p, u, kv, tbl,
+    y_pal, kv_pal = attention_mixer_step(params, cfg_p, u, kv, LAYER, tbl,
                                          lengths + 3)
     np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
                                atol=1e-5, rtol=1e-5)
@@ -264,7 +297,7 @@ def test_prefill_kernel_matches_lax(rng, case):
     q, kc, vc, kp, vp, tbl, lens, creal = prefill_case(rng, **case)
     ref_o, ref_kp, ref_vp = prefill_lax_ref(q, kc, vc, kp, vp, tbl, lens,
                                             creal)
-    got_o, got_kp, got_vp = ragged_paged_prefill_attention(
+    got_o, got_kp, got_vp = prefill_at(
         q, kc, vc, kp, vp, tbl, lens, creal, interpret=True
     )
     b, c = q.shape[:2]
@@ -310,7 +343,7 @@ def test_prefill_kernel_preserves_prefix_pages(rng):
     # snapshot before the call: the kernel's aliased page outputs may
     # donate the input buffers
     kp_np, vp_np = np.asarray(kp), np.asarray(vp)
-    _, got_kp, got_vp = ragged_paged_prefill_attention(
+    _, got_kp, got_vp = prefill_at(
         q, kc, vc, kp, vp, tbl, lens, creal, interpret=True
     )
     pg = kp_np.shape[2]
@@ -339,7 +372,7 @@ def test_prefill_kernel_zero_chunk_mid_page_flush(rng):
         rng, b=2, lens=(12, 4), reals=(0, 16)
     )
     kp_np, vp_np = np.asarray(kp), np.asarray(vp)
-    _, got_kp, got_vp = ragged_paged_prefill_attention(
+    _, got_kp, got_vp = prefill_at(
         q, kc, vc, kp, vp, tbl, lens, creal, interpret=True
     )
     pg = kp_np.shape[2]
@@ -357,8 +390,8 @@ def test_prefill_kernel_one_trace_across_ragged_lengths(rng):
 
     fn = jax.jit(
         lambda q, kc, vc, kp, vp, tbl, ln, cr:
-        ragged_paged_prefill_attention(q, kc, vc, kp, vp, tbl, ln, cr,
-                                       interpret=True)
+        ragged_paged_prefill_attention(q, kc, vc, pool_of(kp), pool_of(vp),
+                                       LAYER, tbl, ln, cr, interpret=True)
     )
     before = TRACE_COUNTS["ragged_prefill"]
     for lens, reals in (([0, 0, 0], [16, 16, 16]),
@@ -377,16 +410,16 @@ def test_prefill_kernel_tpu_lowering(rng):
     b, c, nh, nkv, hd, pg, W, P = 2, 128, 8, 2, 64, 16, 8, 33
     q = jnp.zeros((b, c, nh, hd), jnp.bfloat16)
     kc = jnp.zeros((b, c, nkv, hd), jnp.bfloat16)
-    kp = jnp.zeros((P, nkv, pg, hd), jnp.bfloat16)
+    kp = jnp.zeros((LAYERS, P, nkv, pg, hd), jnp.bfloat16)
     tbl = jnp.zeros((b, W), jnp.int32)
     ln = jnp.zeros((b,), jnp.int32)
 
-    def f(q, kc, vc, kp, vp, tbl, ln, cr):
-        return ragged_paged_prefill_attention(q, kc, vc, kp, vp, tbl, ln,
+    def f(q, kc, vc, kp, vp, a, tbl, ln, cr):
+        return ragged_paged_prefill_attention(q, kc, vc, kp, vp, a, tbl, ln,
                                               cr, interpret=False)
 
     exp = jax.export.export(jax.jit(f), platforms=["tpu"])(
-        q, kc, kc, kp, kp, tbl, ln, ln
+        q, kc, kc, kp, kp, jnp.int32(LAYER), tbl, ln, ln
     )
     assert exp.platforms == ("tpu",)
 
@@ -412,7 +445,7 @@ def test_attention_chunk_kernel_path_matches_lax(rng):
     cfg_p = ModelConfig(**kw, attn_impl="pallas")
     params = init_attention_params(rng, cfg_x)
     b, c = 3, 16
-    kv = init_attention_state(cfg_x, b, 64)
+    kv = jax.tree.map(pool_of, init_attention_state(cfg_x, b, 64))
     tbl, _ = attention_page_meta(cfg_x, b, 64)
     lengths = jnp.asarray([0, 5, 12], jnp.int32)
     u = jax.random.normal(jax.random.fold_in(rng, 1), (b, c, 64),
@@ -422,13 +455,13 @@ def test_attention_chunk_kernel_path_matches_lax(rng):
         [[0.0] * 8 + [1.0] * 8, [1.0] * 16, [1.0] * 16], jnp.float32
     )
     # seed the pool through one lax chunk first (both paths identically)
-    _, kv = attention_mixer_chunk(params, cfg_x, u, kv, tbl, lengths,
+    _, kv = attention_mixer_chunk(params, cfg_x, u, kv, LAYER, tbl, lengths,
                                   token_mask=None)
     lengths = lengths + c
-    y_ref, kv_ref = attention_mixer_chunk(params, cfg_x, u + 1.0, kv, tbl,
-                                          lengths, token_mask=mask)
-    y_pal, kv_pal = attention_mixer_chunk(params, cfg_p, u + 1.0, kv, tbl,
-                                          lengths, token_mask=mask)
+    y_ref, kv_ref = attention_mixer_chunk(params, cfg_x, u + 1.0, kv, LAYER,
+                                          tbl, lengths, token_mask=mask)
+    y_pal, kv_pal = attention_mixer_chunk(params, cfg_p, u + 1.0, kv, LAYER,
+                                          tbl, lengths, token_mask=mask)
     pad = np.asarray(c - mask.sum(axis=1), np.int32)
     for r in range(b):
         np.testing.assert_allclose(
@@ -438,7 +471,8 @@ def test_attention_chunk_kernel_path_matches_lax(rng):
     # identity tables never touch the trash page, so the pools must agree
     # everywhere except page 0 (the kernel's no-write flush target)
     for a, c_ in zip(kv_pal, kv_ref):
-        np.testing.assert_allclose(np.asarray(a)[1:], np.asarray(c_)[1:],
+        np.testing.assert_allclose(np.asarray(a)[:, 1:],
+                                   np.asarray(c_)[:, 1:],
                                    atol=1e-6, rtol=1e-6)
 
 
@@ -471,13 +505,92 @@ def test_page_recycle_no_alias_head_major(rng):
     clean_k = write(jnp.zeros_like(fresh_k), kc)
     clean_v = write(jnp.zeros_like(fresh_v), kc * 0.5)
 
-    got_stale = ragged_paged_decode_attention(
+    got_stale = decode_at(
         q, stale_k, stale_v, tbl_new, kv_len, interpret=True
     )
-    got_clean = ragged_paged_decode_attention(
+    got_clean = decode_at(
         q, clean_k, clean_v, tbl_new, kv_len, interpret=True
     )
     # positions < 14 were overwritten by the new owner; >= 14 are masked
     # by kv_len — stale residue is invisible
     np.testing.assert_array_equal(np.asarray(got_stale),
                                   np.asarray(got_clean))
+
+
+# ------------------------------------------- a layer of the pool, by index
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
+def test_kernels_address_a_layer_of_the_pool(rng, dtype):
+    """Both kernels on a three-layer pool, at each layer index handed over
+    as a TRACED scalar (what the group scan does): bit for bit the call on
+    that layer's slice alone; the prefill kernel's write changes the
+    indexed layer's owned pages and its trash page, nothing else in the
+    pool; and one trace serves every layer index."""
+    quant = dtype == "int8"
+    act = jnp.float32 if quant else jnp.bfloat16
+    b, c, nh, nkv, hd, pg, W, P = 3, 16, 8, 2, 32, 8, 4, 17
+    ks = jax.random.split(rng, 8)
+    if quant:
+        pages = lambda k: jax.random.randint(
+            k, (LAYERS, P, nkv, pg, hd), -127, 128).astype(jnp.int8)
+        scales = lambda k: 0.001 + 0.05 * jax.random.uniform(
+            k, (LAYERS, P, nkv), jnp.float32)
+        k_old, v_old = scales(ks[5]), scales(ks[6])
+        k_new, v_new = 1.5 * k_old, 1.25 * v_old
+    else:
+        pages = lambda k: jax.random.normal(k, (LAYERS, P, nkv, pg, hd), act)
+        k_old = v_old = k_new = v_new = None
+    kp, vp = pages(ks[0]), pages(ks[1])
+    q = jax.random.normal(ks[2], (b, c, nh, hd), act)
+    kc = jax.random.normal(ks[3], (b, c, nkv, hd), act)
+    vc = jax.random.normal(ks[4], (b, c, nkv, hd), act)
+    perm = 1 + np.random.default_rng(2).permutation(P - 1)[: b * W]
+    tbl = jnp.asarray(perm.reshape(b, W), jnp.int32)
+    lens = jnp.asarray([0, 5, 12], jnp.int32)
+    creal = jnp.asarray([16, 11, 16], jnp.int32)
+    at = lambda x, a: None if x is None else x[a]
+
+    def decode(kp, vp, a, k_s, v_s):
+        return ragged_paged_decode_attention(
+            q[:, 0], kp, vp, a, tbl, lens + creal,
+            k_scale=k_s, v_scale=v_s, interpret=True)
+
+    def prefill(kp, vp, a, k_o, v_o, k_n, v_n):
+        kw = dict(k_scale_old=k_o, v_scale_old=v_o,
+                  k_scale_new=k_n, v_scale_new=v_n) if quant else {}
+        return ragged_paged_prefill_attention(
+            q, kc, vc, kp, vp, a, tbl, lens, creal, interpret=True, **kw)
+
+    # the layer's scales are sliced by the caller (32 KB a layer at the
+    # benchmark's pool); the pages never are
+    decode_jit = jax.jit(
+        lambda a: decode(kp, vp, a, at(k_old, a), at(v_old, a)))
+    prefill_jit = jax.jit(
+        lambda a: prefill(kp, vp, a, at(k_old, a), at(v_old, a),
+                          at(k_new, a), at(v_new, a)))
+    owned = {0}  # the trash page eats the no-write flushes
+    for r in range(b):
+        for j in range(W):
+            if j * pg + pg > int(lens[r]) and j * pg < int(lens[r] + creal[r]):
+                owned.add(int(tbl[r, j]))
+    others = sorted(set(range(P)) - owned)
+    before = dict(TRACE_COUNTS)
+    got = [(decode_jit(jnp.int32(a)), prefill_jit(jnp.int32(a)))
+           for a in range(LAYERS)]
+    assert TRACE_COUNTS["ragged_decode"] == before["ragged_decode"] + 1
+    assert TRACE_COUNTS["ragged_prefill"] == before["ragged_prefill"] + 1
+    eq = lambda x, y: np.testing.assert_array_equal(
+        np.asarray(x, np.float32), np.asarray(y, np.float32))
+    for a, (dec, (o, kpool, vpool)) in enumerate(got):
+        one = lambda x: x[a][None]
+        eq(dec, decode(one(kp), one(vp), 0, at(k_old, a), at(v_old, a)))
+        ref_o, ref_k, ref_v = prefill(
+            one(kp), one(vp), 0, at(k_old, a), at(v_old, a),
+            at(k_new, a), at(v_new, a))
+        eq(o, ref_o)
+        for pool, ref, src in ((kpool, ref_k, kp), (vpool, ref_v, vp)):
+            eq(pool[a, 1:], ref[0, 1:])
+            eq(pool[a][jnp.asarray(others)], src[a][jnp.asarray(others)])
+            for other in set(range(LAYERS)) - {a}:
+                eq(pool[other], src[other])

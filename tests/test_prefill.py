@@ -443,47 +443,178 @@ def test_parked_slot_resumes_to_solo_stream():
 
 
 def _walk_eqns(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs nested in its params."""
+    """``(equation, wraps)`` for every equation of a jaxpr and of the jaxprs
+    nested in its params; ``wraps`` says the equation has such a body
+    (scan, pjit, cond, ...), whose equations follow.  A ``pallas_call`` is
+    one equation: its body is the kernel's."""
     for eqn in jaxpr.eqns:
-        yield eqn
-        for v in eqn.params.values():
-            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    yield from _walk_eqns(sub)
+        subs = [] if eqn.primitive.name == "pallas_call" else [
+            getattr(sub, "jaxpr", sub)
+            for v in eqn.params.values()
+            for sub in (v if isinstance(v, (tuple, list)) else (v,))
+            if hasattr(getattr(sub, "jaxpr", sub), "eqns")
+        ]
+        yield eqn, bool(subs)
+        for sub in subs:
+            yield from _walk_eqns(sub)
 
 
-def test_tick_updates_the_pool_in_place():
-    """Structure of the pure-SSM tick, by count: no ``select_n`` produces
-    a stacked (L, S, ...) pool leaf, and the layer loop holds the stacked
-    blocks in its carry, never among its scanned inputs or outputs (either
-    is a second pool-sized buffer on the device)."""
+@pytest.mark.parametrize("program,attn_impl", [
+    ("tick", None),              # pure SSM (PR 26's case)
+    ("tick", "pallas"),          # periodic hybrid, kernels interpreted
+    ("tick", "xla"),             # periodic hybrid, the lax fallback
+    ("prefill_chunk", "pallas"),
+    ("prefill_chunk", "xla"),
+])
+def test_tick_updates_the_pool_in_place(program, attn_impl):
+    """Structure of the serving programs, by jaxpr.  The layer loops (the
+    pure-SSM layer scan; the periodic hybrid's group scan and the Mamba
+    scans inside a group) hold the stacked state in their CARRY and never
+    among their scanned inputs or outputs: either is a second pool-sized
+    buffer on the device.  That is every leaf of ``state["blocks"]`` in
+    the tick and every leaf of the page pool ``state["attn_blocks"]`` in
+    the hybrid tick and chunk step.  Outside a ``pallas_call`` nothing but
+    an in-place write (``scatter`` / ``dynamic_update_slice``) produces an
+    array of a pool leaf's shape, no ``select_n`` and no ``concatenate``
+    among them; and where the kernels walk the pages nothing produces an
+    array of one layer's page slice ``(P, nkv, pg, hd)`` either (the lax
+    fallback gathers its pages out of the pool by index)."""
     from mamba_distributed_tpu.serving import engine as engine_mod
 
-    L, S, steps = 3, 5, 2
-    cfg = dataclasses.replace(tiny_cfg(), n_layer=L)
+    S, steps = 5, 4  # no layer loop is 4 long
+    if attn_impl is None:
+        cfg = dataclasses.replace(tiny_cfg(), n_layer=3)
+    else:
+        # two groups of [mamba, attn, mamba]: period 3, offset 1
+        cfg = dataclasses.replace(
+            tiny_cfg(attn_layer_idx=(1, 4), attn_num_heads=4,
+                     attn_num_kv_heads=2, remat=False, kv_page_tokens=8,
+                     kv_slot_tokens=32, attn_impl=attn_impl),
+            n_layer=6)
     dparams = cast_decode_params(
         init_lm_params(jax.random.PRNGKey(0), cfg), cfg=cfg)
     pool = init_pool(cfg, capacity=S)
-    leaf_shapes = {x.shape for x in jax.tree.leaves(pool["state"]["blocks"])}
-    assert len(leaf_shapes) == 2 and all(s[:2] == (L, S) for s in leaf_shapes)
-    jaxpr = jax.make_jaxpr(
-        lambda p, q: engine_mod._tick(p, q, cfg=cfg, k_max=5, steps=steps)
-    )(dparams, pool)
+    if program == "tick":
+        state = pool["state"]
+        kw = ({} if attn_impl is None else dict(
+            tbl=jnp.zeros((S, 4), jnp.int32), lengths=jnp.zeros((S,), jnp.int32)
+        ))
+        jaxpr = jax.make_jaxpr(
+            lambda p, q: engine_mod._tick(p, q, cfg=cfg, k_max=5,
+                                          steps=steps, **kw)
+        )(dparams, pool)
+        carried = jax.tree.leaves(state)
+    else:
+        # the engine's chunk carry: one request's Mamba state, the whole
+        # page pool, that slot's table row and length
+        state = {
+            **state_cache.read_state(pool, 0),
+            "attn_blocks": pool["state"]["attn_blocks"],
+            "attn_meta": (jnp.zeros((1, 4), jnp.int32),
+                          jnp.zeros((1,), jnp.int32)),
+        }
+        jaxpr = jax.make_jaxpr(
+            lambda p, st: engine_mod.prefill_chunk(
+                p, jnp.zeros((1, 16), jnp.int32), jnp.ones((1, 16)), st,
+                cfg=cfg)
+        )(dparams, state)
+        carried = jax.tree.leaves(state["attn_blocks"])
+    pool_shapes = {x.shape for x in carried}
+    n_groups = len(cfg.attn_layer_idx) or cfg.n_layer
+    assert all(s[0] in (cfg.n_layer - len(cfg.attn_layer_idx),
+                        len(cfg.attn_layer_idx)) for s in pool_shapes)
+    slice_shapes = (
+        {x.shape[1:] for x in jax.tree.leaves(state["attn_blocks"])
+         if x.ndim == 5}
+        if attn_impl == "pallas" else set()
+    )
+    assert (attn_impl == "pallas") == bool(slice_shapes)
+
     shape = lambda v: getattr(v.aval, "shape", None)
-    layer_loops = 0
-    for eqn in _walk_eqns(jaxpr.jaxpr):
-        if eqn.primitive.name == "select_n":
-            assert shape(eqn.outvars[0]) not in leaf_shapes, eqn
-        if eqn.primitive.name == "scan" and eqn.params["length"] == L:
-            layer_loops += 1
+    layer_loops = pallas_calls = 0
+    for eqn, wraps in _walk_eqns(jaxpr.jaxpr):
+        name = eqn.primitive.name
+        if name == "pallas_call":
+            pallas_calls += 1
+            continue
+        if name == "scan" and eqn.params["length"] != steps:
+            # a layer loop: whatever of the stacked state it touches, it
+            # carries (the sub-step scan, of length `steps`, carries the
+            # whole pool by construction and is not one)
             nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
             carry = {shape(v) for v in eqn.invars[nc:nc + nk]}
             scanned = ({shape(v) for v in eqn.invars[nc + nk:]}
                        | {shape(v) for v in eqn.outvars[nk:]})
-            assert leaf_shapes <= carry
-            assert not leaf_shapes & scanned
+            assert not pool_shapes & scanned, eqn
+            if eqn.params["length"] == n_groups:
+                layer_loops += 1
+                assert pool_shapes <= carry
+        if wraps:
+            continue
+        for out in eqn.outvars:
+            if shape(out) in pool_shapes:
+                assert name in ("scatter", "dynamic_update_slice"), eqn
+            assert shape(out) not in slice_shapes, eqn
     assert layer_loops == 1
+    assert pallas_calls == (attn_impl == "pallas")
+
+
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
+def test_periodic_and_unrolled_hybrid_agree(monkeypatch, attn_impl):
+    """The group scan (periodic hybrids) and the unrolled loop (aperiodic
+    ones) run the same indexed layer functions over the same carried
+    state: the same stack taken down either branch gives the same logits
+    and the same state, through two chunks of ``lm_prefill_chunk`` and
+    three steps of ``lm_step``."""
+    from mamba_distributed_tpu.models import lm
+    from mamba_distributed_tpu.models.lm import (
+        init_lm_state, lm_prefill_chunk, lm_step,
+    )
+
+    cfg = dataclasses.replace(
+        tiny_cfg(attn_layer_idx=(1, 4), attn_num_heads=4,
+                 attn_num_kv_heads=2, remat=False, kv_page_tokens=8,
+                 kv_slot_tokens=64, attn_impl=attn_impl),
+        n_layer=6)
+    dparams = cast_decode_params(
+        init_lm_params(jax.random.PRNGKey(0), cfg), cfg=cfg)
+    b = 2
+    ids = jnp.asarray(np.stack([rand_prompt(2 * CHUNK, seed=s)
+                                for s in (1, 2)]))
+    mask = jnp.ones((b, CHUNK)).at[1, :5].set(0.0)  # a left pad, chunk 0
+
+    def run():
+        state = init_lm_state(cfg, b, 64)
+        state["attn_meta"] = (state["attn_meta"][0],
+                              jnp.zeros((b,), jnp.int32))
+        outs = []
+        for i in range(2):
+            logits, state = lm_prefill_chunk(
+                dparams, cfg, ids[:, i * CHUNK:(i + 1) * CHUNK], state,
+                token_mask=mask if i == 0 else None)
+            outs.append(logits)
+        for i in range(3):
+            tok = jnp.argmax(logits[:, :cfg.vocab_size], axis=-1)
+            logits, state = lm_step(
+                dparams, cfg, state, tok.astype(jnp.int32),
+                write_mask=jnp.asarray([True, i != 1]))
+            outs.append(logits)
+        return outs, state
+
+    assert lm._hybrid_period(cfg) == (3, 1)
+    scanned, scanned_state = run()
+    monkeypatch.setattr(lm, "_hybrid_period", lambda cfg: None)
+    unrolled, unrolled_state = run()
+    for a, c in zip(scanned, unrolled):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   rtol=0, atol=1e-5)
+    # page 0 of a layer is the trash page: garbage by contract
+    trash = lambda st: {**st, "attn_blocks": jax.tree.map(
+        lambda x: x[:, 1:], st["attn_blocks"])}
+    for a, c in zip(jax.tree.leaves(trash(scanned_state)),
+                    jax.tree.leaves(trash(unrolled_state))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   rtol=0, atol=1e-5)
 
 
 def test_failed_chunk_requeues_and_frees_slot(monkeypatch):

@@ -308,8 +308,10 @@ def test_ragged_decode_kernel_vs_lax_int8():
     tbl = np.arange(1, P).reshape(S, W).astype(np.int32)
     kv_len = np.asarray([0, 5, 29], np.int32)
     q = rng.standard_normal((S, nh, hd)).astype(np.float32)
+    # the kernel reads layer 1 of a two-layer pool (layer 0: zeros)
+    pool = lambda x: jnp.stack([jnp.zeros_like(x), jnp.asarray(x)])
     out = ragged_paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(kq), jnp.asarray(vq),
+        jnp.asarray(q), pool(kq), pool(vq), 1,
         jnp.asarray(tbl), jnp.asarray(kv_len),
         k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs))
     kk, vv = gather_kv_pages(jnp.asarray(kq), jnp.asarray(vq),
@@ -338,7 +340,8 @@ def test_ragged_prefill_kernel_vs_lax_int8(monkeypatch):
     params = init_lm_params(jax.random.PRNGKey(0), cfg)
     ap = jax.tree.map(lambda x: x[0], params["attn_blocks"])["mixer"]
     b, c, W = 2, 16, 8
-    kv0 = init_attention_state(cfg, b, 64)
+    # a one-layer pool: the mixers take the whole pool and a layer index
+    kv0 = jax.tree.map(lambda x: x[None], init_attention_state(cfg, b, 64))
     tbl = 1 + np.arange(b * W, dtype=np.int32).reshape(b, W)
     lengths = np.asarray([5, 0], np.int32)  # mid-page resume + fresh row
     u = np.asarray(jax.random.normal(jax.random.PRNGKey(5), (b, c, 32)),
@@ -349,13 +352,13 @@ def test_ragged_prefill_kernel_vs_lax_int8(monkeypatch):
     for impl in ("xla", "pallas"):
         monkeypatch.setenv("MDT_ATTN_IMPL", impl)
         outs[impl] = attention_mixer_chunk(
-            ap, cfg, jnp.asarray(u), kv0, jnp.asarray(tbl),
+            ap, cfg, jnp.asarray(u), kv0, 0, jnp.asarray(tbl),
             jnp.asarray(lengths), token_mask=jnp.asarray(mask))
     (y_x, kv_x), (y_p, kv_p) = outs["xla"], outs["pallas"]
     np.testing.assert_allclose(np.asarray(y_x), np.asarray(y_p),
                                rtol=3e-5, atol=3e-5)
-    kxq, vxq, kxs, vxs = [np.asarray(x) for x in kv_x]
-    kpq, vpq, kps, vps = [np.asarray(x) for x in kv_p]
+    kxq, vxq, kxs, vxs = [np.asarray(x[0]) for x in kv_x]
+    kpq, vpq, kps, vps = [np.asarray(x[0]) for x in kv_p]
     total = lengths + np.asarray([c, c - 6])
     for r in range(b):
         for j in range(W):
@@ -388,17 +391,17 @@ def test_int8_kernels_tpu_lowering():
     S, nh, nkv, hd, pg, W = 64, 32, 8, 64, 64, 16
     P = 1 + S * W
     q = jnp.zeros((S, nh, hd), jnp.bfloat16)
-    kp = jnp.zeros((P, nkv, pg, hd), jnp.int8)
-    ks = jnp.ones((P, nkv), jnp.float32)
+    kp = jnp.zeros((2, P, nkv, pg, hd), jnp.int8)
+    ks = jnp.ones((P, nkv), jnp.float32)  # one layer's scales
     tbl = jnp.zeros((S, W), jnp.int32)
     ln = jnp.zeros((S,), jnp.int32)
 
-    def f(q, kp, vp, tbl, ln, ks, vs):
+    def f(q, kp, vp, a, tbl, ln, ks, vs):
         return ragged_paged_decode_attention(
-            q, kp, vp, tbl, ln, k_scale=ks, v_scale=vs, interpret=False)
+            q, kp, vp, a, tbl, ln, k_scale=ks, v_scale=vs, interpret=False)
 
     exp = jax.export.export(jax.jit(f), platforms=["tpu"])(
-        q, kp, kp, tbl, ln, ks, ks)
+        q, kp, kp, jnp.int32(1), tbl, ln, ks, ks)
     assert exp.platforms == ("tpu",)
 
     b, c = 8, 256
@@ -407,14 +410,14 @@ def test_int8_kernels_tpu_lowering():
     tbl2 = jnp.zeros((b, W), jnp.int32)
     ln2 = jnp.zeros((b,), jnp.int32)
 
-    def g(q, kc, vc, kp, vp, tbl, ln, cr, kso, ksn, vso, vsn):
+    def g(q, kc, vc, kp, vp, a, tbl, ln, cr, kso, ksn, vso, vsn):
         return ragged_paged_prefill_attention(
-            q, kc, vc, kp, vp, tbl, ln, cr,
+            q, kc, vc, kp, vp, a, tbl, ln, cr,
             k_scale_old=kso, k_scale_new=ksn,
             v_scale_old=vso, v_scale_new=vsn, interpret=False)
 
     exp2 = jax.export.export(jax.jit(g), platforms=["tpu"])(
-        q2, kc, kc, kp, kp, tbl2, ln2, ln2, ks, ks, ks, ks)
+        q2, kc, kc, kp, kp, jnp.int32(1), tbl2, ln2, ln2, ks, ks, ks, ks)
     assert exp2.platforms == ("tpu",)
 
 
