@@ -390,12 +390,12 @@ def main() -> int:
 
     import jax
 
-    from mamba_distributed_tpu.utils.platform import configure_compile_cache
-
-    cache_dir = configure_compile_cache()
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        _refuse(f"needs a TPU: jax.devices()[0].platform is {dev.platform!r}")
+    from mamba_distributed_tpu.utils import platform
+    try:
+        platform.init_backend()
+    except SystemExit as e:  # the one owner's reason under this tool's name
+        _refuse(e.code)
+    cache_dir = platform.configure_compile_cache()
     n_dev = len(jax.devices())
 
     phases: dict[str, dict] = {}

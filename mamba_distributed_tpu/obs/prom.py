@@ -21,7 +21,7 @@ without touching engine objects:
 - ``replica_families()`` / ``fabric_families()``: the fabric's metric
   schema — every name emitted here must appear in the
   docs/OBSERVABILITY.md metric table (``scripts/check_metrics_schema.py``
-  is the drift gate, mirroring bench_gate).
+  is the drift gate: names, not numbers).
 - ``parse_exposition()``: a minimal parser for the same format —
   enough for the round-trip unit tests and the schema gate; not a
   general Prometheus client.

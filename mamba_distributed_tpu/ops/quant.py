@@ -158,8 +158,8 @@ def quantize_serving_params(params: dict) -> dict:
 def apply_dtype_overrides(cfg, weight_dtype: str | None = None,
                           kv_dtype: str | None = None):
     """``dataclasses.replace`` the serving dtype knobs when given — the
-    ONE place the bench CLIs' ``--weight-dtype``/``--kv-dtype`` flags
-    land (scripts/bench_serving.py, scripts/bench_decode.py), so a
+    ONE place a caller's weight / KV-page dtype choice would land
+    (no caller since the old bench CLIs went: ROADMAP D9), so a
     future knob (the fp8 follow-on) threads through one function."""
     import dataclasses
 

@@ -6,7 +6,7 @@ replica comes into existence — the seam that lets the same policy loop
 drive an in-process test fabric and a multi-process service fabric:
 
   * ``EngineProvisioner`` builds ``EngineReplica``s locally from shared
-    params/config — tests and the ``bench_serving --autoscale`` harness,
+    params/config — the in-process fabric of tests/test_autoscale.py,
     where a "replica" costs one slot pool;
   * ``ProcessProvisioner`` wraps a spawn callable (the service path:
     ``scripts/serve_fabric.spawn_worker`` -> ``RemoteReplica``) and owns

@@ -6,8 +6,8 @@ into freed slots between ticks — bucketed prefill (inference/bucketing.py)
 plus ``state_cache.insert`` write a request's state into its slot without
 retracing anything.  Decode is weight-bandwidth-bound, so filling more
 slots costs (nearly) nothing per tick: aggregate tokens/sec scales with
-occupancy (docs/SERVING.md; scripts/bench_serving.py measures it against
-sequential ``generate()`` calls).
+occupancy (docs/SERVING.md; the benchmark's serving cells measure it on
+the chip: benchmark/README.md).
 
 Long prompts (``t > cfg.prefill_chunk_tokens``) prefill in CHUNKS
 (serving/prefill.py) interleaved with decode ticks: each ``step()``
@@ -20,7 +20,7 @@ prompts keep the PR-1 behavior: a one-shot pow2-bucketed prefill at
 admission, not counted against the chunk budget (they are at most
 ~chunk-sized by construction).  This bounds both the TTFT of short
 requests and the ITL of running slots while a long prompt streams in —
-the head-of-line blocking ``bench_serving --long-prompt`` measures.
+the head-of-line blocking tests/test_prefill.py pins by tick counts.
 
 Speculative decoding (``cfg.spec_tokens = K > 0``; serving/
 spec_decode.py, docs/SERVING.md "Speculative decoding") swaps the

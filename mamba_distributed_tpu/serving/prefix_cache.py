@@ -23,7 +23,7 @@ serving primitive).  This module is the host-side LRU store:
   * **Full-prompt entries** additionally carry the last logits, so an
     exact prompt repeat (best-of-N sampling, retries, identical
     few-shot questions) skips prefill entirely — zero chunk steps,
-    near-zero TTFT (the ``bench_serving --shared-prefix`` headline).
+    near-zero TTFT (counted in tests/test_prefix_cache.py).
   * **Entries hold device arrays.**  The "host-side" part is the
     bookkeeping: looking up, pinning and LRU-evicting entries costs no
     device sync and no jit trace — a snapshot is just a kept reference

@@ -1,12 +1,10 @@
 """Tiny stdlib HTTP/SSE client for the fabric front end.
 
-Used by the tests, ``scripts/bench_serving.py --service`` and any
-operator tooling that wants to drive the service without pulling in an
-HTTP library: ``http.client`` with ``Connection: close`` streaming —
-the SSE body is read line-by-line off the socket, so TTFT/ITL stamps
-taken here measure the full wire path (HTTP parse + SSE framing + the
-worker RPC hop), which is exactly what the ``service_overhead_cpu``
-bench row prices.
+Used by the tests and any operator tooling that wants to drive the
+service without pulling in an HTTP library: ``http.client`` with
+``Connection: close`` streaming — the SSE body is read line-by-line off
+the socket, so TTFT/ITL stamps taken here measure the full wire path
+(HTTP parse + SSE framing + the worker RPC hop).
 """
 
 from __future__ import annotations

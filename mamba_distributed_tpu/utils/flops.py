@@ -4,7 +4,7 @@ The reference publishes no MFU (SURVEY.md §6); this is the standard
 matmul-dominated accounting: 2*m*n FLOPs per (m x n) matvec per token,
 3x forward for a training step (fwd + 2x bwd), attention causally halved.
 
-Two conventions (both reported by bench.py; docs/KERNELS.md):
+Two conventions (docs/KERNELS.md; the trainer logs ``model``):
 
 - ``hardware``: counts what the chunked SSD algorithm actually executes,
   including the O(chunk) Gram/decay matmuls.  This measures how busy the

@@ -89,28 +89,13 @@ class MetricsLogger:
         )
 
 
-def emit_bench_record(record: dict, json_path: str | None = None) -> None:
-    """Print a bench record as one JSON line and, when ``json_path`` is
-    given, write the same line there — the machine-readable perf-
-    trajectory artifact (BENCH_SERVING.json collects these).  Shared by
-    scripts/bench_serving.py and scripts/bench_decode.py so the two
-    artifacts can never drift in format."""
-    import json
-
-    line = json.dumps(record)
-    print(line, flush=True)
-    if json_path:
-        with open(json_path, "w") as f:
-            f.write(line + "\n")
-
-
 class ServingMetrics:
     """Serving-engine counters: queue depth, slot occupancy, throughput.
 
     The engine (serving/engine.py) calls ``record_prefill`` once per
     admission and ``record_tick`` once per compiled decode tick;
-    ``summary()`` rolls everything up for bench output
-    (scripts/bench_serving.py).  With ``jsonl_path`` set, every tick also
+    ``summary()`` rolls everything up for whoever drives the engine
+    (docs/OBSERVABILITY.md).  With ``jsonl_path`` set, every tick also
     appends one structured record — same one-JSON-object-per-line format
     as MetricsLogger's metrics.jsonl, tagged ``"kind": "serving_tick"``.
 

@@ -44,6 +44,24 @@ def configure_compile_cache() -> str:
     return env_dir or jax.config.jax_compilation_cache_dir
 
 
+def init_backend():
+    """Set-up of a tool that exists to run on the chip; returns the device.
+
+    Places the compile cache, brings the backend up and insists on a
+    TPU: on any other platform the exit is non-zero, the reason is one
+    line on stderr, and nothing has been printed on stdout.
+    """
+    import jax
+
+    configure_compile_cache()
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(
+            f"needs a TPU: jax.devices()[0].platform is {dev.platform!r}"
+        )
+    return dev
+
+
 def key_compile_cache_by_scopes() -> None:
     """Make an operation's names part of the persistent cache's key.
 

@@ -3,7 +3,7 @@
 The fabric's Prometheus schema lives in ONE place — the family
 constructors in ``obs/prom.py`` — and its documentation lives in the
 "Live telemetry plane" metric table of docs/OBSERVABILITY.md.  This
-gate (the bench_gate pattern, applied to names instead of numbers)
+gate (a committed table checked against the code, names not numbers)
 fails CI when the two drift:
 
   1. render a fully-featured synthetic fabric exposition (every

@@ -55,8 +55,8 @@ def spawn_worker(config_path: str, replica_id: int, role: str, *,
                  extra_args: list[str] | None = None,
                  timeout_s: float = 120.0) -> tuple[subprocess.Popen, int]:
     """Spawn one serve_worker.py subprocess; returns (proc, port) once
-    its READY line arrives.  Shared by this CLI, the tests, and
-    ``bench_serving --service``.  ``obs_ring`` sizes the worker's
+    its READY line arrives.  Shared by this CLI and the tests.
+    ``obs_ring`` sizes the worker's
     in-memory span ring (the wire-v5 obs_pull source); ``extra_args``
     passes any further serve_worker flags verbatim."""
     cmd = [sys.executable,

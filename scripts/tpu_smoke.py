@@ -12,10 +12,18 @@ from __future__ import annotations
 import json
 import os
 import sys
+import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from bench import _progress, init_backend  # noqa: E402
+from mamba_distributed_tpu.utils.platform import init_backend  # noqa: E402
+
+_T0 = time.time()
+
+
+def _progress(msg: str) -> None:
+    print(f"[tpu_smoke +{time.time() - _T0:6.1f}s] {msg}", file=sys.stderr,
+          flush=True)
 
 
 def main() -> None:
@@ -24,6 +32,7 @@ def main() -> None:
     import numpy as np
 
     dev = init_backend()
+    _progress(f"backend up: {len(jax.devices())}x {dev.device_kind}")
 
     from mamba_distributed_tpu.ops.pallas import (
         selective_scan_pallas,

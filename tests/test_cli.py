@@ -89,433 +89,6 @@ def test_cli_train_generate_eval_roundtrip(tmp_path):
     assert re.fullmatch(r"16 \d{1,2}/16 [01]\.\d{4}", line), repr(line)
 
 
-@pytest.mark.serving
-def test_bench_serving_long_prompt_smoke(tmp_path):
-    """CI smoke for the chunked-prefill headline bench: ``--long-prompt``
-    must drive BOTH prefill modes end-to-end, report the short/long TTFT
-    split, and leave a tick stream carrying the chunk accounting that
-    obs_report.py renders (ISSUE 3 satellites: bench + CI registration)."""
-    import json
-
-    jsonl = str(tmp_path / "lp.jsonl")
-    env = dict(os.environ)
-    # mamba2-tiny has chunk_size=64, so 64-token prefill chunks are legal;
-    # a 160-token long prompt -> 192-token bucket -> 3 chunks
-    env.update(JAX_PLATFORMS="cpu", SERVE_REQUESTS="2", SERVE_CAPACITY="3",
-               SERVE_PROMPT_MIN="4", SERVE_PROMPT_MAX="8",
-               SERVE_MAX_NEW="4", SERVE_TOKENS_PER_TICK="2",
-               SERVE_LONG_COUNT="1", SERVE_LONG_LEN="160",
-               SERVE_CHUNK_TOKENS="64", SERVE_PREFILL_BUDGET="64")
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_serving.py"),
-         "--long-prompt", "--jsonl", jsonl],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=900,
-    )
-    assert p.returncode == 0, p.stderr[-2000:]
-    rec = json.loads(p.stdout.strip().splitlines()[-1])
-    assert rec["ttft_short_p95_ms_chunked"] is not None
-    assert rec["ttft_short_p95_ms_oneshot"] is not None
-    assert rec["prefill_chunks"] == 3
-    assert rec["prefill_chunk_tokens"] == 64
-    assert rec["prefill_tokens_per_tick"] == 64
-    assert rec["long_prompt_len"] == 160
-    ticks = [json.loads(ln) for ln in open(jsonl)
-             if json.loads(ln).get("kind") == "serving_tick"]
-    assert sum(t.get("prefill_chunk_tokens", 0) for t in ticks) == 192
-    # the stall/chunk columns render through the report tables
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "obs_report.py"),
-         jsonl],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    assert "prefill_stall_ms" in r.stdout
-    assert "prefill chunk tokens" in r.stdout
-
-
-@pytest.mark.serving
-@pytest.mark.lora
-def test_bench_serving_lora_smoke(tmp_path):
-    """CI smoke for the multi-tenant LoRA bench: ``--lora-adapters``
-    must run the mixed-adapter engine and the N sequential single-
-    adapter engines end-to-end (streams asserted identical inside the
-    bench), report the speedup pair, and leave a tick stream whose
-    adapters: line obs_report.py renders (ISSUE 15 satellites)."""
-    import json
-
-    jsonl = str(tmp_path / "lora.jsonl")
-    env = dict(os.environ)
-    env.update(JAX_PLATFORMS="cpu", SERVE_REQUESTS="4", SERVE_CAPACITY="4",
-               SERVE_PROMPT_MIN="6", SERVE_PROMPT_MAX="12",
-               SERVE_MAX_NEW="8", SERVE_TOKENS_PER_TICK="2")
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_serving.py"),
-         "--lora-adapters", "2", "--lora-rank", "4", "--jsonl", jsonl],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=900,
-    )
-    assert p.returncode == 0, p.stderr[-2000:]
-    rec = json.loads(p.stdout.strip().splitlines()[-1])
-    assert rec["adapters"] == 2
-    assert rec["lora_rank"] == 4
-    assert rec["one_engine_tok_s"] > 0
-    assert rec["sequential_tok_s"] > 0
-    assert rec["adapter_cache"]["resident"] == 2
-    ticks = [json.loads(ln) for ln in open(jsonl)
-             if json.loads(ln).get("kind") == "serving_tick"]
-    assert ticks and all("adapters_resident" in t for t in ticks)
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "obs_report.py"),
-         jsonl],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    assert "adapters:" in r.stdout
-
-
-@pytest.mark.serving
-@pytest.mark.spec
-def test_bench_serving_spec_smoke(tmp_path):
-    """CI smoke for the speculative-decoding bench: ``--spec-tokens``
-    must run the K-draft and K=0 engines end-to-end (streams asserted
-    identical inside the bench), report the launches-per-token pair,
-    and leave a tick stream whose speculation line obs_report.py
-    renders (ISSUE 12 satellites: bench + CI registration)."""
-    import json
-
-    jsonl = str(tmp_path / "spec.jsonl")
-    env = dict(os.environ)
-    env.update(JAX_PLATFORMS="cpu", SERVE_REQUESTS="3", SERVE_CAPACITY="2",
-               SERVE_PROMPT_MIN="8", SERVE_PROMPT_MAX="16",
-               SERVE_MAX_NEW="24", SERVE_TOKENS_PER_TICK="2")
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_serving.py"),
-         "--spec-tokens", "3", "--jsonl", jsonl],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=900,
-    )
-    assert p.returncode == 0, p.stderr[-2000:]
-    rec = json.loads(p.stdout.strip().splitlines()[-1])
-    assert rec["spec_tokens"] == 3
-    assert rec["spec_drafter"] == "ngram"
-    assert rec["value"] >= 1.0  # every launch commits >= 1 token/stream
-    assert rec["launches_per_token_baseline"] == 1.0
-    assert rec["launches_per_token_spec"] <= 1.0
-    assert rec["fewer_launches_vs_baseline"] >= 1.0
-    ticks = [json.loads(ln) for ln in open(jsonl)
-             if json.loads(ln).get("kind") == "serving_tick"]
-    assert ticks and all("spec_drafted" in t for t in ticks)
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "obs_report.py"),
-         jsonl],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    assert "speculation:" in r.stdout
-
-
-@pytest.mark.serving
-def test_bench_serving_shared_prefix_smoke(tmp_path):
-    """CI smoke for the prefix-cache headline bench: ``--shared-prefix``
-    must run cache-off and cache-warm end-to-end, report the TTFT
-    split (warm full hits / partial hits / off) and the prefix-cache
-    summary, leave a tick stream carrying the hit/miss gauges that
-    obs_report.py renders, and gate against the committed
-    BENCH_SERVING.json ``shared_prefix_cpu`` row (ISSUE 9 satellite)."""
-    import json
-
-    jsonl = str(tmp_path / "sp.jsonl")
-    json_out = str(tmp_path / "sp.json")
-    env = dict(os.environ)
-    # mamba2-tiny has chunk_size=64 -> 64-token chunks are legal; a
-    # 128-token preamble = 2 shared chunks, 8-token suffixes
-    env.update(JAX_PLATFORMS="cpu", SERVE_REQUESTS="3", SERVE_CAPACITY="2",
-               SERVE_MAX_NEW="4", SERVE_TOKENS_PER_TICK="2",
-               SERVE_SHARED_PREFIX_LEN="128", SERVE_SUFFIX_LEN="8",
-               SERVE_CHUNK_TOKENS="64")
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_serving.py"),
-         "--shared-prefix", "--jsonl", jsonl, "--json", json_out],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=900,
-    )
-    assert p.returncode == 0, p.stderr[-2000:]
-    rec = json.loads(p.stdout.strip().splitlines()[-1])
-    assert rec["ttft_p95_ms_off"] is not None
-    assert rec["ttft_p95_ms_warm"] is not None
-    assert rec["full_hits"] == 3  # every seen prompt skipped prefill
-    assert rec["partial_hits"] >= 1  # fresh suffixes seeded the preamble
-    assert rec["prefix_cache"]["misses"] == 0
-    assert rec["shared_prefix_len"] == 128
-    ticks = [json.loads(ln) for ln in open(jsonl)
-             if json.loads(ln).get("kind") == "serving_tick"]
-    assert sum(t.get("prefix_hits", 0) for t in ticks) == rec["full_hits"] \
-        + rec["partial_hits"]
-    # the gauges render through the report tables
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "obs_report.py"),
-         jsonl],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    assert "prefix cache:" in r.stdout
-    assert "ttft_ms (prefix hit)" in r.stdout
-    # the registered gate path: the committed shared_prefix_cpu row
-    # gates this record's speedup (huge band: the smoke's tiny workload
-    # is a different operating point than the committed default run)
-    g = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_gate.py"),
-         json_out, "--case", "shared_prefix_cpu", "--band", "0.99"],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert g.returncode == 0, g.stdout + g.stderr
-    assert "shared_prefix_cpu" in g.stdout
-
-
-@pytest.mark.serving
-@pytest.mark.disagg
-def test_bench_serving_disagg_smoke(tmp_path):
-    """CI smoke for the disaggregated-tier bench: ``--disagg`` must run
-    the role fabric AND the mixed baseline end-to-end, report the
-    short-request TTFT/ITL split with at least one real migration, and
-    gate against the committed BENCH_SERVING.json ``disagg_cpu`` row
-    (ISSUE 10 satellite)."""
-    import json
-
-    jsonl = str(tmp_path / "dg.jsonl")
-    json_out = str(tmp_path / "dg.json")
-    env = dict(os.environ)
-    # mamba2-tiny has chunk_size=64 -> 64-token chunks; a 160-token
-    # long exceeds the default threshold (= SERVE_PROMPT_MAX = 8), so
-    # it routes to the prefill tier and chunks there
-    env.update(JAX_PLATFORMS="cpu", SERVE_REQUESTS="2", SERVE_CAPACITY="3",
-               SERVE_PROMPT_MIN="4", SERVE_PROMPT_MAX="8",
-               SERVE_MAX_NEW="4", SERVE_TOKENS_PER_TICK="2",
-               SERVE_LONG_COUNT="1", SERVE_LONG_LEN="160",
-               SERVE_CHUNK_TOKENS="64", SERVE_PREFILL_BUDGET="64")
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_serving.py"),
-         "--disagg", "--jsonl", jsonl, "--json", json_out],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=900,
-    )
-    assert p.returncode == 0, p.stderr[-2000:]
-    rec = json.loads(p.stdout.strip().splitlines()[-1])
-    assert rec["ttft_short_p95_ms_disagg"] is not None
-    assert rec["ttft_short_p95_ms_mixed"] is not None
-    assert rec["itl_short_p95_ms_disagg"] is not None
-    assert rec["migrations"] == 1  # the long took the handoff
-    assert rec["migration_ms"]["count"] == 1
-    assert rec["per_replica"]["0"]["migrations_out"] == 1
-    assert rec["disagg_prompt_threshold"] == 8
-    # the timed disagg run's stream carries the migration stamps
-    recs = [json.loads(ln) for ln in open(jsonl)]
-    assert any(r.get("migrations") for r in recs
-               if r.get("kind") == "request")
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "obs_report.py"),
-         jsonl],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    assert "migrations (disaggregated tiers)" in r.stdout
-    # the registered gate path: the committed disagg_cpu row gates this
-    # record's speedup (huge band: the smoke's tiny workload is a
-    # different operating point than the committed default run)
-    g = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_gate.py"),
-         json_out, "--case", "disagg_cpu", "--band", "0.99"],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert g.returncode == 0, g.stdout + g.stderr
-    assert "disagg_cpu" in g.stdout
-
-
-@pytest.mark.serving
-@pytest.mark.compaction
-def test_bench_serving_compaction_smoke(tmp_path):
-    """CI smoke for the occupancy-adaptive compaction bench (ISSUE 14
-    satellite): ``--occupancy ... --compaction`` must time compacted
-    and full-width engines at every fill level (streams asserted
-    identical inside the bench), make the low-fill speedup the
-    headline, leave a tick stream whose compaction line obs_report.py
-    renders, and gate against the committed compaction_occupancy_cpu
-    row."""
-    import json
-
-    json_out = str(tmp_path / "comp.json")
-    jsonl = str(tmp_path / "comp.jsonl")
-    env = dict(os.environ)
-    env.update(JAX_PLATFORMS="cpu", SERVE_CAPACITY="4",
-               SERVE_PROMPT_MIN="4", SERVE_PROMPT_MAX="6",
-               SERVE_MAX_NEW="4", SERVE_TOKENS_PER_TICK="2")
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_serving.py"),
-         "--occupancy", "0.25,1.0", "--compaction",
-         "--json", json_out, "--jsonl", jsonl],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=900,
-    )
-    assert p.returncode == 0, p.stderr[-2000:]
-    rec = json.loads(open(json_out).read().strip())
-    assert rec["metric"].startswith(
-        "serving_compaction_low_occupancy_speedup")
-    assert rec["low_occupancy_target"] == 0.25
-    assert set(rec["compaction_speedup_by_fill"]) == {"0.25", "1.0"}
-    for point in rec["occupancy_sweep"]:
-        assert point["tokens_per_sec_compacted"] > 0
-        assert point["compaction"]["bucket_histogram"]
-    # the 25%-fill point actually narrowed its launches (1 live slot
-    # of 4 -> lane bucket < capacity)
-    low = rec["occupancy_sweep"][0]
-    assert low["compaction"]["ticks_compacted"] > 0
-    assert low["compaction"]["lanes_saved"] > 0
-    # --compaction without --occupancy is a usage error, not a hang
-    p2 = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_serving.py"),
-         "--compaction"],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=120,
-    )
-    assert p2.returncode == 2
-    assert "--occupancy" in p2.stderr
-    # the tick stream renders the report's compaction line
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "obs_report.py"),
-         jsonl],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    assert "compaction:" in r.stdout
-    # gates against the committed row (huge band: the smoke's tiny
-    # workload is a different operating point than the committed run)
-    g = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_gate.py"),
-         json_out, "--case", "compaction_occupancy_cpu", "--band", "0.99"],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert g.returncode == 0, g.stdout + g.stderr
-    assert "compaction_occupancy_cpu" in g.stdout
-
-
-@pytest.mark.serving
-@pytest.mark.pipe_serve
-@pytest.mark.slow
-def test_bench_serving_pipeline_smoke(tmp_path):
-    """CI smoke for the 3-D serving-mesh pipeline bench:
-    ``--stage-shards 2`` must build the pipelined engine AND the
-    equal-device pure-TP comparator on the identical workload (token
-    counts asserted equal inside the bench), stamp the pipeline
-    fields on the record, leave a tick stream whose pipeline line
-    obs_report.py renders, and gate against the committed
-    pipeline_vs_tp_cpu row.  Marked slow like the serve_fabric smoke:
-    it compiles TWO engines in a subprocess — the same surfaces run
-    un-marked in tests/test_pipeline_serving.py through the library
-    entrypoints."""
-    import json
-
-    json_out = str(tmp_path / "pipe.json")
-    jsonl = str(tmp_path / "pipe.jsonl")
-    env = dict(os.environ)
-    env.update(JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=2",
-               SERVE_REQUESTS="4", SERVE_CAPACITY="4",
-               SERVE_PROMPT_MIN="4", SERVE_PROMPT_MAX="6",
-               SERVE_MAX_NEW="4", SERVE_TOKENS_PER_TICK="2")
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_serving.py"),
-         "--stage-shards", "2", "--json", json_out, "--jsonl", jsonl],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=900,
-    )
-    assert p.returncode == 0, p.stderr[-2000:]
-    rec = json.loads(open(json_out).read().strip())
-    assert rec["serving_stage_shards"] == 2
-    assert rec["pure_tp_tokens_per_sec"] > 0
-    assert rec["pipeline_vs_tp_speedup"] > 0
-    # capacity 4 tiles over 2 stages -> the explicit microbatched
-    # clock engaged and billed its warmup/drain ramp
-    assert rec["pipelined_ticks"] >= 1
-    assert rec["bubble_lanes"] > 0
-    # the tick stream renders the report's pipeline line
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "obs_report.py"),
-         jsonl],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    assert "pipeline:" in r.stdout
-    # gates against the committed row (huge band: the smoke's tiny
-    # workload is a different operating point than the committed run)
-    g = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_gate.py"),
-         json_out, "--case", "pipeline_vs_tp_cpu", "--band", "0.99"],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert g.returncode == 0, g.stdout + g.stderr
-    assert "pipeline_vs_tp_cpu" in g.stdout
-
-
-@pytest.mark.serving
-def test_bench_gate_smoke(tmp_path, monkeypatch):
-    """CI smoke for the bench regression gate (ISSUE 7 satellite): a
-    fresh tiny ``bench_serving --json`` run passes against a baseline
-    row inside the noise band, fails against an inflated one, and the
-    goodput/SLO-era record still gates cleanly against the committed
-    BENCH_SERVING.json (--missing-ok covers a metric with no history)."""
-    import json
-
-    fresh = str(tmp_path / "fresh.json")
-    env = dict(os.environ)
-    env.update(JAX_PLATFORMS="cpu", SERVE_REQUESTS="2", SERVE_CAPACITY="2",
-               SERVE_PROMPT_MIN="4", SERVE_PROMPT_MAX="6",
-               SERVE_MAX_NEW="3", SERVE_TOKENS_PER_TICK="3")
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_serving.py"),
-         "--json", fresh],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=900,
-    )
-    assert p.returncode == 0, p.stderr[-2000:]
-    rec = json.loads(open(fresh).read().strip())
-
-    def gate(*args):
-        return subprocess.run(
-            [sys.executable, os.path.join(REPO, "scripts", "bench_gate.py"),
-             fresh, *args],
-            capture_output=True, text=True, cwd=REPO, timeout=120,
-        )
-
-    def baseline(value, speedup=None):
-        path = str(tmp_path / "baseline.json")
-        record = {"metric": rec["metric"], "value": value}
-        if speedup is not None:
-            record["speedup_vs_sequential"] = speedup
-        json.dump({"cases": [{"name": "tiny_smoke", "record": record}]},
-                  open(path, "w"))
-        return path
-
-    # within the band: fresh value sits well above baseline * (1 - band)
-    r = gate("--baseline", baseline(rec["value"] * 0.9), "--band", "0.25")
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "ok" in r.stdout
-    # regression on an extra higher-is-better field: value passes, the
-    # unreachable speedup floor fails the gate
-    r = gate("--baseline", baseline(rec["value"] * 0.9, speedup=1e9),
-             "--band", "0.1", "--field", "speedup_vs_sequential")
-    assert r.returncode == 1, r.stdout + r.stderr
-    assert "REGRESSION" in r.stdout
-    # the committed artifact: a metric with no baseline row anywhere —
-    # rc 2 reports "no baseline" distinctly unless --missing-ok opts
-    # into the new-metric path (in-process to keep the smoke cheap; the
-    # CLI surface is exercised above)
-    monkeypatch.syspath_prepend(os.path.join(REPO, "scripts"))
-    import bench_gate
-
-    fresh2 = str(tmp_path / "fresh2.json")
-    json.dump(dict(rec, metric="serving_metric_with_no_history_smoke"),
-              open(fresh2, "w"))
-    assert bench_gate.main([fresh2, "--band", "0.99"]) == 2
-    assert bench_gate.main([fresh2, "--band", "0.99", "--missing-ok"]) == 0
-    # ...while the default tiny record DOES gate since PR 8: the
-    # tp_vs_replicated_cpu row shares its metric, and "last matching
-    # case wins" picks it up (the stale pre-PR-8 expectation here was
-    # rc 2 — tier-1's one red test between PRs 8 and 9)
-    assert bench_gate.main([fresh, "--band", "0.99"]) == 0
-
-
 def _write_service_cfg(tmp_path):
     """Tiny CPU config JSON shared by the service CLI smokes."""
     from mamba_distributed_tpu.config import ModelConfig
@@ -632,198 +205,6 @@ def test_serve_fabric_cli_smoke(tmp_path):
         assert proc.wait(timeout=120) == 0
     finally:
         proc.kill()
-
-
-@pytest.mark.serving
-@pytest.mark.sessions
-def test_bench_serving_park_smoke(tmp_path):
-    """CI smoke for the durable-session bench (ISSUE 16 satellite):
-    ``--park`` must drive every wave through the disk PARK round trip
-    (parity vs the never-parked engine asserted inside the bench),
-    leave a tick stream whose sessions line obs_report.py renders, and
-    gate against the committed park_resume_cpu row."""
-    import json
-
-    jsonl = str(tmp_path / "park.jsonl")
-    json_out = str(tmp_path / "park.json")
-    env = dict(os.environ)
-    env.update(JAX_PLATFORMS="cpu", SERVE_CAPACITY="2",
-               SERVE_PARK_WAVES="2", SERVE_PROMPT_MIN="4",
-               SERVE_PROMPT_MAX="8", SERVE_MAX_NEW="24",
-               SERVE_TOKENS_PER_TICK="4")
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_serving.py"),
-         "--park", "--jsonl", jsonl, "--json", json_out],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=900,
-    )
-    assert p.returncode == 0, p.stderr[-2000:]
-    rec = json.loads(p.stdout.strip().splitlines()[-1])
-    assert rec["sessions_parked"] >= 1
-    assert rec["value"] == round(rec["sessions_parked"] / 2, 2)
-    assert rec["parked_disk_peak"] == rec["sessions_parked"]
-    assert rec["bytes_disk_peak"] > 0
-    assert rec["resume_ms_p95"] is not None
-    assert rec["parity"] == "token-identical vs never-parked engine"
-    # the timed run's tick stream carries the session gauges and
-    # obs_report renders the sessions line
-    ticks = [json.loads(ln) for ln in open(jsonl)
-             if json.loads(ln).get("kind") == "serving_tick"]
-    assert ticks and all("sessions_parked_host" in t for t in ticks)
-    assert sum(t.get("session_parks", 0)
-               for t in ticks) == rec["sessions_parked"]
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "obs_report.py"),
-         jsonl],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    assert "sessions:" in r.stdout
-    # the registered gate path (huge band: the smoke's tiny workload is
-    # a different operating point than the committed default run)
-    g = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_gate.py"),
-         json_out, "--case", "park_resume_cpu", "--band", "0.99"],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert g.returncode == 0, g.stdout + g.stderr
-    assert "park_resume_cpu" in g.stdout
-
-
-@pytest.mark.serving
-@pytest.mark.tuning
-def test_bench_serving_online_lora_smoke(tmp_path):
-    """CI smoke for the online-tuning bench (ISSUE 20 satellite):
-    ``--online-lora`` must train a tenant's factors on a trainer lane
-    WHILE the same router serves the mixed workload (frozen-base
-    parity vs a never-training fabric asserted inside the bench),
-    deploy the trained version, serve a post-deploy stream under it,
-    and report the SLO-attainment + time-to-deployed pair."""
-    import json
-
-    json_out = str(tmp_path / "ol.json")
-    env = dict(os.environ)
-    env.update(JAX_PLATFORMS="cpu", SERVE_REQUESTS="4", SERVE_CAPACITY="2",
-               SERVE_PROMPT_MIN="4", SERVE_PROMPT_MAX="12",
-               SERVE_MAX_NEW="8", SERVE_TOKENS_PER_TICK="4",
-               SERVE_TUNE_STEPS="2")
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_serving.py"),
-         "--online-lora", "--lora-rank", "4", "--json", json_out],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=900,
-    )
-    assert p.returncode == 0, p.stderr[-2000:]
-    rec = json.loads(p.stdout.strip().splitlines()[-1])
-    assert rec["metric"].startswith("serving_online_lora_slo_attainment")
-    assert 0.0 <= rec["value"] <= 1.0
-    assert rec["deployed"] == "tenant-0"
-    assert rec["time_to_deployed_s"] > 0
-    assert rec["tune_steps"] == 2
-    # warmup job (1 step) + the timed job's 2 steps, all on one lane
-    assert rec["train_steps_total"] == 3
-    assert rec["final_loss"] > 0
-    assert "token-identical" in rec["parity"]
-    assert "post-deploy stream" in rec["adapter_serve"]
-
-
-@pytest.mark.serving
-@pytest.mark.autoscale
-def test_bench_serving_open_loop_smoke(tmp_path):
-    """CI smoke for the open-loop overload bench (ISSUE 18): the
-    ``--open-loop`` mode must calibrate closed-loop, replay the same
-    Poisson arrival schedule shed-off then shed-on, actually shed under
-    2x overload, and gate against the committed overload_shed_cpu row."""
-    import json
-
-    json_out = str(tmp_path / "ov.json")
-    env = dict(os.environ)
-    env.update(JAX_PLATFORMS="cpu", SERVE_OPEN_LOOP_S="2",
-               SERVE_OPEN_LOOP_REPLICAS="1", SERVE_CAPACITY="4",
-               SERVE_PROMPT_MIN="4", SERVE_PROMPT_MAX="8",
-               SERVE_MAX_NEW="8", SERVE_TOKENS_PER_TICK="4")
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_serving.py"),
-         "--open-loop", "--json", json_out],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=900,
-    )
-    assert p.returncode == 0, p.stderr[-2000:]
-    rec = json.loads(p.stdout.strip().splitlines()[-1])
-    assert rec["metric"].startswith("serving_overload_goodput_ratio")
-    assert rec["arrival_process"] == "poisson"
-    assert rec["offered_rate_per_s"] > rec["calibrated_rate_per_s"]
-    # both passes saw the IDENTICAL schedule; only admission differs
-    off, on = rec["shed_off"], rec["shed_on"]
-    assert off["offered"] == on["offered"]
-    assert off["shed"] == 0 and off["completed"] == off["offered"]
-    assert on["shed"] > 0
-    assert on["completed"] + on["shed"] == on["offered"]
-    assert sum(on["sheds_by_reason"].values()) == on["shed"]
-    assert rec["admission"]["sheds"] == on["shed"]
-    assert rec["admission"]["admitted"] == on["completed"]
-    # --autoscale / --arrival outside --open-loop are usage errors
-    p2 = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_serving.py"),
-         "--autoscale"],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=120,
-    )
-    assert p2.returncode == 2
-    assert "--open-loop" in p2.stderr
-    # the registered gate path (huge band: the smoke's tiny workload is
-    # a different operating point than the committed default run)
-    g = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_gate.py"),
-         json_out, "--case", "overload_shed_cpu", "--band", "0.99"],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert g.returncode == 0, g.stdout + g.stderr
-    assert "overload_shed_cpu" in g.stdout
-
-
-@pytest.mark.serving
-@pytest.mark.autoscale
-def test_bench_serving_autoscale_smoke(tmp_path):
-    """CI smoke for the autoscale recovery bench (ISSUE 18): the
-    ``--open-loop --autoscale`` mode must drive a load step through a
-    fixed and an elastic fleet, actually scale up AFTER the step, lose
-    no stream on either pass, and gate against the committed
-    autoscale_step_cpu row."""
-    import json
-
-    json_out = str(tmp_path / "as.json")
-    env = dict(os.environ)
-    env.update(JAX_PLATFORMS="cpu", SERVE_OPEN_LOOP_S="2",
-               SERVE_AUTOSCALE_MAX="2", SERVE_CAPACITY="4",
-               SERVE_PROMPT_MIN="4", SERVE_PROMPT_MAX="8",
-               SERVE_MAX_NEW="8", SERVE_TOKENS_PER_TICK="4")
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_serving.py"),
-         "--open-loop", "--autoscale", "--json", json_out],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=900,
-    )
-    assert p.returncode == 0, p.stderr[-2000:]
-    rec = json.loads(p.stdout.strip().splitlines()[-1])
-    assert rec["metric"].startswith("serving_autoscale_step_goodput")
-    summary = rec["autoscale_summary"]
-    assert summary["scale_ups"] >= 1
-    assert rec["replicas_final"] >= 2
-    # every scale-up is stamped inside the pass (burst attribution is a
-    # noise-sensitive claim — the committed default-scale row pins it)
-    assert len(rec["scale_up_at_s"]) == summary["scale_ups"]
-    assert all(0.0 <= t <= rec["elastic"]["wall_s"] + 1.0
-               for t in rec["scale_up_at_s"])
-    # elastic admission stays open: every offered stream completes on
-    # BOTH passes (the autoscale variant sheds nothing)
-    for side in (rec["fixed"], rec["elastic"]):
-        assert side["shed"] == 0
-        assert side["completed"] == side["offered"]
-    assert rec["fixed"]["tokens"] == rec["elastic"]["tokens"]
-    # the registered gate path (huge band, as above)
-    g = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_gate.py"),
-         json_out, "--case", "autoscale_step_cpu", "--band", "0.99"],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert g.returncode == 0, g.stdout + g.stderr
-    assert "autoscale_step_cpu" in g.stdout
 
 
 @pytest.mark.obs

@@ -42,6 +42,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "scripts"))
 
 from obs_report import build_report, format_report, load_events  # noqa: E402
+from obs_report import main as obs_report_main  # noqa: E402
 
 
 # -------------------------------------------------------------- histogram
@@ -519,6 +520,176 @@ def test_obs_report_round_trip_through_files(tmp_path):
     assert json.loads(out.stdout)["requests"] == json.loads(
         json.dumps(report["requests"])
     )
+
+
+# One driver per feature: run the engine (or the router) in process on
+# the tiny model with the operator's stream on, and return the stream
+# files plus the lines the report must show — each number in them is the
+# engine's own counter, so the block is checked against its source.
+
+
+def _request(prompt_len, max_new, seed=0, **kw):
+    return GenerationRequest(
+        prompt_ids=np.arange(prompt_len, dtype=np.int32) % 7,
+        max_new_tokens=max_new, key=jax.random.PRNGKey(seed), **kw)
+
+
+def _drive_prefill(tmp_path):
+    """Chunked prefill of one long prompt beside a short one."""
+    cfg, params = _tiny_chunked_serving()
+    jsonl = str(tmp_path / "s.jsonl")
+    metrics = ServingMetrics(capacity=2, jsonl_path=jsonl)
+    eng = ServingEngine(params, cfg, capacity=2, tokens_per_tick=2,
+                        metrics=metrics)
+    eng.run([_request(40, 3), _request(5, 3, seed=1)])
+    s = metrics.summary()
+    assert s["prefill_chunks"] == 3  # 40 tokens -> a 48-token bucket
+    return [jsonl], [f"prefill chunk tokens: {s['prefill_chunk_tokens']}",
+                     "prefill_stall_ms"]
+
+
+def _drive_sessions(tmp_path):
+    """Park a decoding stream to disk and resume it on the same engine."""
+    from mamba_distributed_tpu.serving import DiskSessionStore, SessionStore
+    from mamba_distributed_tpu.serving.service import wire
+
+    cfg, params = _tiny_serving()
+    jsonl = str(tmp_path / "s.jsonl")
+    store = SessionStore(disk=DiskSessionStore(str(tmp_path / "park")))
+    metrics = ServingMetrics(capacity=2, jsonl_path=jsonl)
+    eng = ServingEngine(params, cfg, capacity=2, tokens_per_tick=2,
+                        metrics=metrics, session_store=store)
+    eng.submit(_request(4, 3, seed=1))
+    rid = eng.submit(_request(6, 12))
+    eng.step()
+    eng.step()  # both are decoding
+    request, snap = eng.park(rid)
+    sid = store.park({"request": wire.encode_request_tree(request),
+                      "snapshot": snap})
+    payload = store.resume(sid)
+    new_rid = eng.submit_migrated(
+        wire.decode_request_tree(payload["request"]), payload["snapshot"])
+    while eng.pending:
+        eng.step()
+    assert len(eng.results[new_rid].new_tokens) == 12
+    ticks = [r for r in load_events([jsonl]) if r["kind"] == "serving_tick"]
+    assert sum(t.get("session_parks", 0) for t in ticks) == 1
+    se = metrics.summary()["sessions"]
+    assert (se["parks"], se["resumes"]) == (1, 1)
+    return [jsonl], ["sessions: ", "1 parks / 1 resumes / 0 expired"]
+
+
+def _drive_kv_pages(tmp_path):
+    """A hybrid engine: the page pool's gauges ride every tick."""
+    cfg = dataclasses.replace(
+        _tiny_chunked_serving()[0], attn_layer_idx=(1,), attn_num_heads=4,
+        attn_num_kv_heads=2, remat=False, kv_page_tokens=8,
+        kv_slot_tokens=64)
+    params = init_lm_params(jax.random.PRNGKey(0), cfg)
+    jsonl = str(tmp_path / "s.jsonl")
+    metrics = ServingMetrics(capacity=2, jsonl_path=jsonl)
+    eng = ServingEngine(params, cfg, capacity=2, tokens_per_tick=2,
+                        metrics=metrics)
+    eng.run([_request(20, 4), _request(9, 4, seed=1)])
+    assert metrics.peak_kv_pages_used > 0
+    return [jsonl], [f"kv pages: peak {metrics.peak_kv_pages_used}/"
+                     f"{metrics.kv_pages_capacity}"]
+
+
+def _drive_preemptions(tmp_path):
+    """A higher-priority arrival takes the one slot of a decoding stream."""
+    cfg, params = _tiny_serving()
+    jsonl = str(tmp_path / "s.jsonl")
+    metrics = ServingMetrics(capacity=1, jsonl_path=jsonl)
+    eng = ServingEngine(params, cfg, capacity=1, tokens_per_tick=2,
+                        metrics=metrics)
+    eng.submit(_request(9, 12, priority=0))
+    eng.step()
+    eng.step()  # the low-priority request is mid-decode
+    eng.submit(_request(7, 4, seed=1, priority=5))
+    while eng.pending:
+        eng.step()
+    assert metrics.preemptions == 1
+    return [jsonl], ["preemptions: 1"]
+
+
+def _drive_goodput(tmp_path):
+    """Useful tokens against computed lanes, from the engine's own ticks."""
+    cfg, params = _tiny_serving()
+    jsonl = str(tmp_path / "s.jsonl")
+    metrics = ServingMetrics(capacity=2, jsonl_path=jsonl)
+    eng = ServingEngine(params, cfg, capacity=2, tokens_per_tick=2,
+                        metrics=metrics)
+    eng.run([_request(4 + i, 4, seed=i) for i in range(3)])
+    g = metrics.summary()["goodput"]
+    return [jsonl], [f"goodput: {g['useful_tokens']} useful tokens / "
+                     f"{g['wasted_token_lanes']} wasted lanes",
+                     "serving MFU: -"]  # no MFU off a TPU
+
+
+def _drive_slo(tmp_path):
+    """Targets no request can miss: the table counts every request met."""
+    from mamba_distributed_tpu.obs import SLOMonitor
+
+    cfg, params = _tiny_serving()
+    jsonl, events = str(tmp_path / "s.jsonl"), str(tmp_path / "e.jsonl")
+    tracer = SpanTracer(events)
+    eng = ServingEngine(params, cfg, capacity=2, tokens_per_tick=2,
+                        metrics=ServingMetrics(capacity=2, jsonl_path=jsonl),
+                        tracer=tracer,
+                        slo=SLOMonitor(ttft_p95_ms=1e9, window=4,
+                                       tracer=tracer))
+    eng.run([_request(4 + i, 3, seed=i) for i in range(3)])
+    return [jsonl, events], ["== SLO attainment (rolling window 4) ==",
+                             "100.0%"]
+
+
+def _drive_tier_migrations(tmp_path):
+    """Prefill and decode tiers: the long prompt is handed over once."""
+    from mamba_distributed_tpu.serving import RequestRouter
+
+    cfg, params = _tiny_chunked_serving()
+    cfg = dataclasses.replace(cfg, disagg_prompt_threshold=16)
+    jsonl = str(tmp_path / "s.jsonl")
+    router = RequestRouter(params, cfg, num_replicas=2, capacity=2,
+                           tokens_per_tick=2, roles=["prefill", "decode"],
+                           jsonl_path=jsonl)
+    router.run([_request(40, 3), _request(5, 3, seed=1)])
+    assert router.migrations == 1
+    return [jsonl], ["tier migrations: 1 prefill->decode handoff(s)",
+                     "== migrations (disaggregated tiers) =="]
+
+
+def _drive_pipeline(tmp_path):
+    """Two stages over the forced host devices: the microbatched clock."""
+    cfg, params = _tiny_serving()
+    cfg = dataclasses.replace(cfg, serving_stage_shards=2)
+    jsonl = str(tmp_path / "s.jsonl")
+    metrics = ServingMetrics(capacity=4, jsonl_path=jsonl)
+    eng = ServingEngine(params, cfg, capacity=4, tokens_per_tick=2,
+                        metrics=metrics)
+    eng.run([_request(4 + i, 4, seed=i) for i in range(4)])
+    pipe = metrics.summary()["pipeline"]
+    assert pipe["pipelined_ticks"] >= 1 and pipe["bubble_lanes"] > 0
+    return [jsonl], [f"pipeline: 2 stages   {pipe['pipelined_ticks']}/"]
+
+
+@pytest.mark.parametrize("drive", [
+    _drive_prefill, _drive_sessions, _drive_kv_pages, _drive_preemptions,
+    _drive_goodput, _drive_slo, _drive_tier_migrations, _drive_pipeline,
+], ids=lambda drive: drive.__name__.removeprefix("_drive_"))
+def test_report_renders_feature_block(drive, tmp_path, capsys):
+    """The operator's path, feature by feature: engine or router ->
+    ``ServingMetrics(jsonl_path=...)`` -> ``obs_report.py``'s ``main``
+    prints the feature's block with the engine's own counts in it.  (The
+    blocks of the prefix cache, adapters, speculation, compaction and
+    quantisation are rendered by the tests of those features' files.)"""
+    files, wanted = drive(tmp_path)
+    capsys.readouterr()
+    assert obs_report_main(files) == 0
+    text = capsys.readouterr().out
+    for line in wanted:
+        assert line in text, f"{line!r} not in the report:\n{text}"
 
 
 @pytest.mark.fast

@@ -21,9 +21,8 @@ What's covered (docs/SERVING.md "3-D serving mesh"):
   * STABILITY — repeated pipelined ticks reuse one trace per pow2
     lane bucket (TRACE_COUNTS flat; no per-tick recompiles).
 
-The heavy matrix points are marked ``slow`` to keep the tier-1 wall
-budget (the 870s precedent that sized test_tick_compaction): the
-"not slow" subset here is the lean smoke spine.
+The heavy matrix points are marked ``slow``: the "not slow" subset
+here is the lean smoke spine.
 """
 
 import jax
@@ -221,8 +220,7 @@ def test_engine_parity_and_flat_traces_stage2():
     clock engages (pipelined ticks billed bubbles), and repeated
     pipelined ticks reuse ONE trace per pow2 lane bucket —
     TRACE_COUNTS stay flat across ticks at a held bucket.  Marked
-    slow with the rest of the compile-heavy matrix (the PR-17
-    precedent of sorting acceptance e2e past the tier-1 870s wall);
+    slow with the rest of the compile-heavy matrix;
     `pytest -m pipe_serve` runs the whole tier standalone."""
     cfg = tiny_cfg(tick_compaction=True)
     params = init_lm_params(jax.random.PRNGKey(0), cfg)
@@ -230,7 +228,7 @@ def test_engine_parity_and_flat_traces_stage2():
     assert dict(eng.mesh.shape) == {"data": 1, "stage": 2, "model": 1}
     assert eng.stage_shards == 2
     # staggered budgets so occupancy decays through >1 pow2 bucket;
-    # chunked longs ride the slow matrix below (tier-1 wall budget)
+    # chunked longs ride the slow matrix below
     reqs = [GenerationRequest(prompt_ids=rand_prompt(5 + 3 * i, seed=10 + i),
                               max_new_tokens=m, key=jax.random.PRNGKey(100 + i))
             for i, m in enumerate((4, 8, 8))]

@@ -1,5 +1,5 @@
 """Runtime set-up contracts: compile-cache placement, no MFU off a TPU,
-no interpreted kernels on one, and chip_smoke.py's refusal without a chip."""
+no interpreted kernels on one, and the chip tools' refusal without a chip."""
 
 import json
 import os
@@ -58,6 +58,26 @@ def test_peak_flops_has_no_default():
         peak_flops_per_chip(jax.devices()[0])  # "cpu": not in the table
 
 
+def test_flops_conventions():
+    """mfu_model's FLOPs basis must be strictly below the hardware
+    convention for mamba2 (chunked overhead dropped) and identical for
+    mamba1 (already the recurrence)."""
+    from mamba_distributed_tpu.config import get_preset
+
+    m2 = get_preset("mamba2-280m").model
+    from mamba_distributed_tpu.utils.flops import flops_per_token
+
+    hw = flops_per_token(m2, 1024, convention="hardware")
+    model = flops_per_token(m2, 1024, convention="model")
+    assert model < hw
+    m1 = get_preset("mamba1-280m").model
+    assert flops_per_token(m1, 1024, convention="hardware") == flops_per_token(
+        m1, 1024, convention="model"
+    )
+    with pytest.raises(ValueError, match="convention"):
+        flops_per_token(m2, 1024, convention="6nd")
+
+
 def test_no_mfu_off_a_tpu(tmp_path, capsys):
     """Trainer and ServingEngine construct on the CPU, say what they run
     on, and compute no MFU against some TPU's peak."""
@@ -104,14 +124,15 @@ def test_interpret_env_is_an_error_on_a_tpu_backend(monkeypatch):
     assert common.resolve_attn_impl("auto") == "pallas"
 
 
-def test_chip_smoke_refuses_without_a_chip():
+@pytest.mark.parametrize("tool", ["chip_smoke.py", "scripts/tpu_smoke.py"])
+def test_chip_smoke_refuses_without_a_chip(tool):
     """No accelerator: non-zero exit, one line naming the platform, and
-    no result on stdout."""
+    no result on stdout (``platform.init_backend`` owns the refusal)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("MDT_PALLAS_INTERPRET", None)
     env.pop("MDT_ATTN_IMPL", None)
     p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        [sys.executable, os.path.join(REPO, tool)],
         capture_output=True, text=True, env=env, timeout=300,
     )
     assert p.returncode != 0

@@ -5,10 +5,6 @@ bit-identical to a solo ``generate()`` call with the same key no matter
 what admissions/evictions happen around it in the pool.
 """
 
-import os
-import subprocess
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,8 +22,6 @@ from mamba_distributed_tpu.serving import (
 from mamba_distributed_tpu.serving import state_cache
 
 pytestmark = [pytest.mark.serving, pytest.mark.fast]
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def tiny_cfg(layer="mamba2"):
@@ -372,52 +366,6 @@ def test_engine_metrics_report_occupancy(setup):
     assert s["decode_tokens"] == 12 and s["ticks"] >= 2
     assert 0.0 < s["mean_slot_occupancy"] <= 1.0
     assert s["prefills"] == 3
-
-
-# ------------------------------------------------------------------- bench
-
-
-def test_bench_serving_cli_smoke(tmp_path):
-    """The bench entrypoint must run end-to-end and emit one JSON line
-    (same contract as bench_decode; keeps the script from rotting).
-    ``--jsonl`` must leave behind the tick+request stream obs_report.py
-    consumes (satellite: telemetry passthrough)."""
-    import json
-
-    jsonl = str(tmp_path / "serve.jsonl")
-    json_out = str(tmp_path / "serve.json")
-    env = dict(os.environ)
-    env.update(JAX_PLATFORMS="cpu", SERVE_REQUESTS="3", SERVE_CAPACITY="2",
-               SERVE_PROMPT_MIN="4", SERVE_PROMPT_MAX="12",
-               SERVE_MAX_NEW="6", SERVE_TOKENS_PER_TICK="3")
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_serving.py"),
-         "--jsonl", jsonl, "--json", json_out],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=600,
-    )
-    assert p.returncode == 0, p.stderr[-2000:]
-    rec = json.loads(p.stdout.strip().splitlines()[-1])
-    # --json writes the SAME record as a machine-readable artifact
-    assert json.loads(open(json_out).read()) == rec
-    assert rec["value"] > 0 and rec["requests"] == 3
-    assert 0.0 < rec["mean_slot_occupancy"] <= 1.0
-    assert rec["total_new_tokens"] >= 3
-    assert rec["latency"]["ttft_ms"]["count"] == 3
-    assert rec["prefill_tokens_per_sec"] > 0
-    lines = [json.loads(ln) for ln in open(jsonl)]
-    kinds = {ln["kind"] for ln in lines}
-    assert kinds == {"serving_tick", "request"}
-    assert sum(ln["kind"] == "request" for ln in lines) == 3
-    # the stream renders as latency-percentile tables end-to-end
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "obs_report.py"),
-         jsonl, "--json"],
-        capture_output=True, text=True, cwd=REPO, timeout=120,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    report = json.loads(r.stdout)
-    assert report["requests"]["count"] == 3
-    assert report["requests"]["ttft_ms"]["p99"] is not None
 
 
 # ------------------------------------------------- hybrid paged-KV serving

@@ -89,9 +89,9 @@ def run_engine(params, cfg, reqs, capacity=3, **kw):
 
 
 class WrongDrafter(spec_decode.Drafter):
-    """Proposes deliberately wrong tokens (never the model's argmax in
-    a 64-vocab with these seeds): every tick rejects at the first
-    draft — the maximal-rollback worst case."""
+    """Proposes token 1 everywhere: seldom the model's argmax in a
+    64-vocab, so nearly every tick rejects at the first draft.  A test
+    that needs EVERY draft rejected uses ``ContraryDrafter``."""
 
     def observe(self, stream, tokens):
         pass
@@ -101,6 +101,32 @@ class WrongDrafter(spec_decode.Drafter):
 
     def forget(self, stream):
         pass
+
+
+class ContraryDrafter(spec_decode.Drafter):
+    """Wrong by construction: told each stream's prompt length and the
+    tokens a non-speculative greedy engine emits for it (``expect``),
+    it proposes at every position a token other than the one the model
+    will choose — every draft is rejected, whatever the seeds."""
+
+    def __init__(self, vocab):
+        self.vocab = vocab
+        self._truth = {}  # stream -> (prompt length, greedy tokens)
+        self._seen = {}   # stream -> tokens observed (prompt included)
+
+    def expect(self, stream, prompt_len, tokens):
+        self._truth[stream] = (prompt_len, list(tokens))
+
+    def observe(self, stream, tokens):
+        self._seen[stream] = self._seen.get(stream, 0) + len(tokens)
+
+    def draft(self, stream, n):
+        prompt_len, truth = self._truth[stream]
+        pos = self._seen[stream] - prompt_len
+        return [(t + 1) % self.vocab for t in truth[pos:pos + n]]
+
+    def forget(self, stream):
+        self._seen.pop(stream, None)
 
 
 # --------------------------------------------------------- token identity
@@ -261,14 +287,18 @@ def test_rejection_rollback_restores_carries_bitexact():
     pre-tick snapshot (the per-row select keeps the old blocks)."""
     cfg = spec(tiny_cfg())
     params = init_lm_params(jax.random.PRNGKey(0), cfg)
-    eng = ServingEngine(params, cfg, capacity=2, tokens_per_tick=2,
-                        max_top_k=8, drafter=WrongDrafter())
     # SHORT prompts: both admit one-shot in the first step, so the
     # second step is a pure all-reject verify tick (no prefill writes
     # between the snapshot and the comparison), and the pending queues
     # (2 < K+1 trusted tokens) cannot trigger a catch-up advance
-    for r in greedy_requests(mixed_prompts(n=2, lo=4, hi=8), max_new=16):
-        eng.submit(r)
+    prompts = mixed_prompts(n=2, lo=4, hi=8)
+    truth, _ = run_engine(params, tiny_cfg(),
+                          greedy_requests(prompts, max_new=16), capacity=2)
+    drafter = ContraryDrafter(cfg.vocab_size)
+    eng = ServingEngine(params, cfg, capacity=2, tokens_per_tick=2,
+                        max_top_k=8, drafter=drafter)
+    for r, tokens in zip(greedy_requests(prompts, max_new=16), truth):
+        drafter.expect(eng.submit(r), len(r.prompt_ids), tokens)
     eng.step()  # admissions + first verify tick
     before = jax.tree.map(np.asarray, eng.pool["state"]["blocks"])
     events = eng.step()

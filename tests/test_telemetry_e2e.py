@@ -4,10 +4,8 @@ gate, and cross-host obs shipping -> trace export from the controller's
 pulled stream alone.
 
 Reuses the test_service.py Fabric harness (worker subprocesses +
-RemoteReplicas + controller + HTTP front end on loopback).  Sorts after
-the tier-1 870s wall on purpose (the test_tick_compaction precedent —
-worker-subprocess jit warmup is expensive); run directly with
-``pytest -m metrics`` / ``pytest -m service``.
+RemoteReplicas + controller + HTTP front end on loopback).  Run directly
+with ``pytest -m metrics`` / ``pytest -m service``.
 """
 
 import json

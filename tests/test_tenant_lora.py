@@ -27,9 +27,7 @@ The contract under test, per ISSUE 15's acceptance criteria:
     zero jit signatures across a repeated mixed-adapter workload (one
     compiled tick shape regardless of how many adapters are live).
 
-Runnable standalone: ``pytest -m lora``.  (This file sorts after
-test_quant_serving so the heavy matrix lands past the tier-1 wall
-cutoff — it costs zero tier-1 dots but runs in full via its marker.)
+Runnable standalone: ``pytest -m lora``.
 """
 
 import dataclasses

@@ -24,9 +24,8 @@ The contract under test:
   * OFF-BY-DEFAULT — ``tick_compaction=False`` is byte-stable: no
     gather/scatter traces, no record stamps, summary block None.
 
-Runnable standalone: ``pytest -m compaction``.  (This file sorts after
-test_quant_serving.py on purpose — the tier-1 wall-clock budget; the
-heaviest parity matrices are additionally marked ``slow``.)
+Runnable standalone: ``pytest -m compaction``.  (The heaviest parity
+matrices are marked ``slow``.)
 """
 
 import dataclasses
