@@ -280,28 +280,6 @@ class ModelConfig:
     # Longest suffix n-gram the "ngram" drafter matches against the
     # stream's history before falling back to shorter ones.
     spec_ngram_order: int = 3
-    # --- occupancy-adaptive compacted ticks (serving/engine.py;
-    # docs/SERVING.md "Occupancy-adaptive ticks") ---
-    # Compact each decode/verify tick to the LIVE slots: gather the
-    # decodable slots (conv/SSM carries, logits, meta, page-table rows)
-    # into a pow2 lane bucket — per data shard, so the mesh-sharded
-    # pool keeps its tiling — run the existing jitted tick at bucket
-    # width, and scatter the results back.  Compute per tick then
-    # tracks live slots instead of static capacity (the batch-axis
-    # analogue of what paged KV does for cache bytes), which is where
-    # low/medium-occupancy traffic wins.  One compiled shape per pow2
-    # bucket (same trace discipline as the prompt buckets); token
-    # streams are bit-identical to the uncompacted tick by construction
-    # (same per-row math, fewer pad rows).  False (default) is the
-    # byte-stable status quo: no gather/scatter, identical traces,
-    # identical records.
-    tick_compaction: bool = False
-    # Shrink hysteresis for the compacted-tick lane bucket: the bucket
-    # GROWS immediately when live slots need it, but only shrinks after
-    # this many consecutive ticks that would have fit the smaller
-    # bucket — occupancy jitter around a pow2 boundary must not thrash
-    # gather/tick/scatter recompiles.  0 shrinks immediately.
-    compaction_hysteresis_ticks: int = 4
     # --- multi-tenant LoRA serving (serving/adapters.py; docs/
     # SERVING.md "Multi-tenant LoRA") ---
     # Named LoRA adapters one engine may serve concurrently.  0
@@ -502,12 +480,6 @@ class ModelConfig:
             raise ValueError(
                 f"serving_stage_shards must be >= 1, got "
                 f"{self.serving_stage_shards}"
-            )
-        if self.compaction_hysteresis_ticks < 0:
-            raise ValueError(
-                f"compaction_hysteresis_ticks must be >= 0 (0 shrinks the "
-                f"lane bucket immediately), got "
-                f"{self.compaction_hysteresis_ticks}"
             )
         if self.disagg_prompt_threshold < 0:
             raise ValueError(

@@ -1,7 +1,7 @@
 """XLA compile watchdog: count and time every backend compile.
 
-The repo's pow2 bucketing (prompt buckets, tick compaction's lane
-buckets, spec lanes) exists to BOUND recompiles — which makes silent
+The repo's pow2 bucketing (prompt buckets, the decode tick's lane
+ladder, spec lanes) exists to BOUND recompiles — which makes silent
 recompile thrash the production failure mode nothing watched until
 now: a config that defeats the bucketing (or an occupancy pattern that
 oscillates across a pow2 boundary) turns every tick into a multi-ms

@@ -374,9 +374,9 @@ def validate_serving_stage_shards(cfg, stage_shards: int) -> None:
     need ``n_layer % stage_shards == 0``; hybrid stacks need both the
     mamba stack (``n_layer - n_attn``) and the attention stack
     (``n_attn``) to divide — a stage owns whole layers of each family.
-    Tick compaction is NOT required: the microbatched schedule
+    No rung of the tick's ladder is required: the microbatched schedule
     (parallel/pipeline.pipelined_decode_layers) buckets whatever lane
-    width the launch runs at, compacted or full-capacity, and launches
+    width the launch runs at, narrow or full-capacity, and launches
     the schedule cannot microbatch fall back to the stage-sharded
     GSPMD scan.  ``cfg`` is a ModelConfig."""
     if stage_shards <= 1:
@@ -427,11 +427,11 @@ def slot_pool_specs(pool, num_shards: int, stage_shards: int = 1):
     fallback keeps arbitrary pools valid).  Weights are NOT covered
     here — serving replicates them (``NamedSharding(mesh, P())``).
 
-    The COMPACTED-tick lane trees ride the same rules (the bucketed
-    slot-pool constraint): ``state_cache.gather_slots``/
-    ``scatter_slots`` pass their ``{"blocks", "logits", "meta"}``
-    trees through here with the lane bucket in place of the slot
-    axis — the engine keeps the bucket a multiple of the data-shard
+    A NARROW tick's lane trees ride the same rules (the bucketed
+    slot-pool constraint): ``state_cache.gather_rows``/
+    ``scatter_rows`` pass their ``{"blocks", "logits", "meta"}``
+    trees through here with the rung's lanes in place of the slot
+    axis — the engine keeps a rung a multiple of the data-shard
     count and maps each shard's live slots onto that shard's lanes,
     so a compact lane tree tiles over ``data`` exactly like the full
     pool it was gathered from (docs/SERVING.md "Occupancy-adaptive

@@ -50,7 +50,7 @@ vs x@W + (x@A)@B), so bit-exactness is the wrong pin here; greedy
 tokens agree exactly on the fp32 CPU matrix (tests/test_tenant_lora.py
 pins zero disagreements across mamba1/mamba2/hybrid, chunked longs,
 (2,2) TP, prefix-warm, preempt/resume, migration, spec K>0 and
-tick compaction).
+narrow ticks).
 
 Quantized int8 base weights + a LoRA delta is a ROADMAP residual — the
 engine rejects the combination with a named error rather than silently
@@ -601,7 +601,7 @@ def bind_adapter_ids(params, ids: jax.Array):
     subtree (called INSIDE the compiled tick/prefill/verify steps —
     pure tree surgery at trace time).  ``ids`` is the launch's (b,)
     int32 row->cache-slot map (the slot pool's ``meta["adapter_id"]``,
-    compacted to lane order when the tick is compacted).  Stacked
+    gathered into lane order when the tick is narrow).  Stacked
     targets broadcast the ids over their leading layer axis so the
     scan-over-layers slices a per-layer copy alongside the factors.
     Trees without ``"lora"`` subtrees pass through untouched — the

@@ -113,6 +113,30 @@ from mamba_distributed_tpu.utils.platform import (
 # tests/test_prefill.py).
 TRACE_COUNTS = {"prefill": 0, "tick": 0}
 
+# The decode tick's lane ladder (docs/SERVING.md "Occupancy-adaptive
+# ticks").  A data shard's rungs start at RUNG_FLOOR_LANES, double, and end
+# at its share of the capacity.  Under 8 lanes nothing is saved: the
+# projections pad their rows to 8 sublanes and a sub-step's weight read does
+# not shrink, while every rung costs one more program in each set-up.  A
+# rung is left for a narrower one only after RUNG_HYSTERESIS_TICKS
+# consecutive ticks that would have fitted it (it is taken for a wider one
+# at once).
+RUNG_FLOOR_LANES = 8
+RUNG_HYSTERESIS_TICKS = 4
+
+
+def tick_rungs(capacity: int, num_shards: int = 1) -> tuple[int, ...]:
+    """The widths a decode tick may launch at, narrowest first: the floor a
+    shard, doubled while under the shard's slots, then the capacity.  It
+    depends on nothing but what is given: 96 -> 8, 16, 32, 64, 96; 16 -> 8,
+    16; an engine of at most the floor's slots a shard has one rung."""
+    per = capacity // num_shards
+    lanes, b = [], RUNG_FLOOR_LANES
+    while b < per:
+        lanes.append(b)
+        b *= 2
+    return tuple(n * num_shards for n in lanes) + (capacity,)
+
 
 @functools.partial(jax.jit, static_argnames=("cfg", "mesh"))
 def _prefill(params: dict, ids: jax.Array, mask: jax.Array, cfg: ModelConfig,
@@ -142,7 +166,7 @@ def _prefill(params: dict, ids: jax.Array, mask: jax.Array, cfg: ModelConfig,
     jax.jit, static_argnames=("cfg", "k_max", "steps", "mesh", "n_micro"),
     donate_argnums=(1,),
 )
-def _tick(params: dict, pool: dict, tbl=None, lengths=None, *,
+def _tick(params: dict, pool: dict, tbl=None, lengths=None, lanes=None, *,
           cfg: ModelConfig, k_max: int, steps: int, mesh=None,
           n_micro=None):
     """Advance every slot ``steps`` tokens.  Returns (pool', tokens
@@ -151,6 +175,18 @@ def _tick(params: dict, pool: dict, tbl=None, lengths=None, *,
     slot's finish state after it; the rest is masked garbage.  The host
     consumes ``done`` rather than re-deriving the finish rule, so there
     is exactly one copy of it (here).
+
+    ``lanes`` — ``(idx, keep)``, (W,) int32 and (W,) bool, both traced —
+    makes the launch a NARROW rung of the engine's ladder: the rows of
+    slots ``idx`` are gathered into W lanes, the sub-steps run at lane
+    width (``tbl``/``lengths`` are then the lanes' rows, and the three
+    outputs are (steps, W)), and the advanced lanes are written back into
+    the donated pool row by row, pad lanes (``~keep``) dropped
+    (``state_cache.gather_rows`` / ``scatter_rows``).  Gather, sub-steps
+    and write-back are one program, so one dispatch a tick at any rung;
+    ``lanes=None`` is the ladder's last rung, the whole pool, with no
+    gather and no write-back.  Per-row mathematics is the same at every
+    rung.
 
     HYBRID stacks additionally take the host-owned paged-KV metadata:
     ``tbl`` (S, B) int32 — page-table rows sliced to the tick's page
@@ -226,6 +262,14 @@ def _tick(params: dict, pool: dict, tbl=None, lengths=None, *,
             lengths = jax.lax.with_sharding_constraint(
                 lengths, slot_axis_sharding(mesh)
             )
+    whole = None
+    if lanes is not None:
+        # the pool proper stays behind as ``whole``; the sub-steps see the
+        # lanes (the hybrid's shared page pool has no slot axis and rides
+        # along as it is)
+        whole = pool
+        pool = _with_slot_rows(pool, state_cache.gather_rows(
+            _slot_rows(pool), *lanes, mesh=mesh))
     # multi-tenant LoRA (serving/adapters.py): bind each slot's factor-
     # pool row from the pool meta into the attached pools — a no-op
     # tree walk on LoRA-less params (no "lora" subtrees), and the ids
@@ -291,7 +335,25 @@ def _tick(params: dict, pool: dict, tbl=None, lengths=None, *,
         (pool, _), (tokens, emitted, done) = jax.lax.scan(
             one, (pool, lengths), None, length=steps
         )
+    if whole is not None:
+        pool = _with_slot_rows(pool, state_cache.scatter_rows(
+            _slot_rows(whole), _slot_rows(pool), *lanes, mesh=mesh))
     return pool, tokens, emitted, done
+
+
+def _slot_rows(pool: dict) -> dict:
+    """A pool's per-slot subtrees, as ``state_cache``'s gather and
+    write-back see them (``attn_blocks``, the shared page pool, has no slot
+    axis and rides the launch's own donation)."""
+    return {"blocks": pool["state"]["blocks"], "logits": pool["logits"],
+            "meta": pool["meta"]}
+
+
+def _with_slot_rows(pool: dict, rows: dict) -> dict:
+    """``pool`` with its per-slot subtrees replaced by ``rows`` (the
+    inverse of ``_slot_rows``; whatever else the state holds stays)."""
+    return {"state": {**pool["state"], "blocks": rows["blocks"]},
+            "logits": rows["logits"], "meta": rows["meta"]}
 
 
 class ServingEngine:
@@ -714,22 +776,17 @@ class ServingEngine:
                 weight_dtype=cfg.serving_weight_dtype,
                 kv_dtype=cfg.kv_page_dtype,
             )
-        # --- occupancy-adaptive compacted ticks (docs/SERVING.md
-        # "Occupancy-adaptive ticks"): cfg.tick_compaction gathers the
-        # LIVE slots into a pow2 lane bucket per data shard, runs the
-        # existing tick/verify jit at bucket width, scatters back.
-        # Off (default) is the byte-stable status quo — no gather/
-        # scatter traces, no record stamps.
-        self.compaction = cfg.tick_compaction
-        if self.compaction:
-            # current per-shard lane bucket (pow2): grows immediately
-            # when live slots need it, shrinks only after
-            # cfg.compaction_hysteresis_ticks consecutive smaller-
-            # sufficient ticks so occupancy jitter around a pow2
-            # boundary can't thrash gather/tick/scatter recompiles
-            self._compact_bucket = 1
-            self._shrink_streak = 0
-            self.metrics.configure_compaction()
+        # --- the decode tick's lane ladder (docs/SERVING.md
+        # "Occupancy-adaptive ticks"): every tick launches at the
+        # narrowest rung that holds the decodable slots, the whole pool
+        # being the last rung.  ``_rung`` indexes the rung in use (taken
+        # wider at once, narrower only after RUNG_HYSTERESIS_TICKS ticks
+        # that would have fitted); ``_warm_shapes`` holds the launch shapes
+        # (hybrid: page-count buckets) whose whole ladder has been run.
+        self._rungs = tick_rungs(capacity, self.num_shards)
+        self._rung = 0
+        self._shrink_streak = 0
+        self._warm_shapes: set = set()
         # --- multi-tenant LoRA serving (serving/adapters.py; docs/
         # SERVING.md "Multi-tenant LoRA"): cfg.lora_max_adapters > 0
         # attaches bounded device factor pools to the decode params and
@@ -2359,118 +2416,93 @@ class ServingEngine:
         """Requests not yet finished (queued + in-flight)."""
         return self.scheduler.depth + len(self._slots)
 
-    # --------------------------------------------------- compacted ticks
+    # ------------------------------------------------- the lane ladder
 
-    def _compaction_width(self, live_slots) -> int | None:
-        """Lane width of this tick's compacted launch, or None for the
-        plain full-width tick (compaction off, or the bucket would not
-        be narrower than capacity).  The bucket is a pow2 over the
-        BUSIEST data shard's live count — every shard gets the same
-        lane count so the compact tree tiles over the data axis exactly
-        like the full pool — grown immediately, shrunk only after
-        ``cfg.compaction_hysteresis_ticks`` consecutive ticks that
-        would have fit the smaller bucket."""
-        if not self.compaction:
-            return None
-        per = self.capacity // self.num_shards
+    def _tick_width(self, live_slots) -> int:
+        """Lane width of this tick's launch: the narrowest rung of the
+        ladder that holds the BUSIEST data shard's live slots — every
+        shard gets the same lane count, so the lanes tile over the data
+        axis exactly like the full pool — taken wider at once, narrower
+        only after ``RUNG_HYSTERESIS_TICKS`` consecutive ticks that would
+        have fitted the narrower rung.  The last rung is the capacity."""
         by_shard = [0] * self.num_shards
         for s in live_slots:
             by_shard[self._slot_shard(s)] += 1
-        need = next_pow2_bucket(max(1, max(by_shard)), min_bucket=1)
-        b = self._compact_bucket
-        if need > b:
-            b = need
+        lanes = max(by_shard) * self.num_shards
+        need = next(i for i, w in enumerate(self._rungs) if w >= lanes)
+        if need > self._rung:
+            self._rung = need
             self._shrink_streak = 0
-        elif need < b:
+        elif need < self._rung:
             self._shrink_streak += 1
-            if self._shrink_streak >= self.cfg.compaction_hysteresis_ticks:
-                b = need
+            if self._shrink_streak >= RUNG_HYSTERESIS_TICKS:
+                self._rung = need
                 self._shrink_streak = 0
         else:
             self._shrink_streak = 0
-        self._compact_bucket = b
-        if b >= per:
-            return None  # full width: the existing tick IS the launch
-        return b * self.num_shards
+        return self._rungs[self._rung]
 
-    def _compact_maps(self, live_slots, width: int):
-        """Host-side lane maps for one compacted launch: ``idx`` (W,)
-        gathers lane j from slot idx[j] (pad lanes repeat their shard's
-        first slot — garbage lanes the scatter never reads), ``inv``/
-        ``touched`` (S,) scatter lane inv[s] back into live slot s, and
-        ``lanes`` maps slot -> lane for the host-side token plumbing.
-        Shard d's live slots land in lanes [d*b, d*b + n_d): the gather
-        is shard-local, so the mesh-sharded pool's tiling survives
-        compaction."""
+    def _lane_maps(self, live_slots, width: int):
+        """Host-side lane maps for one narrow launch: ``idx`` (W,) gathers
+        lane j from slot idx[j], ``keep`` (W,) marks the lanes that carry
+        a live slot (a pad lane repeats its shard's first slot, runs
+        inactive and is dropped by the write-back), and ``lanes`` maps
+        slot -> lane for the host-side token plumbing.  Shard d's live
+        slots land in lanes [d*b, d*b + n_d): the gather is shard-local,
+        so the mesh-sharded pool's tiling survives."""
         b = width // self.num_shards
         per = self.capacity // self.num_shards
-        idx = np.zeros((width,), np.int32)
-        inv = np.zeros((self.capacity,), np.int32)
-        touched = np.zeros((self.capacity,), bool)
+        idx = np.repeat(np.arange(self.num_shards, dtype=np.int32) * per, b)
+        keep = np.zeros((width,), bool)
         lanes: dict[int, int] = {}
         fill = [d * b for d in range(self.num_shards)]
-        for d in range(self.num_shards):
-            idx[d * b : (d + 1) * b] = d * per  # pad default, in-shard
         for s in sorted(live_slots):
             d = self._slot_shard(s)
             lane = fill[d]
             fill[d] += 1
             idx[lane] = s
-            inv[s] = lane
-            touched[s] = True
+            keep[lane] = True
             lanes[s] = lane
-        return idx, inv, touched, lanes
+        return idx, keep, lanes
 
-    def _compact_rows(self):
-        """The full pool's per-slot subtrees, as gather/scatter see
-        them (``attn_blocks`` — the shared page pool — has no slot axis
-        and rides the tick's own donation instead)."""
-        return {
-            "blocks": self.pool["state"]["blocks"],
-            "logits": self.pool["logits"],
-            "meta": self.pool["meta"],
-        }
-
-    def _compact_page_meta(self, idx, lanes, spare: bool):
-        """Compacted page table + lengths for a hybrid launch: the live
-        slots' rows in lane order, pad lanes pointing at the trash page
-        with length 0.  The page-count bucket is the pow2 of the
-        largest LIVE allocation (+1 spare trash column in spec mode,
-        exactly like the full-width tick), so attention reads scale
-        with what the compacted lanes actually hold."""
+    def _page_bucket(self, slots, spare: bool = False) -> int:
+        """Page-count BUCKET of a hybrid launch over ``slots``: the pow2
+        of their largest allocation (+1 spare trash column in spec mode),
+        so attention reads scale with what the launch's lanes hold (one
+        trace per bucket and rung; bucket width changes never perturb
+        token streams — masked attention is bit-stable across page-bucket
+        widths, models/attention.py)."""
         largest = max(
-            (len(self._slots[s].pages) for s in lanes
+            (len(self._slots[s].pages) for s in slots
              if self._slots[s].pages),
             default=1,
         )
-        bucket = min(
+        return min(
             next_pow2_bucket(largest + (1 if spare else 0), min_bucket=1),
             self._page_tbl.shape[1],
         )
+
+    def _lane_page_meta(self, idx, keep, bucket: int):
+        """Page table + lengths of a narrow hybrid launch: the live slots'
+        rows in lane order, pad lanes pointing at the trash page with
+        length 0."""
         ctbl = self._page_tbl[idx, :bucket].copy()
         clen = self._kv_len[idx].copy()
-        pad = np.ones((len(idx),), bool)
-        pad[list(lanes.values())] = False
-        ctbl[pad] = 0
-        clen[pad] = 0
+        ctbl[~keep] = 0
+        clen[~keep] = 0
         return ctbl, clen
 
-    def _scatter_pool(self, new_cpool_state, compact_out, inv, touched):
-        """Reassemble ``self.pool`` from a compacted launch's output:
-        scatter the per-slot lanes back (donating the old full-width
-        rows) and carry the page pool forward from the launch's own
-        donation."""
+    def _scatter_pool(self, new_state, lanes_out, idx, keep):
+        """Reassemble ``self.pool`` from a narrow SPECULATIVE launch's
+        output: write the lanes back into the donated full-width rows and
+        carry the page pool forward from the launch's own donation."""
         res = state_cache.scatter_slots(
-            self._compact_rows(), compact_out,
-            jnp.asarray(inv), jnp.asarray(touched), mesh=self.mesh,
+            _slot_rows(self.pool), lanes_out,
+            jnp.asarray(idx), jnp.asarray(keep), mesh=self.mesh,
         )
-        state = {"blocks": res["blocks"]}
-        if self.hybrid:
-            state["attn_blocks"] = new_cpool_state["attn_blocks"]
-        self.pool = {"state": state, "logits": res["logits"],
-                     "meta": res["meta"]}
+        self.pool = _with_slot_rows({"state": new_state}, res)
 
-    def _pipeline_micro(self, width: int | None) -> int | None:
+    def _pipeline_micro(self, width: int) -> int | None:
         """Microbatch count for the explicit GPipe decode schedule, or
         None for the GSPMD layer scan.
 
@@ -2486,78 +2518,102 @@ class ServingEngine:
         executes the sequential scan — the bitwise-identical fallback.
 
         ``n_micro = stage_shards`` when the launch width tiles over
-        the stages (the pow2 compaction buckets make this the common
+        the stages (the ladder's doubling rungs make this the common
         case), else 1 (a sequential flush — still one trace per
-        bucket, so TRACE_COUNTS stay flat across repeated ticks)."""
+        rung, so TRACE_COUNTS stay flat across repeated ticks)."""
         if (self.stage_shards <= 1 or self.hybrid or self.spec
                 or self.lora or self.model_shards > 1
                 or self.num_shards > 1):
             return None
-        w = self.capacity if width is None else width
-        return (self.stage_shards if w % self.stage_shards == 0
+        return (self.stage_shards if width % self.stage_shards == 0
                 else 1)
 
-    def _compact_tick(self, live_slots, width: int, n_micro=None):
-        """One COMPACTED decode tick: gather the live slots' rows into
-        ``width`` lanes, run the identical ``_tick`` jit at lane width
-        (one trace per pow2 bucket), scatter the advanced rows back,
-        and expand the token matrices to slot indexing for the shared
-        event plumbing.  Pad lanes repeat an in-shard slot's rows and
-        compute garbage — their hybrid KV writes land on the trash page
-        (their compacted table rows are zeroed) and nothing ever reads
-        them back.  Per-row math is the full tick's, so streams are
-        bit-identical to the uncompacted engine (tests/
-        test_tick_compaction.py)."""
-        idx, inv, touched, lanes = self._compact_maps(live_slots, width)
-        gathered = state_cache.gather_slots(
-            self._compact_rows(), jnp.asarray(idx), mesh=self.mesh,
+    def _run_tick(self, live_slots, width: int, bucket=None):
+        """One decode tick at rung ``width``: a single ``_tick`` launch.
+        Under the capacity the launch is NARROW — the live slots' rows
+        gathered into ``width`` lanes, advanced, and written back into
+        the donated pool, all inside the one program; pad lanes run
+        inactive, their hybrid KV writes land on the trash page (their
+        table rows are zeroed) and nothing reads them back — and the
+        token matrices are expanded to slot indexing for the shared
+        event plumbing.  At the capacity it is the whole pool, as it
+        stands.  Per-row math is the same at every rung, so streams are
+        bit-identical whatever the ladder (tests/test_tick_compaction.py).
+
+        With no ``live_slots`` the launch is the ladder's warm-up: every
+        lane pad (at the capacity: every slot parked by ``idle_meta``),
+        nothing advanced, nothing written, nothing fetched."""
+        narrow = width < self.capacity
+        warm = not live_slots
+        pool, tick_kv, lanes_dev, parked = self.pool, (), None, None
+        if narrow:
+            idx, keep, lanes = self._lane_maps(live_slots, width)
+            lanes_dev = (jnp.asarray(idx), jnp.asarray(keep))
+            if self.hybrid:
+                tick_kv = self._lane_page_meta(idx, keep, bucket)
+        else:
+            if self.hybrid:
+                tick_kv = (self._page_tbl[:, :bucket], self._kv_len)
+            if warm:
+                parked = pool["meta"]  # kept out of the launch's donation
+                pool = {**pool, "meta": state_cache.idle_meta(parked)}
+        pool, tokens, emitted, done = _tick(
+            self._params, pool, *map(jnp.asarray, tick_kv),
+            lanes=lanes_dev, cfg=self.cfg, k_max=self.max_top_k,
+            steps=self.tokens_per_tick, mesh=self.mesh,
+            n_micro=self._pipeline_micro(width),
         )
-        cpool = {"state": {"blocks": gathered["blocks"]},
-                 "logits": gathered["logits"], "meta": gathered["meta"]}
-        tick_kv = ()
-        if self.hybrid:
-            # the shared page pool has no slot axis: it rides the
-            # tick's donation exactly as in the full-width launch
-            cpool["state"]["attn_blocks"] = \
-                self.pool["state"]["attn_blocks"]
-            ctbl, clen = self._compact_page_meta(idx, lanes, spare=False)
-            tick_kv = (jnp.asarray(ctbl), jnp.asarray(clen))
-        new_cpool, tokens, emitted, done = _tick(
-            self._params, cpool, *tick_kv, cfg=self.cfg,
-            k_max=self.max_top_k, steps=self.tokens_per_tick,
-            mesh=self.mesh, n_micro=n_micro,
-        )
-        self._scatter_pool(
-            new_cpool["state"],
-            {"blocks": new_cpool["state"]["blocks"],
-             "logits": new_cpool["logits"], "meta": new_cpool["meta"]},
-            inv, touched,
-        )
+        if parked is not None:
+            pool = {**pool, "meta": parked}
+        self.pool = pool
+        if warm:
+            return None
         tokens = np.asarray(tokens)  # (steps, width) — the host sync
         emitted = np.asarray(emitted)
         done = np.asarray(done)
-        steps = tokens.shape[0]
-        cols = np.fromiter(lanes.keys(), np.int64, len(lanes))
-        ls = np.fromiter(lanes.values(), np.int64, len(lanes))
-        tokens_f = np.zeros((steps, self.capacity), tokens.dtype)
-        emitted_f = np.zeros((steps, self.capacity), bool)
-        done_f = np.zeros((steps, self.capacity), bool)
-        tokens_f[:, cols] = tokens[:, ls]
-        emitted_f[:, cols] = emitted[:, ls]
-        done_f[:, cols] = done[:, ls]
+        if narrow:
+            steps = tokens.shape[0]
+            cols = np.fromiter(lanes.keys(), np.int64, len(lanes))
+            ls = np.fromiter(lanes.values(), np.int64, len(lanes))
+            tokens_f = np.zeros((steps, self.capacity), tokens.dtype)
+            emitted_f = np.zeros((steps, self.capacity), bool)
+            done_f = np.zeros((steps, self.capacity), bool)
+            tokens_f[:, cols] = tokens[:, ls]
+            emitted_f[:, cols] = emitted[:, ls]
+            done_f[:, cols] = done[:, ls]
+            tokens, emitted, done = tokens_f, emitted_f, done_f
         if self.hybrid:
-            # the device-side lengths advance, mirrored at full width
-            self._kv_len += emitted_f.sum(axis=0).astype(np.int32)
-        return tokens_f, emitted_f, done_f
+            # mirror the device-side lengths advance: +1 per live
+            # sub-step, exactly what `emitted` marks
+            self._kv_len += emitted.sum(axis=0).astype(np.int32)
+        return tokens, emitted, done
 
-    def _spec_tick(self, width: int | None = None):
+    def _warm_ladder(self, width: int, bucket) -> None:
+        """A launch shape the engine has not run brings its whole ladder
+        with it: before the first tick (hybrid: the first at a new
+        page-count bucket) every OTHER rung is run once at that shape,
+        through the real launch path, with nothing live.  A burst that
+        later needs a wider rung then finds its program compiled (or
+        loaded from the cache) — no compilation in the middle of
+        serving, whatever the traffic before it reached."""
+        if bucket in self._warm_shapes:
+            return
+        self._warm_shapes.add(bucket)
+        for w in self._rungs:
+            if w != width:
+                self._run_tick((), w, bucket)
+
+    def _spec_tick(self, width: int):
         """One speculative draft-verify tick (serving/spec_decode.py).
 
-        ``width`` (from ``_compaction_width``) compacts the launch to
-        the live lanes: the feed/verify/commit all run at lane width
-        and the committed lanes scatter back — the same per-row math at
-        a narrower batch, so the compacted spec stream is bit-identical
-        to the full-width one (and to plain greedy).
+        ``width`` (from ``_tick_width``) under the capacity narrows the
+        launch to the live lanes: the feed/verify/commit all run at lane
+        width and the committed lanes are written back — the same
+        per-row math at a narrower batch, so the narrow spec stream is
+        bit-identical to the full-width one (and to plain greedy).  The
+        verify and the commit are programs of their own, so the gather
+        and the write-back are too (``state_cache.gather_slots`` /
+        ``scatter_slots``), and a rung is compiled when it is first used.
 
         Per live slot: compose the feed (its pending committed tokens +
         up to K drafter proposals, zero-filled to the static width W),
@@ -2581,11 +2637,9 @@ class ServingEngine:
         S = self.capacity
         live = {s: t for s, t in self._slots.items()
                 if t.status is RequestStatus.DECODE}
-        compacted = width is not None
+        compacted = width < S
         if compacted:
-            idx, inv, touched, lanes = self._compact_maps(
-                list(live), width
-            )
+            idx, keep, lanes = self._lane_maps(list(live), width)
             n_lanes = width
         else:
             lanes = {s: s for s in live}
@@ -2630,15 +2684,16 @@ class ServingEngine:
             trusted[slot] = len(tr.spec_pending)
         if compacted:
             gathered = state_cache.gather_slots(
-                self._compact_rows(), jnp.asarray(idx), mesh=self.mesh,
+                _slot_rows(self.pool), jnp.asarray(idx),
+                jnp.asarray(keep), mesh=self.mesh,
             )
             state_in = {"blocks": gathered["blocks"]}
             logits_in, meta_in = gathered["logits"], gathered["meta"]
             if self.hybrid:
                 state_in["attn_blocks"] = \
                     self.pool["state"]["attn_blocks"]
-                ctbl, clen = self._compact_page_meta(idx, lanes,
-                                                     spare=True)
+                ctbl, clen = self._lane_page_meta(
+                    idx, keep, self._page_bucket(lanes, spare=True))
                 state_in["attn_meta"] = (jnp.asarray(ctbl),
                                          jnp.asarray(clen))
         else:
@@ -2649,13 +2704,7 @@ class ServingEngine:
                 # slot's overshoot writes clamp onto a zero (trash)
                 # table entry — the table rows carry a permanent spare
                 # column for exactly this (see __init__)
-                largest = max(
-                    (len(t.pages) for t in self._slots.values()
-                     if t.pages),
-                    default=1,
-                )
-                bucket = min(next_pow2_bucket(largest + 1, min_bucket=1),
-                             self._page_tbl.shape[1])
+                bucket = self._page_bucket(self._slots, spare=True)
                 state_in["attn_meta"] = (
                     jnp.asarray(self._page_tbl[:, :bucket]),
                     jnp.asarray(self._kv_len),
@@ -2708,8 +2757,8 @@ class ServingEngine:
                 tr.spec_pending = pending + fed[nt:nt + a] + [nxt]
                 tr.spec_pending_emitted = len(tr.spec_pending)
         # next step's chunk budget pays for this tick's verify lanes —
-        # the lanes actually COMPUTED: the compacted bucket width when
-        # compaction narrowed the launch, the live count otherwise
+        # the lanes actually COMPUTED: the rung's width when the launch
+        # was narrow, the live count otherwise
         self._spec_budget_debt = (width if compacted else len(live)) * W
         self._spec_streams += len(live)
         new_state = {k: v for k, v in new_state.items()
@@ -2719,13 +2768,8 @@ class ServingEngine:
             jnp.asarray(advance), jnp.int32(W),
         )
         if compacted:
-            self._scatter_pool(
-                committed["state"],
-                {"blocks": committed["state"]["blocks"],
-                 "logits": committed["logits"],
-                 "meta": committed["meta"]},
-                inv, touched,
-            )
+            self._scatter_pool(committed["state"], _slot_rows(committed),
+                               idx, keep)
         else:
             self.pool = committed
         if self.hybrid:
@@ -2765,26 +2809,21 @@ class ServingEngine:
         occupied = len(self._slots)
         live_slots = [s for s, t in self._slots.items()
                       if t.status is RequestStatus.DECODE]
-        # occupancy-adaptive compaction: the lane width this tick's
-        # launch actually computes (None => the full-width status quo).
-        # Mid-prefill residents compact OUT of the launch entirely —
+        # the rung this tick launches at: the lanes it computes.
+        # Mid-prefill residents stay OUT of a narrow launch entirely —
         # their parked carries are simply never gathered — so the tick
         # is priced by decodable slots, not residency.
-        width = self._compaction_width(live_slots)
-        # explicit GPipe microbatch count for this tick's launch (None
-        # => the GSPMD layer scan; _pipeline_micro documents the gate)
-        # and the schedule's honest bubble bill: the warmup/drain ramp
-        # idles (stage_shards - 1) stage-ticks per lm_step call, worth
+        width = self._tick_width(live_slots)
+        # the explicit GPipe schedule's honest bubble bill (_pipeline_micro
+        # documents the gate): the warmup/drain ramp idles
+        # (stage_shards - 1) stage-ticks per lm_step call, worth
         # (stage_shards - 1) * microbatch_width full-depth lane
         # equivalents x tokens_per_tick sub-steps
         n_micro = self._pipeline_micro(width)
         bubble_lanes = 0
         if n_micro:
-            bubble_lanes = (
-                (self.stage_shards - 1)
-                * ((self.capacity if width is None else width) // n_micro)
-                * self.tokens_per_tick
-            )
+            bubble_lanes = ((self.stage_shards - 1) * (width // n_micro)
+                            * self.tokens_per_tick)
         # live trace-id set: the requests this tick actually advances
         # (mid-prefill residents are masked out of sampling) — stamped
         # on the span AND the jsonl record so host-side attribution can
@@ -2795,10 +2834,11 @@ class ServingEngine:
         )
         t0 = time.perf_counter()
         # ``occupied`` counts residents, ``live`` the slots this tick
-        # decodes; ``prefill_tokens`` is what the prefill phase dispatched
-        # since the last tick (chunk lanes plus one-shot prompt tokens)
+        # decodes, ``width`` the lanes it launches (>= live);
+        # ``prefill_tokens`` is what the prefill phase dispatched since
+        # the last tick (chunk lanes plus one-shot prompt tokens)
         with self.tracer.span("serving_tick", occupied=occupied,
-                              live=len(live_slots),
+                              live=len(live_slots), width=width,
                               prefill_tokens=(
                                   self._pending_chunk_tokens
                                   + self._pending_oneshot_real_tokens),
@@ -2810,46 +2850,19 @@ class ServingEngine:
                 # lengths mirror (it advances by the chunk width only
                 # on full accepts)
                 tokens, emitted, done = self._spec_tick(width)
-            elif width is not None:
-                tokens, emitted, done = self._compact_tick(
-                    live_slots, width, n_micro
-                )
             else:
-                tick_kv = ()
-                if self.hybrid:
-                    # page-count BUCKET: pow2 of the largest resident
-                    # allocation, so the tick's attention reads scale
-                    # with what is actually live (one trace per bucket;
-                    # bucket width changes never perturb token streams —
-                    # masked attention is bit-stable across page-bucket
-                    # widths, models/attention.py)
-                    largest = max(
-                        (len(t.pages) for t in self._slots.values()
-                         if t.pages), default=1,
-                    )
-                    bucket = min(next_pow2_bucket(largest, min_bucket=1),
-                                 self._page_tbl.shape[1])
-                    tick_kv = (jnp.asarray(self._page_tbl[:, :bucket]),
-                               jnp.asarray(self._kv_len))
-                self.pool, tokens, emitted, done = _tick(
-                    self._params, self.pool, *tick_kv, cfg=self.cfg,
-                    k_max=self.max_top_k, steps=self.tokens_per_tick,
-                    mesh=self.mesh, n_micro=n_micro,
-                )
-                tokens = np.asarray(tokens)  # (steps, S) — the host sync
-                emitted = np.asarray(emitted)
-                done = np.asarray(done)
-                if self.hybrid:
-                    # mirror the device-side lengths advance: +1 per
-                    # live sub-step, exactly what `emitted` marks
-                    self._kv_len += emitted.sum(axis=0).astype(np.int32)
+                bucket = (self._page_bucket(live_slots) if self.hybrid
+                          else None)
+                self._warm_ladder(width, bucket)
+                tokens, emitted, done = self._run_tick(
+                    live_slots, width, bucket)
         t_now = time.perf_counter()
         with self.tracer.span("serving_emit"):
             return self._emit(tokens, emitted, done, t0, t_now, occupied,
                               width, live_traces, bubble_lanes)
 
     def _emit(self, tokens, emitted, done, t0: float, t_now: float,
-              occupied: int, width, live_traces, bubble_lanes: int
+              occupied: int, width: int, live_traces, bubble_lanes: int
               ) -> list[TokenEvent]:
         """``step()``'s host work once the tick's arrays are on the host
         (span ``serving_emit``): token events, latency stamps, evictions,
@@ -3102,14 +3115,10 @@ class ServingEngine:
             prefill_oneshot_tokens=self._pending_oneshot_real_tokens,
             prefill_oneshot_lanes=self._pending_oneshot_lanes,
             # goodput honesty: lanes are billed at the width the launch
-            # actually computed — the compacted bucket when compaction
-            # narrowed it, static capacity otherwise
-            slot_lanes=(self.capacity if width is None else width)
+            # actually computed, the tick's rung
+            slot_lanes=width
             * (self.spec_width if self.spec else self.tokens_per_tick),
-            compaction_width=(
-                (self.capacity if width is None else width)
-                if self.compaction else None
-            ),
+            compaction_width=width,
             traces=live_traces,
             model_shards=(self.model_shards if self.model_shards > 1
                           else None),

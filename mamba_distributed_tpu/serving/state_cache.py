@@ -36,7 +36,7 @@ the device AdapterCache slot whose stacked factors this slot's rows
 multiply inside the tick — 0 (the default, and the only value on
 LoRA-less engines) selects the reserved all-zero factor row, an exact
 no-op.  It lives in the pool meta — not a separate tick argument — so
-the compacted-tick gathers/scatters move it with the other axis-0
+a narrow tick's gather and write-back move it with the other axis-0
 meta rows for free.
 
 ``insert``/``evict`` are jit-compiled with the pool donated: the slot
@@ -495,95 +495,118 @@ def _write_blocks(pool_state, slot: jax.Array, state):
     return {**pool_state, "blocks": new_blocks}
 
 
-# ------------------------------------------------- compacted-tick lanes
+# ------------------------------------------------- the tick's lane ladder
 #
-# Occupancy-adaptive compacted ticks (serving/engine.py; docs/SERVING.md
-# "Occupancy-adaptive ticks"): the engine gathers the LIVE slots' rows
-# into a pow2 lane bucket, runs the existing jitted tick/verify step at
-# bucket width, and scatters the results back — compute per tick tracks
-# live slots, not static capacity.  These two jits are the whole device
-# side of that layer.  One trace per bucket width (the index arrays are
-# traced; only the width is a shape) — the engine's per-bucket trace
-# pins ride on these counters, mirroring the prompt-bucket discipline.
+# The decode tick launches the narrowest rung of a fixed ladder that holds
+# the decodable slots (serving/engine.py; docs/SERVING.md "Occupancy-
+# adaptive ticks"): the live slots' rows are gathered into W lanes, the
+# sub-steps run at lane width, and the advanced rows are written back into
+# the donated pool.  ``gather_rows`` / ``scatter_rows`` are that device
+# side, traced inside the jitted ``_tick``; ``gather_slots`` /
+# ``scatter_slots`` are the same two as programs of their own, for the
+# speculative tick, whose verify and commit are separate launches.  One
+# trace a rung (the lane maps are traced; only the width is a shape).
 TRACE_COUNTS = {"gather": 0, "scatter": 0}
 
 
-@functools.partial(jax.jit, static_argnames=("mesh",))
-def gather_slots(rows: dict, idx: jax.Array, mesh=None):
-    """Gather slot rows ``idx`` (W,) of a ``{"blocks", "logits",
-    "meta"}`` tree (the per-slot subtrees of a pool — ``blocks`` leaves
-    (L, S, ...) take axis 1, ``logits``/``meta`` leaves axis 0) into a
-    compact (.., W, ..) tree.  NOT donated: the full pool lives on (the
-    compacted tick's scatter writes it back).  Pad lanes may repeat any
-    in-range slot index — their computed results are garbage the
-    scatter never reads.  ``mesh`` (static; a serving_mesh, else None)
-    pins the compact lanes to the data-axis layout via the SAME
-    ``slot_pool_specs`` rules the full pool uses (the engine keeps the
-    bucket a multiple of the shard count and gathers shard-locally, so
-    the tiling carries over)."""
-    TRACE_COUNTS["gather"] += 1
-    out = {
-        "blocks": jax.tree.map(
-            lambda a: jnp.take(a, idx, axis=1), rows["blocks"]
-        ),
-        "logits": jnp.take(rows["logits"], idx, axis=0),
-        "meta": jax.tree.map(
-            lambda a: jnp.take(a, idx, axis=0), rows["meta"]
-        ),
-    }
-    if mesh is not None:
-        from mamba_distributed_tpu.parallel.sharding import (
-            slot_pool_shardings,
-        )
+def _constrain_rows(rows: dict, mesh):
+    """Pin a ``{"blocks", "logits", "meta"}`` tree to the data-axis layout
+    of the full pool (the SAME ``slot_pool_specs`` rules: the engine keeps a
+    rung a multiple of the shard count and gathers shard-locally, so the
+    tiling carries over)."""
+    if mesh is None:
+        return rows
+    from mamba_distributed_tpu.parallel.sharding import slot_pool_shardings
 
-        out = jax.lax.with_sharding_constraint(
-            out, slot_pool_shardings(out, mesh)
-        )
-    return out
+    return jax.lax.with_sharding_constraint(
+        rows, slot_pool_shardings(rows, mesh)
+    )
+
+
+@jax.named_scope(scopes.POOL_SELECT)
+def gather_rows(rows: dict, idx: jax.Array, keep: jax.Array, mesh=None):
+    """Gather slot rows ``idx`` (W,) of a ``{"blocks", "logits", "meta"}``
+    tree (the per-slot subtrees of a pool: ``blocks`` leaves (L, S, ...)
+    take axis 1, ``logits``/``meta`` leaves axis 0) into a compact
+    (.., W, ..) tree.  ``keep`` (W,) marks the lanes that carry a slot: a
+    pad lane repeats an in-range row of its shard, is made inactive here (it
+    samples nothing and, in a hybrid, writes its KV row to the trash page)
+    and is dropped by ``scatter_rows``."""
+    # mode="clip": the default ("fill") would add a select over the lanes
+    take = functools.partial(jnp.take, indices=idx, mode="clip")
+    out = {
+        "blocks": jax.tree.map(lambda a: take(a, axis=1), rows["blocks"]),
+        "logits": take(rows["logits"], axis=0),
+        "meta": jax.tree.map(lambda a: take(a, axis=0), rows["meta"]),
+    }
+    out["meta"]["active"] = out["meta"]["active"] & keep
+    return _constrain_rows(out, mesh)
+
+
+@jax.named_scope(scopes.POOL_SELECT)
+def scatter_rows(rows: dict, compact: dict, idx: jax.Array,
+                 keep: jax.Array, mesh=None):
+    """Write a narrow launch's lanes back into the full-width rows, in
+    place: lane j goes to slot ``idx[j]`` where ``keep[j]``, one
+    ``dynamic_update_slice`` a lane and leaf on the donated buffers (what
+    ``_write_blocks`` does for one slot), and no select or copy over them.
+    The loop runs over the kept lanes alone, so a pad lane writes nothing
+    and a slot no lane names (empty, mid-prefill) keeps its bits."""
+    kept_first = jnp.argsort(~keep, stable=True)
+
+    def write(j, rows):
+        lane = kept_first[j]
+        slot = idx[lane]
+
+        def put(axis):
+            def one(full, lanes):
+                row = jax.lax.dynamic_slice_in_dim(lanes, lane, 1, axis)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    full, row.astype(full.dtype), slot, axis)
+            return one
+
+        return {
+            "blocks": jax.tree.map(put(1), rows["blocks"],
+                                   compact["blocks"]),
+            "logits": put(0)(rows["logits"], compact["logits"]),
+            "meta": jax.tree.map(put(0), rows["meta"], compact["meta"]),
+        }
+
+    out = jax.lax.fori_loop(0, keep.sum(), write, rows)
+    return _constrain_rows(out, mesh)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh",))
+def gather_slots(rows: dict, idx: jax.Array, keep: jax.Array, mesh=None):
+    """``gather_rows`` as a program (the speculative tick).  NOT donated:
+    the full pool lives on, and ``scatter_slots`` writes into it."""
+    TRACE_COUNTS["gather"] += 1
+    return gather_rows(rows, idx, keep, mesh)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh",), donate_argnums=(0,))
-def scatter_slots(rows: dict, compact: dict, inv: jax.Array,
-                  touched: jax.Array, mesh=None):
-    """Write a compacted tick's output lanes back into the full-width
-    rows: slot s takes compact lane ``inv[s]`` where ``touched[s]``,
-    else keeps its old row (mid-prefill carries, empty slots — and pad
-    lanes, which no slot maps to — are never written).  Implemented as
-    a per-slot gather + select rather than a scatter, so duplicate pad
-    indices can never race a live row.  ``rows`` (the full pool's
-    per-slot subtrees) is donated — the output aliases it; the compact
-    buffers are the tick's spent output and simply expire."""
+def scatter_slots(rows: dict, compact: dict, idx: jax.Array,
+                  keep: jax.Array, mesh=None):
+    """``scatter_rows`` as a program (the speculative tick).  ``rows`` is
+    donated and the output aliases it; the compact buffers are the launch's
+    spent output and expire."""
     TRACE_COUNTS["scatter"] += 1
-    t_slot = lambda ndim, ax: touched.reshape(
-        (1,) * ax + (-1,) + (1,) * (ndim - ax - 1)
-    )
-    out = {
-        "blocks": jax.tree.map(
-            lambda f, c: jnp.where(
-                t_slot(f.ndim, 1), jnp.take(c, inv, axis=1), f
-            ),
-            rows["blocks"], compact["blocks"],
-        ),
-        "logits": jnp.where(
-            t_slot(rows["logits"].ndim, 0),
-            jnp.take(compact["logits"], inv, axis=0), rows["logits"],
-        ),
-        "meta": jax.tree.map(
-            lambda f, c: jnp.where(
-                t_slot(f.ndim, 0), jnp.take(c, inv, axis=0), f
-            ),
-            rows["meta"], compact["meta"],
-        ),
-    }
-    if mesh is not None:
-        from mamba_distributed_tpu.parallel.sharding import (
-            slot_pool_shardings,
-        )
+    return scatter_rows(rows, compact, idx, keep, mesh)
 
-        out = jax.lax.with_sharding_constraint(
-            out, slot_pool_shardings(out, mesh)
-        )
-    return out
+
+@jax.jit
+def idle_meta(meta: dict) -> dict:
+    """A pool's meta with every slot parked: nothing live, every row held.
+    The engine hands it to a full-width launch that has to run and change
+    nothing (the ladder's warm-up), and puts the real meta back after.
+    Every leaf is a buffer of its own: the launch donates what it is given,
+    and the real meta must outlive it."""
+    return {
+        **jax.tree.map(jnp.copy, meta),
+        "active": jnp.zeros_like(meta["active"]),
+        "done": jnp.zeros_like(meta["done"]),
+        "prefilling": jnp.ones_like(meta["prefilling"]),
+    }
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

@@ -211,15 +211,12 @@ class ServingMetrics:
         self.spec_accepted = 0
         self.spec_stream_ticks = 0  # Σ live streams over verify ticks
         self.spec_accept_rate = StreamingHistogram(lo=1e-2, hi=200.0)
-        # occupancy-adaptive compacted ticks (serving/engine.py;
-        # docs/SERVING.md "Occupancy-adaptive ticks"): the engine calls
-        # configure_compaction() when cfg.tick_compaction is on,
-        # unlocking summary()["compaction"] — per-width tick histogram,
-        # distinct compiled bucket widths ("recompiles": each width is
-        # one gather/tick/scatter trace trio), and the token lanes the
-        # narrower launches saved vs static capacity.  Off by default
-        # so compaction-off records/summaries stay byte-stable.
-        self._compaction_on = False
+        # the decode tick's lane ladder (serving/engine.py;
+        # docs/SERVING.md "Occupancy-adaptive ticks"): every tick record
+        # carries the width it launched at, and summary()["compaction"]
+        # rolls them up — per-width tick histogram, distinct narrow
+        # widths used ("recompiles": each is one program), and the token
+        # lanes the narrower launches saved vs static capacity.
         self.compaction_ticks = 0  # ticks that ran NARROWER than capacity
         self.compaction_hist: dict[int, int] = {}  # lane width -> ticks
         self.compaction_lanes_saved = 0
@@ -395,14 +392,6 @@ class ServingMetrics:
     def record_preemption(self) -> None:
         """One priority swap-out (serving/engine._preempt)."""
         self.preemptions += 1
-
-    # ------------------------------------------------- compacted ticks
-
-    def configure_compaction(self) -> None:
-        """Mark occupancy-adaptive tick compaction live (engine
-        construction): ``summary()`` gains its ``compaction`` block and
-        tick records their ``compaction_width`` stamp."""
-        self._compaction_on = True
 
     # ------------------------------------------------ speculative decoding
 
@@ -870,13 +859,11 @@ class ServingMetrics:
             record["compiles"] = compiles
             record["compile_ms"] = round(compile_ms, 3)
         if compaction_width is not None:
-            # occupancy-adaptive compaction stamp (only when the engine
-            # has compaction on — records stay byte-stable otherwise):
-            # the lane width this tick's launch computed.  slot_lanes
-            # above is already billed at that width, so the goodput
-            # fields price the compacted launch, not static capacity;
-            # lanes_saved is the delta a full-width launch would have
-            # burned on the same tick.
+            # the lane width this tick's launch computed (the engine
+            # stamps it on every tick).  slot_lanes above is already
+            # billed at that width, so the goodput fields price the
+            # launch, not static capacity; lanes_saved is the delta a
+            # full-width launch would have burned on the same tick.
             record["compaction_width"] = compaction_width
             self.compaction_hist[compaction_width] = (
                 self.compaction_hist.get(compaction_width, 0) + 1
@@ -967,11 +954,9 @@ class ServingMetrics:
                         and self._fpt_decode is not None) else None
                 ),
             },
-            "compaction": (None if not self._compaction_on else {
+            "compaction": {
                 "ticks_compacted": self.compaction_ticks,
-                # one gather/tick/scatter trace trio per distinct
-                # NARROW width ever used (full-width launches reuse
-                # the pre-existing tick trace)
+                # one tick program per distinct NARROW width ever used
                 "recompiles": sum(1 for w in self.compaction_hist
                                   if w < self.capacity),
                 "bucket_histogram": {
@@ -979,7 +964,7 @@ class ServingMetrics:
                     for w, n in sorted(self.compaction_hist.items())
                 },
                 "lanes_saved": self.compaction_lanes_saved,
-            }),
+            },
             "pipeline": (None if not self._pipeline_on else {
                 "stage_shards": self.stage_shards_cfg,
                 # ticks that ran the explicit microbatched clock (the

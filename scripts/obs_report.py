@@ -254,8 +254,8 @@ def build_report(events: list[dict]) -> dict:
                     sp_tokens / (streams or len(spticks)), 2
                 ),
             }
-        # occupancy-adaptive compaction gauges (absent unless a
-        # compaction-enabled engine wrote the stream): how many ticks
+        # the lane ladder's gauges (absent from a stream written before
+        # every tick carried its width): how many ticks
         # ran narrower than capacity and at what lane widths
         # (docs/SERVING.md "Occupancy-adaptive ticks")
         cticks = [e for e in ticks

@@ -214,17 +214,21 @@ def test_pipelined_decode_holds_masked_rows():
 
 
 @pytest.mark.slow
-def test_engine_parity_and_flat_traces_stage2():
-    """(data=1, stage=2, model=1) with tick compaction on: every
+def test_engine_parity_and_flat_traces_stage2(monkeypatch):
+    """(data=1, stage=2, model=1) with a ladder of rungs 1, 2, 4: every
     stream bit-matches solo generate(), the explicit microbatched
-    clock engages (pipelined ticks billed bubbles), and repeated
-    pipelined ticks reuse ONE trace per pow2 lane bucket —
-    TRACE_COUNTS stay flat across ticks at a held bucket.  Marked
-    slow with the rest of the compile-heavy matrix;
-    `pytest -m pipe_serve` runs the whole tier standalone."""
-    cfg = tiny_cfg(tick_compaction=True)
+    clock engages (pipelined ticks billed bubbles), and the first tick
+    traces ONE program a rung — TRACE_COUNTS stay flat across every
+    tick after it.  Marked slow with the rest of the compile-heavy
+    matrix; `pytest -m pipe_serve` runs the whole tier standalone."""
+    from mamba_distributed_tpu.serving import engine as engine_mod
+
+    cfg = tiny_cfg()
     params = init_lm_params(jax.random.PRNGKey(0), cfg)
+    monkeypatch.setattr(engine_mod, "RUNG_FLOOR_LANES", 1)
+    monkeypatch.setattr(engine_mod, "RUNG_HYSTERESIS_TICKS", 0)
     eng = ServingEngine(params, cfg, capacity=4, tokens_per_tick=2)
+    assert eng._rungs == (1, 2, 4)
     assert dict(eng.mesh.shape) == {"data": 1, "stage": 2, "model": 1}
     assert eng.stage_shards == 2
     # staggered budgets so occupancy decays through >1 pow2 bucket;
@@ -239,15 +243,14 @@ def test_engine_parity_and_flat_traces_stage2():
         before = TRACE_COUNTS["tick"]
         eng.step()
         ticks_at.append((before, TRACE_COUNTS["tick"]))
-    # one compiled tick trace per DISTINCT pow2 lane bucket the run
-    # visited — never one per tick (that would be a per-tick recompile)
-    n_tick_steps = sum(1 for b, a in ticks_at if a >= b)
-    distinct_traces = TRACE_COUNTS["tick"] - ticks_at[0][0] \
-        if ticks_at else 0
+    # one compiled tick trace a rung, all of them at the first tick —
+    # never one per tick (that would be a per-tick recompile)
+    first = next(i for i, (b, a) in enumerate(ticks_at) if a > b)
+    assert ticks_at[first][1] - ticks_at[first][0] == len(eng._rungs)
+    assert all(a == b for b, a in ticks_at[first + 1:])
     widths = {w for w in eng.metrics.compaction_hist}
-    assert distinct_traces <= len(widths), (
-        f"{distinct_traces} tick traces for buckets {widths}")
-    assert n_tick_steps > len(widths)  # the run actually repeated ticks
+    assert len(widths) > 1  # the run visited more than one rung
+    assert len(ticks_at) - first > len(widths)  # and repeated ticks
     results = [eng.results[i] for i in range(len(reqs))]
     assert_parity(params, cfg, reqs, results)
     # the explicit clock engaged and billed its ramp

@@ -436,21 +436,26 @@ def test_spec_decode_parity():
                             label=f"spec:{r.adapter}")
 
 
-def test_tick_compaction_parity():
-    """Compacted ticks gather the adapter-id meta row with the rest of
+def test_tick_compaction_parity(monkeypatch):
+    """Narrow ticks gather the adapter-id meta row with the rest of
     the axis-0 meta: low-occupancy heterogeneous streams match both
-    the merged reference and an uncompacted LoRA engine bit-exactly."""
-    cfg = tiny_cfg(tick_compaction=True)
+    the merged reference and a one-rung LoRA engine bit-exactly."""
+    from mamba_distributed_tpu.serving import engine as engine_mod
+
+    cfg = tiny_cfg()
     params = init_lm_params(jax.random.PRNGKey(0), cfg)
     reg = make_registry(cfg, params)
     reqs = tenant_requests(adapters=("alice", "bob"))
     eng = ServingEngine(params, cfg, capacity=16, max_top_k=1,
                         tokens_per_tick=2, adapters=reg)
+    assert eng._rungs == (8, 16)
     results = eng.run(reqs)
+    assert eng.metrics.summary()["compaction"]["ticks_compacted"] > 0
     assert_parity(params, reg, cfg, reqs, results)
-    off = ServingEngine(params, dataclasses.replace(
-        cfg, tick_compaction=False), capacity=16, max_top_k=1,
-        tokens_per_tick=2, adapters=reg)
+    monkeypatch.setattr(engine_mod, "RUNG_FLOOR_LANES", 16)
+    off = ServingEngine(params, cfg, capacity=16, max_top_k=1,
+                        tokens_per_tick=2, adapters=reg)
+    assert off._rungs == (16,)
     for a, b in zip(results, off.run(tenant_requests(
             adapters=("alice", "bob")))):
         assert a.new_tokens.tolist() == b.new_tokens.tolist()
