@@ -7,8 +7,9 @@ around a transient (b, l, d, n) tensor.  These kernels keep those
 intermediates in VMEM instead — the SSD decay matrix is rebuilt per tile,
 the selective-scan state lives in registers for the whole sequence — which
 is where the MFU headroom lives (SURVEY.md §7 stage 5).  Decode-side,
-``ragged_paged_decode_attention`` walks the serving pool's paged KV per
-slot (models/attention.py).
+``ragged_paged_decode_attention`` walks each slot's live pages of the
+serving pool in blocks of B pages, every KV head of a page in one copy
+(models/attention.py; docs/KERNELS.md).
 """
 
 from mamba_distributed_tpu.ops.pallas.attention_kernels import (

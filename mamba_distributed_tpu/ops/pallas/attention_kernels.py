@@ -509,19 +509,79 @@ def flash_sdpa_causal(
 #
 # Serving decode over the paged KV pool (models/attention.py): each row of
 # the slot batch sits at its OWN position, its KV scattered across pool
-# pages named by its page-table row.  The kernel walks each row's page
-# list with the table scalar-prefetched (the BlockSpec index map picks the
-# physical page per grid step, so no (S, W*page) gather ever exists) and
-# skips every page at or past the row's kv_len via ``pl.when`` — decode
-# FLOPs track live tokens, not pool capacity.  Grid (slots, kv-heads,
-# pages), pages sequential; the online-softmax accumulator lives in VMEM
-# scratch exactly like the flash forward above.
+# pages named by its page-table row.  The kernel walks a lane's page list
+# in BLOCKS of B pages, the table, lengths and layer index
+# scalar-prefetched, so no (S, W*page) gather ever exists:
+#
+#   * grid (lanes, ceil(W / B)), blocks sequential: one cell holds every
+#     KV head of B pages, not one head of one page;
+#   * a page is ONE copy: its nkv heads lie side by side in the pool
+#     ((A, P, nkv, pg, hd), head-major inside a page), so the pool is
+#     handed over B times for K and B times for V (the same buffer: an
+#     operand is a window, not a copy) and window i of a cell is the
+#     (nkv, pg, hd) page ``pool[layer, tbl[s, j*B + i]]``, fetched by the
+#     pipeline while the cell before computes, across lane boundaries too;
+#   * B from shapes (``_pick_page_block``): B * pg = 512 tokens inside a
+#     VMEM budget, never more than the table is wide.  A head's scores,
+#     softmax update and value product run on the whole (R8, B*pg) block;
+#   * a dead page moves nothing: past its last live block a window
+#     repeats the page it showed there, and the pipeline does not fetch a
+#     block whose index stands still; the dead cell's body is skipped,
+#     and positions past kv_len inside the last live block are masked by
+#     the iota.  A lane with kv_len == 0 computes nothing and emits zeros;
+#   * which page a window shows is worked out ONCE, outside the kernel
+#     (``_window_pages``: an (S, W)-sized integer op), and prefetched in
+#     the table's place: an index map is one SMEM read.  A cell pays for
+#     each of its 2B windows whether or not anything moves, so what an
+#     index map costs is paid S * W * 2 times a call.
+#
+# What this replaced (PR 36): a grid (S, nkv, W) of one (pg, hd) tile a
+# cell: 8,192 cells a call at the benchmark's 16 lanes x 4 heads x 128
+# table entries, 1.53 ms a call at either head width, all of it per-cell
+# overhead.  docs/KERNELS.md has the measurements, and those of the route
+# not kept (the pool left in HBM and copied by the kernel itself).
 # ---------------------------------------------------------------------------
 
 # python-side-effect trace counters (one bump per jit trace): the whole
 # point of the fixed (S, W) layout is that occupancy/length changes never
 # retrace — tests/test_paged_attention.py pins both.
 TRACE_COUNTS = {"ragged_decode": 0, "ragged_prefill": 0}
+
+# the decode walk's block: tokens a block, and the VMEM its 2B page
+# windows may take, double-buffered.  512 tokens make a (R8, 512) score
+# block a head; at the benchmark's pages (4 heads x 64 tokens x 128 lanes
+# of bf16 = 64 KB) that is B = 8 and 2 MB, far inside the 16 MB a v5e
+# kernel gets by default.
+_RPA_BLOCK_TOKENS = 512
+_RPA_VMEM_BYTES = 8 * 2**20
+
+
+def _pick_page_block(W: int, nkv: int, pg: int, hd: int, itemsize: int) -> int:
+    """Pages a block of the decode walk, from shapes alone: 512 tokens'
+    worth, as many as the VMEM budget holds double-buffered for K and V
+    (a row pads to 128 lanes in VMEM), at least one and never more than
+    the table's width (a narrower table is one block).  B need not
+    divide W: the last block's missing pages are dead pages."""
+    page_bytes = nkv * pg * (-(-hd // 128) * 128) * itemsize
+    b = min(_RPA_BLOCK_TOKENS // pg, _RPA_VMEM_BYTES // (4 * page_bytes), W)
+    return max(1, b)
+
+
+def _window_pages(page_table, kv_len, pg: int, bp: int) -> jax.Array:
+    """(S, nb * bp) int32: the physical page window ``i`` of block ``j``
+    of lane ``s`` shows, at ``[s, j * bp + i]`` (``kv_len`` at most the
+    table's W * pg): page ``j * bp + i`` of the lane while that page is
+    live, and after it the page the window
+    showed in its last live block (entry ``i`` of the table if it never
+    had one), so that a dead window's index stands still from cell to
+    cell and nothing is fetched for it."""
+    W = page_table.shape[1]
+    w = jnp.arange(-(-W // bp) * bp, dtype=jnp.int32)[None]
+    j, i = w // bp, w % bp
+    last = ((kv_len + (pg - 1)) // pg - 1)[:, None]    # a lane's last live page
+    j = jnp.minimum(j, jnp.maximum(last - i, 0) // bp)
+    return jnp.take_along_axis(
+        page_table, jnp.minimum(j * bp + i, W - 1), axis=1)
 
 
 def _layer_operand(layer) -> jax.Array:
@@ -531,29 +591,33 @@ def _layer_operand(layer) -> jax.Array:
 
 
 def _rpa_kernel(
-    layer_ref, tbl_ref, len_ref, *rest,
-    nw: int, pg: int, sm_scale: float, quant: bool = False,
+    layer_ref, win_ref, len_ref, *rest,
+    pg: int, bp: int, sm_scale: float, quant: bool = False,
 ):
-    """One (slot, kv-head, page) cell of the ragged decode forward.
+    """One (lane, block of ``bp`` pages) cell of the ragged decode
+    forward, every KV head in it.
 
-    ``layer_ref`` (the pool's layer index) is read by the index maps
-    alone: the K/V blocks arrive as that layer's (1, 1, pg, hd) tiles.
+    ``layer_ref`` (the pool's layer index) and ``win_ref`` (the windows'
+    pages, ``_window_pages``) are read by the index maps: the ``bp`` K
+    windows and ``bp`` V windows arrive as that layer's (1, nkv, pg, hd)
+    pages.
 
     ``quant`` (int8 page pools): two extra scalar-prefetched (P, nkv)
     f32 scale arrays ride between the metadata and the tensor refs; the
-    page tile is read as int8 and dequantized IN-REGISTER — the K
-    scale folds into the score block's scalar multiply, the V scale
-    into the accumulator update — one scalar each per (page, head)
-    cell, no dequantized page ever materializes in VMEM.
+    block is read as int8 and dequantized IN-REGISTER — each page's K
+    scale folds into its columns of the score block, its V scale into
+    its columns of the probabilities — one scalar per (page, head), no
+    dequantized page ever materializes in VMEM.
     """
     if quant:
-        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, m_scr, den_scr, \
-            acc_scr = rest
-    else:
-        q_ref, k_ref, v_ref, o_ref, m_scr, den_scr, acc_scr = rest
+        ks_ref, vs_ref, *rest = rest
+    q_ref, *rest = rest
+    k_refs, v_refs = rest[:bp], rest[bp:2 * bp]
+    o_ref, m_scr, den_scr, acc_scr = rest[2 * bp:]
     s = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
+    nkv, r8 = q_ref.shape[1], q_ref.shape[2]
+    bt = bp * pg                                  # tokens a block
 
     @pl.when(j == 0)
     def _():
@@ -563,59 +627,61 @@ def _rpa_kernel(
 
     kv_len = len_ref[s]
 
-    # whole pages at/past the row's length are SKIPPED, not masked —
+    # whole blocks at/past the row's length are SKIPPED, not masked —
     # the ragged saving (a dead row, kv_len == 0, skips everything)
-    @pl.when(j * pg < kv_len)
+    @pl.when(j * bt < kv_len)
     def _():
-        q = q_ref[0, 0]                                  # (R8, hd)
-        k = k_ref[0, 0]                                  # (pg, hd)
+        kpos = jax.lax.broadcasted_iota(jnp.int32, (r8, bt), 1) + j * bt
+        alive = kpos < kv_len
         if quant:
-            phys = tbl_ref[s, j]
-            # int8 tile -> fp32 dot; the per-(page, head) K scale is a
-            # SCALAR for the whole block, folded into the score scale
-            scores = jax.lax.dot_general(
-                q.astype(jnp.float32), k.astype(jnp.float32),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * (ks_ref[phys, h] * sm_scale)
-        else:
-            scores = jax.lax.dot_general(                # (R8, pg) fp32
+            # per-(page, head) scales as one (1, bt) row a head: page i's
+            # scalar on its pg columns (a dead page's columns are masked)
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+            phys = [win_ref[s, j * bp + i] for i in range(bp)]
+
+            def scale_row(ref, h):
+                row = jnp.zeros((1, bt), jnp.float32)
+                for i in range(bp):
+                    row = jnp.where(col >= i * pg, ref[phys[i], h], row)
+                return row
+
+        for h in range(nkv):
+            q = q_ref[0, h]                              # (R8, hd)
+            k = jnp.concatenate([r[0, h] for r in k_refs], axis=0)
+            v = jnp.concatenate([r[0, h] for r in v_refs], axis=0)
+            if quant:
+                # int8 block -> fp32 products; the scales come after
+                q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+            scores = jax.lax.dot_general(                # (R8, bt) fp32
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * sm_scale
-        kpos = jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1
-        ) + j * pg
-        scores = jnp.where(kpos < kv_len, scores, _NEG_INF)
+            if quant:
+                scores = scores * scale_row(ks_ref, h)
+            scores = jnp.where(alive, scores, _NEG_INF)
 
-        # lane-replicated row stats; lane-max reads (no sub-128 slices)
-        m_prev = jnp.max(m_scr[...], axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
-        scale = jnp.where(m_prev > _NEG_INF, jnp.exp(m_prev - m_new), 0.0)
-        p = jnp.where(scores > _NEG_INF, jnp.exp(scores - m_new), 0.0)
-
-        v = v_ref[0, 0]                                  # (pg, hd)
-        if quant:
-            # V dequant: one scalar multiply on the fp32 accumulator
-            acc_scr[...] = acc_scr[...] * scale + jax.lax.dot_general(
-                p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * vs_ref[tbl_ref[s, j], h]
-        else:
-            acc_scr[...] = acc_scr[...] * scale + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            # lane-replicated row stats; lane-max reads (no sub-128 slices)
+            m_prev = jnp.max(m_scr[h], axis=1, keepdims=True)
+            m_new = jnp.maximum(
+                m_prev, jnp.max(scores, axis=1, keepdims=True))
+            scale = jnp.where(
+                m_prev > _NEG_INF, jnp.exp(m_prev - m_new), 0.0)
+            p = jnp.where(scores > _NEG_INF, jnp.exp(scores - m_new), 0.0)
+            den_scr[h] = den_scr[h] * scale + jnp.sum(
+                p, axis=1, keepdims=True)
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            pv = jax.lax.dot_general(                    # (R8, hd) fp32
+                p * scale_row(vs_ref, h) if quant else p.astype(v.dtype),
+                v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-        den_scr[...] = den_scr[...] * scale + jnp.sum(
-            p, axis=1, keepdims=True
-        )
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+            acc_scr[h] = acc_scr[h] * scale + pv
 
-    @pl.when(j == nw - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _():
-        den = jnp.max(den_scr[...], axis=1, keepdims=True)
+        den = jnp.max(den_scr[...], axis=2, keepdims=True)
         # rows with no live page (kv_len == 0) emit zeros, not NaN
-        o_ref[0, 0] = (acc_scr[...] / jnp.maximum(den, 1e-30)).astype(
+        o_ref[0] = (acc_scr[...] / jnp.maximum(den, 1e-30)).astype(
             o_ref.dtype
         )
 
@@ -637,8 +703,8 @@ def ragged_paged_decode_attention(
     (A, P, nkv, page, hd) — the WHOLE head-major page pool, every
     attention layer's pages (page 0 of each layer = trash); ``layer``
     — which of the A layers to read, an int or a traced int32 scalar: it
-    rides the scalar-prefetch block beside the page table and the K/V
-    index maps address ``(layer, tbl[s, j], h)``, so a caller's layer
+    rides the scalar-prefetch block beside the windows' pages and the K/V
+    index maps address ``(layer, tbl[s, j*B + i])``, so a caller's layer
     loop hands over the pool it carries and never slices it;
     page_table (S, W) int32; kv_len (S,) int32 — tokens readable
     per row (INCLUDING any token written this step).  Returns
@@ -646,10 +712,10 @@ def ragged_paged_decode_attention(
 
     ``k_scale``/``v_scale`` (int8 pools: THIS layer's (P, nkv) f32, one
     symmetric scale per (physical page, kv head)) ride the scalar-prefetch
-    channel next to the page table, and the kernel dequantizes each visited
-    int8 tile in-register — the per-page scalar folds into the score
-    multiply (K) and the accumulator update (V), so page-walk HBM
-    traffic is the int8 bytes and nothing widened ever round-trips.
+    channel next to the page table, and the kernel dequantizes each
+    visited int8 block in-register — a page's scalar folds into its
+    columns of the scores (K) and of the probabilities (V), so page-walk
+    HBM traffic is the int8 bytes and nothing widened ever round-trips.
 
     Numerics match the lax fallback (gather + masked SDPA,
     models/attention._sdpa_positions; int8: dequantizing gather) to fp
@@ -666,55 +732,63 @@ def ragged_paged_decode_attention(
     if nh % nkv:
         raise ValueError(f"num_heads {nh} not a multiple of kv heads {nkv}")
     rep = nh // nkv
-    # GQA rep as the sublane dim of each (slot, kv-head) cell, padded to
+    # GQA rep as the sublane dim of each (slot, kv-head) tile, padded to
     # the 8-sublane granule; pad rows attend real keys and are sliced off
     R8 = -(-rep // 8) * 8
     qh = q.reshape(S, nkv, rep, hd)
     if R8 != rep:
         qh = jnp.pad(qh, ((0, 0), (0, 0), (0, R8 - rep), (0, 0)))
-    # the pool is STORED head-major (A, P, nkv, pg, hd), so KV blocks are
-    # (1, 1, pg, hd) — Mosaic's last-two-dims tiling — under a squeezed
-    # layer dimension, addressed straight off the layer index and the
-    # table: no per-call slice or transpose of the pool on the hot path
+    bp = _pick_page_block(W, nkv, pg, hd, k_pages.dtype.itemsize)
 
-    grid = (S, nkv, W)
     # index maps take the grid ids plus EVERY scalar-prefetch operand
     # (3 plain, 5 with the int8 scales) — *pf absorbs the difference
-    q_spec = pl.BlockSpec(
-        (1, 1, R8, hd), lambda s, h, j, *pf: (s, h, 0, 0)
+    q_spec = pl.BlockSpec((1, nkv, R8, hd), lambda s, j, *pf: (s, 0, 0, 0))
+
+    def page_window(i):
+        # the pool is STORED head-major (A, P, nkv, pg, hd): a page is a
+        # (1, nkv, pg, hd) block — Mosaic's last-two-dims tiling — under
+        # a squeezed layer dimension, addressed straight off the layer
+        # index and the window's page: no per-call slice or transpose of
+        # the pool on the hot path
+        return pl.BlockSpec(
+            (None, 1, nkv, pg, hd),
+            lambda s, j, lyr, win, *pf: (lyr[0], win[s, j * bp + i], 0, 0, 0),
+        )
+
+    windows = [page_window(i) for i in range(bp)]
+    # a length past the table reads the table and no further
+    kv_len = jnp.minimum(kv_len.astype(jnp.int32), W * pg)
+    prefetch = (
+        _layer_operand(layer),
+        _window_pages(page_table.astype(jnp.int32), kv_len, pg, bp),
+        kv_len,
     )
-    kv_spec = pl.BlockSpec(
-        (None, 1, 1, pg, hd),
-        lambda s, h, j, lyr, tbl, *pf: (lyr[0], tbl[s, j], h, 0, 0),
-    )
-    prefetch = (_layer_operand(layer), page_table.astype(jnp.int32),
-                kv_len.astype(jnp.int32))
     if quant:
         prefetch += (k_scale.astype(jnp.float32),
                      v_scale.astype(jnp.float32))
     out = pl.pallas_call(
         functools.partial(
-            _rpa_kernel, nw=W, pg=pg, sm_scale=1.0 / math.sqrt(hd),
+            _rpa_kernel, pg=pg, bp=bp, sm_scale=1.0 / math.sqrt(hd),
             quant=quant,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=grid,
-            in_specs=[q_spec, kv_spec, kv_spec],
+            grid=(S, -(-W // bp)),
+            in_specs=[q_spec] + windows + windows,
             out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((R8, 128), jnp.float32),
-                pltpu.VMEM((R8, 128), jnp.float32),
-                pltpu.VMEM((R8, hd), jnp.float32),
+                pltpu.VMEM((nkv, R8, 128), jnp.float32),
+                pltpu.VMEM((nkv, R8, 128), jnp.float32),
+                pltpu.VMEM((nkv, R8, hd), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((S, nkv, R8, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="ragged_paged_decode_attention",
-    )(*prefetch, qh, k_pages, v_pages)
+    )(*prefetch, qh, *([k_pages] * bp), *([v_pages] * bp))
     return out[:, :, :rep].reshape(S, nh, hd)
 
 

@@ -19,6 +19,8 @@ from mamba_distributed_tpu.models.attention import (
 )
 from mamba_distributed_tpu.ops.pallas.attention_kernels import (
     TRACE_COUNTS,
+    _pick_page_block,
+    _window_pages,
     ragged_paged_decode_attention,
     ragged_paged_prefill_attention,
 )
@@ -196,6 +198,131 @@ def test_ragged_kernel_tpu_lowering(rng):
     exp = jax.export.export(jax.jit(f), platforms=["tpu"])(
         q, kp, kp, jnp.int32(LAYER), tbl, ln)
     assert exp.platforms == ("tpu",)
+
+
+# the two longdoc cells' attention shapes: (query heads, head width,
+# attention layers in the pool), 4 KV heads and 64-token pages on both
+# (benchmark/configs/hybrid-280m.json, falcon-h1-34b.json)
+CELL_HEADS = {"hybrid-280m": (12, 64, 8), "falcon-h1-34b": (20, 128, 6)}
+
+
+@pytest.mark.parametrize("W", [20, 4], ids=["W20_not_a_multiple_of_B",
+                                            "W4_narrower_than_a_block"])
+@pytest.mark.parametrize("pool_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("cell", sorted(CELL_HEADS))
+def test_decode_block_walk_edges_match_lax(rng, cell, pool_dtype, W):
+    """The block walk at the cells' head shapes against the lax path, a lane
+    at every edge of it: nothing cached, one token, one page, one token
+    short of a block, a block, one token into the next, the whole table;
+    under a table that B does not divide and under one narrower than B
+    (which is then one block).  Every table entry past a lane's live pages
+    names a page of poison."""
+    nh, hd, _ = CELL_HEADS[cell]
+    nkv, pg = 4, 64
+    quant = pool_dtype == "int8"
+    B = _pick_page_block(W, nkv, pg, hd, 1 if quant else 2)
+    assert B == min(8, W)
+    lens = sorted({0, 1, pg, B * pg - 1, B * pg, min(B * pg + 1, W * pg),
+                   W * pg})
+    S = len(lens)
+    P = 2 + S * W
+    ks = jax.random.split(rng, 5)
+    q = jax.random.normal(ks[0], (S, nh, hd), jnp.float32)
+    if quant:
+        kp = jax.random.randint(ks[1], (P, nkv, pg, hd), -127, 128).astype(
+            jnp.int8)
+        vp = jax.random.randint(ks[2], (P, nkv, pg, hd), -127, 128).astype(
+            jnp.int8)
+        scale = lambda k: 0.001 + 0.05 * jax.random.uniform(
+            k, (P, nkv), jnp.float32)
+        kw = dict(k_scale=scale(ks[3]), v_scale=scale(ks[4]))
+        tol = dict(atol=3e-5, rtol=3e-5)
+    else:
+        q = q.astype(jnp.bfloat16)
+        kp = jax.random.normal(ks[1], (P, nkv, pg, hd), jnp.bfloat16)
+        vp = jax.random.normal(ks[2], (P, nkv, pg, hd), jnp.bfloat16)
+        kw = {}
+        # the kernel rounds the probabilities to bfloat16 for the value
+        # product; the lax path below is float32 throughout
+        tol = dict(atol=4e-3, rtol=2e-2)
+    # page P-1 is poison (finite: the pool's contract, as a recycled page's
+    # residue is); a lane's dead table entries name it
+    kp = kp.at[P - 1].set(100 if quant else 3e4)
+    vp = vp.at[P - 1].set(-100 if quant else -3e4)
+    tbl = 1 + np.arange(S * W, dtype=np.int32).reshape(S, W)
+    for s, ln in enumerate(lens):
+        tbl[s, -(-ln // pg):] = P - 1
+    tbl, kv_len = jnp.asarray(tbl), jnp.asarray(lens, jnp.int32)
+    got = decode_at(q, kp, vp, tbl, kv_len, interpret=True, **kw)
+    kk, vv = gather_kv_pages(kp, vp, tbl, dtype=jnp.float32, **kw)
+    ref = _sdpa_positions(
+        q.astype(jnp.float32)[:, None], kk.astype(jnp.float32),
+        vv.astype(jnp.float32), jnp.maximum(kv_len - 1, 0)[:, None])[:, 0]
+    got = np.asarray(got, np.float32)
+    assert not np.isnan(got).any()
+    assert (got[0] == 0).all()                   # the lane with kv_len 0
+    np.testing.assert_allclose(got[1:], np.asarray(ref)[1:], **tol)
+
+
+@pytest.mark.parametrize("W, B", [(20, 8), (16, 8), (4, 4), (5, 2)])
+def test_window_pages_live_then_still(W, B):
+    """What the decode kernel's index maps read: window i of block j shows
+    page j*B + i of the lane while that page is live; a dead window shows
+    what it showed a block earlier (so the pipeline fetches nothing for
+    it), back to its last live block or, if it never had one, to block 0."""
+    pg = 8
+    lens = np.asarray([0, 1, pg, B * pg - 1, B * pg, B * pg + 1,
+                       W * pg - 1, W * pg], np.int32)
+    S = len(lens)
+    tbl = 1 + np.arange(S * W, dtype=np.int32).reshape(S, W)
+    got = np.asarray(_window_pages(jnp.asarray(tbl), jnp.asarray(lens), pg, B))
+    nb = -(-W // B)
+    assert got.shape == (S, nb * B)
+    live = -(-lens // pg)
+    for s in range(S):
+        for j in range(nb):
+            for i in range(B):
+                page = j * B + i
+                if page < live[s]:
+                    assert got[s, page] == tbl[s, page]
+                elif j > 0:
+                    assert got[s, page] == got[s, page - B]
+                else:
+                    assert got[s, page] == tbl[s, min(i, W - 1)]
+
+
+@pytest.mark.parametrize("shape, want", [
+    ((128, 4, 64, 128, 2), 8),     # falcon-h1-34b's tick: 512 tokens
+    ((128, 4, 64, 64, 2), 8),      # hybrid-280m's: a 64-wide row pads to 128
+    ((4, 4, 64, 128, 2), 4),       # a table narrower than a block: one block
+    ((128, 8, 64, 64, 1), 8),      # int8 pages
+    ((128, 4, 16, 128, 2), 32),    # small pages: still 512 tokens
+    ((128, 32, 256, 256, 2), 1),   # a 4 MB page: the VMEM budget, at least 1
+])
+def test_pick_page_block_from_shapes(shape, want):
+    assert _pick_page_block(*shape) == want
+
+
+@pytest.mark.parametrize("S", [8, 16])
+@pytest.mark.parametrize("cell", sorted(CELL_HEADS))
+def test_ragged_kernel_tpu_lowering_at_the_cells_shapes(cell, S):
+    """The Mosaic lowering (``jax.export``, no chip) of the decode kernel as
+    the two longdoc cells' ticks call it: the whole pool of 16 slots x 128
+    pages, 8,192-token tables, both rungs of the ladder, the layer traced."""
+    nh, hd, A = CELL_HEADS[cell]
+    nkv, pg, W, P = 4, 64, 128, 2049
+    sds = jax.ShapeDtypeStruct
+    pool = sds((A, P, nkv, pg, hd), jnp.bfloat16)
+
+    def f(q, kp, vp, a, tbl, ln):
+        return ragged_paged_decode_attention(q, kp, vp, a, tbl, ln,
+                                             interpret=False)
+
+    exp = jax.export.export(jax.jit(f), platforms=["tpu"])(
+        sds((S, nh, hd), jnp.bfloat16), pool, pool, sds((), jnp.int32),
+        sds((S, W), jnp.int32), sds((S,), jnp.int32))
+    assert exp.platforms == ("tpu",)
+    assert exp.out_avals[0].shape == (S, nh, hd)
 
 
 def test_attention_step_kernel_path_matches_lax(rng, monkeypatch):
