@@ -36,11 +36,24 @@ class ModelConfig:
     # --- MoE (beyond the reference; completes the parallelism menu with
     # expert parallelism over mesh.expert) ---
     # 0 => dense gated MLP; > 1 => the MLP becomes a token-choice top-k
-    # mixture of experts (GShard-style dense-dispatch einsums: static
-    # shapes, MXU-friendly; experts shard over the mesh's expert axis)
+    # mixture of experts of width ``d_intermediate`` each.  The layer is
+    # DROPLESS (models/lm._moe_mlp): top-k of the router's float32 logits,
+    # a float32 softmax over the chosen, every chosen row computed whatever
+    # an expert's load; static shapes on every entry, training and serving
     moe_num_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
+    # The experts THIS program holds of each layer: ``moe_experts_held`` of
+    # them from ``moe_first_expert`` on (0 held => all).  The router keeps
+    # its width ``moe_num_experts`` and its ``moe_top_k`` choices a token;
+    # the layer computes the part of the routed sum its own experts give
+    # and leaves the rest out (the chip's share of a deployment whose other
+    # experts live elsewhere; no exchange is run and nothing stands in for
+    # the absent ones).
+    moe_first_expert: int = 0
+    moe_experts_held: int = 0
+    # width of a shared expert, a gated MLP every token passes, added to
+    # the routed sum (0 => none)
+    moe_shared_intermediate: int = 0
     # weight of the Switch/GShard load-balance aux loss added by lm_loss
     moe_aux_weight: float = 0.01
     rms_norm: bool = True
@@ -101,6 +114,15 @@ class ModelConfig:
     attention_in_multiplier: float = 1.0  # parallel block: on attention's input
     attention_out_multiplier: float = 1.0  # parallel block: on attention's output
     key_multiplier: float = 1.0  # on the keys, before RoPE
+    # on each half-block's output (the mixer's or attention's, the MLP's or
+    # the expert layer's) before it is added to the residual stream
+    residual_multiplier: float = 1.0
+    # the softmax scale of attention where a config STATES one; 0 => the
+    # usual 1 / sqrt(head_dim).  Applied to the queries as
+    # ``attention_multiplier * sqrt(head_dim)``, so that every attention
+    # path's own 1 / sqrt(head_dim) gives the stated scale
+    # (models/attention._split_qkv)
+    attention_multiplier: float = 0.0
     # on the gated MLP's gate (before the SiLU) and on its down-projection
     mlp_multipliers: tuple[float, ...] = ()
     # attention strategy under sequence parallelism: "ring" (KV rotates,
@@ -689,6 +711,18 @@ class ModelConfig:
                     f"moe_top_k={self.moe_top_k} must be in "
                     f"[1, {self.moe_num_experts}]"
                 )
+        first, held = self.moe_first_expert, self.moe_experts_held
+        if not (0 <= first and 0 <= held
+                and first + held <= self.moe_num_experts):
+            raise ValueError(
+                f"the held experts [{first}, {first + held}) must lie within "
+                f"the router's {self.moe_num_experts}"
+            )
+        if self.moe_shared_intermediate and not self.moe_num_experts:
+            raise ValueError(
+                "moe_shared_intermediate is the shared expert of a routed "
+                "layer: it needs moe_num_experts > 0"
+            )
 
     def autoscale_policy(self):
         """The ``serving.autoscale.AutoscalePolicy`` these knobs
@@ -726,6 +760,13 @@ class ModelConfig:
         the mixer's place, or beside it in a parallel block."""
         attn = i in self.attn_layer_idx
         return self.attn_parallel or not attn, attn
+
+    @property
+    def moe_held(self) -> tuple[int, int]:
+        """(first, count) of the routed experts this program holds."""
+        if self.moe_experts_held:
+            return self.moe_first_expert, self.moe_experts_held
+        return 0, self.moe_num_experts
 
     @property
     def n_mamba_layers(self) -> int:
@@ -839,8 +880,9 @@ class ModelConfig:
                 n += d  # second norm
                 mlp = d * self.d_intermediate * 2 + self.d_intermediate * d
                 if self.moe_num_experts:
-                    n += d * self.moe_num_experts  # router
-                    n += self.moe_num_experts * mlp  # expert-stacked MLPs
+                    n += d * self.moe_num_experts  # router, whole
+                    n += self.moe_held[1] * mlp  # the experts held here
+                    n += 3 * d * self.moe_shared_intermediate
                 else:
                     n += mlp  # gated MLP
         n += d  # final norm
@@ -1144,6 +1186,15 @@ _FALCON_H1_34B_MULTIPLIERS = dict(
     mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
 )
 
+# the published config's multipliers (config.json of granite-4.0-h-small;
+# logits_scaling 16 is the head's 1/16)
+_GRANITE_4_H_MULTIPLIERS = dict(
+    embedding_multiplier=12.0,
+    lm_head_multiplier=0.0625,
+    residual_multiplier=0.22,
+    attention_multiplier=0.0078125,
+)
+
 PRESETS: dict[str, TrainConfig] = {
     # 0. quick-start: minutes on a CPU, for smoke runs and demos
     "mamba2-tiny": _mk(
@@ -1285,6 +1336,44 @@ PRESETS: dict[str, TrainConfig] = {
              attn_num_heads=4, attn_num_kv_heads=2, attn_head_dim=8,
              rope_theta=1e11,
              **_FALCON_H1_34B_MULTIPLIERS,
+             kv_slot_tokens=256, kv_page_tokens=16,
+             prefill_chunk_tokens=64),
+        dict(seq_len=128, micro_batch_size=4, total_batch_size=512),
+    ),
+    # 7. granite-4.0-h-small (ibm-granite; huggingface.co/ibm-granite/
+    # granite-4.0-h-small config.json, "32B-A9B") at its published widths:
+    # nine Mamba-2 layers (128 heads of 64, state 128, one group) and one
+    # NoPE attention layer (32/8 heads of 128) a period of ten, each
+    # followed by 72 routed experts of width 768 (top-10, softmax over the
+    # chosen) beside a shared expert of 1,536; four scalar multipliers;
+    # tied embedding of 100,352 rows.  Two cuts that go together: ONE
+    # period of the 40 layers, and one chip's 36 of each layer's 72 experts
+    # (two chips share a layer).  A serving preset
+    # (benchmark/configs/granite-4.0-h-small.json states the cuts).
+    "granite-4.0-h-small": _mk(
+        dict(d_model=4096, n_layer=10, vocab_size=100352, ssm_layer="mamba2",
+             headdim=64, d_state=128, ngroups=1, chunk_size=256,
+             attn_layer_idx=(5,), attn_num_heads=32, attn_num_kv_heads=8,
+             attn_head_dim=128, attn_rotary_dim=0,
+             d_intermediate=768, moe_num_experts=72, moe_top_k=10,
+             moe_first_expert=0, moe_experts_held=36,
+             moe_shared_intermediate=1536, param_dtype="bfloat16",
+             **_GRANITE_4_H_MULTIPLIERS,
+             kv_slot_tokens=2048, kv_page_tokens=64,
+             prefill_chunk_tokens=512),
+        dict(),
+    ),
+    # its CPU-runnable toy: the same block pattern, gate and multipliers at
+    # tiny widths, half of eight experts held
+    "granite-h-tiny": _mk(
+        dict(d_model=64, n_layer=4, vocab_size=512, ssm_layer="mamba2",
+             headdim=16, d_state=32, ngroups=1, chunk_size=32,
+             attn_layer_idx=(1, 3), attn_num_heads=4, attn_num_kv_heads=2,
+             attn_head_dim=16, attn_rotary_dim=0,
+             d_intermediate=24, moe_num_experts=8, moe_top_k=3,
+             moe_first_expert=0, moe_experts_held=4,
+             moe_shared_intermediate=48,
+             **_GRANITE_4_H_MULTIPLIERS,
              kv_slot_tokens=256, kv_page_tokens=16,
              prefill_chunk_tokens=64),
         dict(seq_len=128, micro_batch_size=4, total_batch_size=512),

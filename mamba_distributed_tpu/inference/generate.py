@@ -128,6 +128,8 @@ def _decode_params(params: dict, cfg: ModelConfig) -> dict:
             and keys[-2] not in ("conv", "router")
         ):
             return leaf.astype(cd)
+        if keys[-2:] in (["moe", "w1"], ["moe", "w2"]):
+            return leaf.astype(cd)  # the routed experts' stacked kernels
         return leaf
 
     return jax.tree_util.tree_map_with_path(cast, params)

@@ -97,6 +97,12 @@ def _split_qkv(qkv: jax.Array, cfg: ModelConfig):
     if cfg.key_multiplier != 1.0:
         # a published scalar on the keys (before RoPE, which is linear)
         k = (k.astype(jnp.float32) * cfg.key_multiplier).astype(k.dtype)
+    if cfg.attention_multiplier:
+        # a STATED softmax scale: every attention path below divides its
+        # scores by sqrt(hd), so the queries carry the stated scale times
+        # sqrt(hd) (before RoPE, which is linear; q is never cached)
+        q = (q.astype(jnp.float32)
+             * (cfg.attention_multiplier * math.sqrt(hd))).astype(q.dtype)
     return q, k, v
 
 

@@ -86,6 +86,7 @@ def _init_block(key: jax.Array, cfg: ModelConfig, attn: bool) -> dict:
         p["norm2"] = {"weight": jnp.ones((cfg.d_model,), jnp.float32)}
         if cfg.moe_num_experts:
             E = cfg.moe_num_experts
+            first, held = cfg.moe_held
             k_r, k_e = jax.random.split(k_mlp)
 
             def one_expert(k):
@@ -97,12 +98,23 @@ def _init_block(key: jax.Array, cfg: ModelConfig, attn: bool) -> dict:
                                 False)["kernel"] * rescale,
                 )
 
-            w1, w2 = jax.vmap(one_expert)(jax.random.split(k_e, E))
+            # an expert's draw is its own key's whatever the share held
+            w1, w2 = jax.vmap(one_expert)(
+                jax.random.split(k_e, E)[first:first + held])
             p["moe"] = {
                 "router": init_linear(k_r, cfg.d_model, E, False),
-                "w1": w1,  # (E, d, 2*di)
-                "w2": w2,  # (E, di, d)
+                "w1": w1,  # (held, d, 2*di)
+                "w2": w2,  # (held, di, d)
             }
+            if cfg.moe_shared_intermediate:
+                ds = cfg.moe_shared_intermediate
+                k1, k2 = jax.random.split(jax.random.fold_in(k_mlp, 1))
+                p["shared"] = {
+                    "fc1": init_linear(k1, cfg.d_model, 2 * ds, False),
+                    "fc2": init_linear(k2, ds, cfg.d_model, False),
+                }
+                p["shared"]["fc2"]["kernel"] = (
+                    p["shared"]["fc2"]["kernel"] * rescale)
         else:
             k1, k2 = jax.random.split(k_mlp)
             p["mlp"] = {
@@ -173,64 +185,127 @@ def _gated_mlp(params: dict, x: jax.Array, compute_dtype,
         return out
 
 
-def _moe_mlp(params: dict, cfg: ModelConfig, x: jax.Array, compute_dtype):
-    """Token-choice top-k mixture of gated-MLP experts -> (out, aux).
+# The dropless expert layer keeps ONE form per entry, chosen from the shape
+# it is traced at.  Up to this many rows (a decode tick's lanes, a verify
+# chunk) every held expert runs over every row with the gate as a mask:
+# a row costs an expert 6 * d * di operations against the 6 * d * di bytes
+# of bfloat16 weights the expert's read takes anyway, so under the chip's
+# ridge (240 operations a byte on a v5e) the read bounds the time and the
+# form adds none, needs no sort and no gather, and takes the same time
+# whatever the routing.  Over it (a prefill chunk, a training batch) the
+# rows are sorted by expert into a buffer of the most that can land here
+# and multiplied in groups (``jax.lax.ragged_dot``), the tail masked.
+MOE_DENSE_MAX_ROWS = 128
 
-    GShard/Switch-style dense-dispatch formulation, TPU-first: routing,
-    capacity assignment, dispatch and combine are all static-shape
-    einsums (no gather/scatter, no dynamic shapes), so the MXU runs the
-    expert matmuls and GSPMD turns the dispatch/combine contractions
-    into all-to-alls when experts are sharded over ``mesh.expert``.
-    Tokens over an expert's capacity are dropped (combine weight 0 —
-    the residual connection carries them).  ``aux`` is the Switch
-    load-balance loss E * sum_e f_e * P_e (== 1 at perfect balance),
-    averaged into lm_loss with weight cfg.moe_aux_weight.
+
+def _moe_mlp(params: dict, cfg: ModelConfig, x: jax.Array, compute_dtype,
+             row_mask=None):
+    """Token-choice top-k mixture of gated-MLP experts, DROPLESS, over the
+    experts this program holds -> (out, aux, load).
+
+    The router scores all ``moe_num_experts`` in float32; a row's
+    ``moe_top_k`` experts are the top-k of the LOGITS and its gates a
+    float32 softmax over those k alone (the published order; a near-tie
+    between the k-th and the next logit is the one place where rounding
+    changes which expert runs).  Of the row's choices, those whose expert
+    lies in ``cfg.moe_held`` are computed here, every one of them whatever
+    the expert's load: no capacity and no drop.  The others are left out:
+    their experts live elsewhere and nothing stands in for them or for an
+    exchange.  ``out`` is the sum over the row's held choices of
+    ``gate * w2[e](y * silu(gate_proj))``.
+
+    Shapes are static in both forms (``MOE_DENSE_MAX_ROWS`` above), so a
+    seed's routing changes no program, and what a row is answered does not
+    depend on the rows beside it.
+
+    ``aux`` is the Switch load-balance loss E * sum_e f_e * P_e (== 1 at
+    perfect balance) over all ``moe_num_experts``, averaged into lm_loss
+    with weight cfg.moe_aux_weight.  ``load`` ((held + 1,) int32) counts,
+    a held expert, the rows routed to it, and last the held experts that
+    any row reached; of the rows ``row_mask`` (x.shape[:-1]; None: all)
+    marks, since a pad lane's or a pad position's rows are computed like
+    any other and answer nobody.
     """
     E, k = cfg.moe_num_experts, cfg.moe_top_k
-    b, t, d = x.shape
-    n = b * t
-    cap = max(1, -(-int(cfg.moe_capacity_factor * k * n) // E))
-
-    xt = x.reshape(n, d)
-    logits = linear(params["router"], xt, jnp.float32)           # (n, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)                # (n, k)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
-    )
-
-    # position of each (choice, token) in its expert's queue — primary
-    # choices of every token get capacity before any secondary choice
-    oh = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)          # (n, k, E)
-    ohf = jnp.swapaxes(oh, 0, 1).reshape(k * n, E)               # priority
-    pos_f = jnp.cumsum(ohf, axis=0) - ohf                        # (k*n, E)
-    pos = jnp.sum(pos_f * ohf, axis=-1).reshape(k, n).T          # (n, k)
-    keep = (pos < cap).astype(gate_vals.dtype)
-    gate_vals = gate_vals * keep
-
-    # (n, k, E, C) one-hot over (expert, slot) -> dispatch/combine (n, E, C)
-    slot = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=jnp.float32)
-    sel = oh[..., None] * slot[:, :, None, :] * keep[..., None, None]
-    dispatch = jnp.sum(sel, axis=1)                              # (n, E, C)
-    combine = jnp.sum(sel * gate_vals[..., None, None], axis=1)  # (n, E, C)
-
+    first, held = cfg.moe_held
+    lead, d = x.shape[:-1], x.shape[-1]
+    xt = x.reshape(-1, d)
+    n = xt.shape[0]
     cd = compute_dtype
-    xe = jnp.einsum("nd,nec->ecd", xt.astype(cd), dispatch.astype(cd),
-                    preferred_element_type=jnp.float32).astype(cd)
-    yz = jnp.einsum("ecd,edf->ecf", xe, params["w1"].astype(cd),
-                    preferred_element_type=jnp.float32)          # (E,C,2di)
-    y, gate = jnp.split(yz, 2, axis=-1)
-    h = (y * jax.nn.silu(gate)).astype(cd)
-    ye = jnp.einsum("ecf,efd->ecd", h, params["w2"].astype(cd),
-                    preferred_element_type=jnp.float32)          # (E, C, d)
-    out = jnp.einsum("nec,ecd->nd", combine.astype(jnp.float32), ye)
+
+    with jax.named_scope(scopes.ROUTER):
+        logits = linear(params["router"], xt, jnp.float32)       # (n, E)
+        top_v, top_e = jax.lax.top_k(logits, k)                  # (n, k)
+        gates = jax.nn.softmax(top_v, axis=-1)                   # over the k
+        local = top_e - first
+        here = (local >= 0) & (local < held)
+        # (n, k, held): the row's choice j is held expert e
+        oh = (local[..., None] == jnp.arange(held)) & here[..., None]
+        rows_e = jnp.sum(oh, axis=1, dtype=jnp.int32)            # (n, held)
+        sizes = jnp.sum(rows_e, axis=0)                          # (held,)
+        load = sizes if row_mask is None else jnp.sum(
+            jnp.where(row_mask.reshape(n, 1) > 0, rows_e, 0), axis=0)
+        load = jnp.append(load, jnp.sum(load > 0, dtype=jnp.int32))
+
+    if n <= MOE_DENSE_MAX_ROWS:
+        with jax.named_scope(scopes.EXPERTS):
+            gate_e = jnp.sum(jnp.where(oh, gates[..., None], 0.0), axis=1)
+            yz = jnp.einsum("nd,edf->enf", xt.astype(cd),
+                            params["w1"].astype(cd),
+                            preferred_element_type=jnp.float32)
+            y, g = jnp.split(yz, 2, axis=-1)
+            # the gate enters before the down-projection, which is linear:
+            # one product over (expert, width) then sums the routed terms
+            h = (y * jax.nn.silu(g) * gate_e.T[..., None]).astype(cd)
+            out = jnp.einsum("enf,efd->nd", h, params["w2"].astype(cd),
+                             preferred_element_type=jnp.float32)
+    else:
+        with jax.named_scope(scopes.ROUTER):
+            # every (row, choice) pair keyed by its held expert, the pairs
+            # of absent experts last; a stable sort groups them
+            key = jnp.where(here, local, held).reshape(-1)       # (n*k,)
+            order = jnp.argsort(key, stable=True)
+            rows = xt.astype(cd)[order // k]                     # (n*k, d)
+            g_sorted = jnp.where(here, gates, 0.0).reshape(-1)[order]
+        with jax.named_scope(scopes.EXPERTS):
+            yz = jax.lax.ragged_dot(rows, params["w1"].astype(cd), sizes,
+                                    preferred_element_type=jnp.float32)
+            y, g = jnp.split(yz, 2, axis=-1)
+            h = (y * jax.nn.silu(g) * g_sorted[:, None]).astype(cd)
+            ye = jax.lax.ragged_dot(h, params["w2"].astype(cd), sizes,
+                                    preferred_element_type=jnp.float32)
+            # the tail past the groups holds no product of a held expert
+            ye = jnp.where((jnp.arange(n * k) < jnp.sum(sizes))[:, None],
+                           ye, 0.0)
+            # back to (row, choice) order and summed over a row's choices
+            # in that order, whoever shares the buffer
+            out = jnp.sum(ye[jnp.argsort(order)].reshape(n, k, d), axis=1)
 
     # Switch aux: fraction routed to e (over all k choices) x mean prob
-    f = jnp.mean(jnp.sum(oh, axis=1), axis=0)                    # (E,)
-    P_mean = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(f * P_mean) / k
-    return out.reshape(b, t, d).astype(x.dtype), aux
+    probs = jax.nn.softmax(logits, axis=-1)
+    f = jnp.mean(jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32),
+                         axis=1), axis=0)                        # (E,)
+    aux = E * jnp.sum(f * jnp.mean(probs, axis=0)) / k
+    return out.reshape(*lead, d).astype(x.dtype), aux, load
+
+
+def _expert_layer(bp: dict, cfg: ModelConfig, x: jax.Array, compute_dtype,
+                  row_mask=None):
+    """The routed experts held here plus the shared expert every row
+    passes -> (out, aux, load), all under the scope ``moe``."""
+    with jax.named_scope(scopes.MOE):
+        out, aux, load = _moe_mlp(bp["moe"], cfg, x, compute_dtype, row_mask)
+        if cfg.moe_shared_intermediate:
+            out = out + _gated_mlp(bp["shared"], x, compute_dtype)
+    return out, aux, load
+
+
+def _scale_residual(cfg: ModelConfig, out):
+    """A half-block's output under ``cfg.residual_multiplier``, in float32
+    before the one rounding; 1.0 applies nothing and traces nothing."""
+    if cfg.residual_multiplier == 1.0:
+        return out
+    return (out.astype(jnp.float32) * cfg.residual_multiplier).astype(out.dtype)
 
 
 def _mamba_mix_fwd(mp, cfg, normed, seq_ctx, return_state, token_mask,
@@ -296,9 +371,11 @@ def _block_fwd(block_params, cfg, hidden, residual, attn: bool = False,
     block.  A PARALLEL block (``cfg.attn_parallel``) runs a Mamba mixer
     and attention on the one normed input and adds them; it is not asked.
 
-    ``return_state=True`` (prefill) additionally returns the block's decode
-    state, always the pair (Mamba's, attention's) with None for the half a
-    block lacks: the conv+SSM caches, the attention K/V.  ``token_mask``
+    ``return_state=True`` (prefill) returns ``(hidden, residual, state,
+    load)``: the block's decode state, always the pair (Mamba's,
+    attention's) with None for the half a block lacks (the conv+SSM caches,
+    the attention K/V), and the expert layer's ``load`` (``_moe_mlp``, of
+    the positions ``token_mask`` marks; None for a dense block).  ``token_mask``
     (prefill only) zeroes the mixer's scan inputs at left-pad positions
     (inference/bucketing.py).  ``initial_state`` (chunked prefill) is the
     same pair from the previous chunk: the ``(conv_state, ssm_state)``
@@ -344,21 +421,23 @@ def _block_fwd(block_params, cfg, hidden, residual, attn: bool = False,
         hidden, st_m = _mamba_mix_fwd(
             block_params["mixer"], cfg, normed, seq_ctx, return_state,
             token_mask, init_m)
-    aux = jnp.zeros((), jnp.float32)
+    hidden = _scale_residual(cfg, hidden)
+    aux, load = jnp.zeros((), jnp.float32), None
     if cfg.d_intermediate > 0:
         normed, residual = add_rms_norm(
             hidden, residual, block_params["norm2"]["weight"], cfg.norm_eps,
             residual_dtype=jnp.float32 if cfg.residual_in_fp32 else compute_dtype,
         )
         if cfg.moe_num_experts:
-            hidden, aux = _moe_mlp(
-                block_params["moe"], cfg, normed, compute_dtype
+            hidden, aux, load = _expert_layer(
+                block_params, cfg, normed, compute_dtype, token_mask
             )
         else:
             hidden = _gated_mlp(block_params["mlp"], normed, compute_dtype,
                                 cfg.mlp_multipliers)
+        hidden = _scale_residual(cfg, hidden)
     if return_state:
-        return hidden, residual, (st_m, st_a)
+        return hidden, residual, (st_m, st_a), load
     if cfg.moe_num_experts:
         return hidden, residual, aux
     return hidden, residual
@@ -820,7 +899,7 @@ def lm_prefill(params: dict, cfg: ModelConfig, input_ids: jax.Array,
 
         def mbody(carry, bp):
             h, rs = carry
-            h, rs, (st, _) = _block_fwd(bp, cfg, h, rs, return_state=True)
+            h, rs, (st, _), _ = _block_fwd(bp, cfg, h, rs, return_state=True)
             return (h, rs), st
 
         def group(carry, xs):
@@ -829,7 +908,7 @@ def lm_prefill(params: dict, cfg: ModelConfig, input_ids: jax.Array,
                 carry, st_pre = jax.lax.scan(
                     mbody, carry, jax.tree.map(lambda x: x[:r], mblk)
                 )
-            hidden, residual, (_, a_st) = _block_fwd(
+            hidden, residual, (_, a_st), _ = _block_fwd(
                 ablk, cfg, *carry, True, return_state=True
             )
             with jax.named_scope(scopes.LAYERS):
@@ -868,7 +947,7 @@ def lm_prefill(params: dict, cfg: ModelConfig, input_ids: jax.Array,
                 bp = jax.tree.map(
                     lambda p, j=(ai if attn else mi): p[j], stack
                 )
-                hidden, residual, (m_st, a_st) = _block_fwd(
+                hidden, residual, (m_st, a_st), _ = _block_fwd(
                     bp, cfg, hidden, residual, attn, return_state=True
                 )
             if attn:
@@ -895,7 +974,7 @@ def lm_prefill(params: dict, cfg: ModelConfig, input_ids: jax.Array,
 
         def body(carry, bp):
             hidden, residual = carry
-            hidden, residual, (m_st, a_st) = _block_fwd(
+            hidden, residual, (m_st, a_st), _ = _block_fwd(
                 bp, cfg, hidden, residual, return_state=True,
                 token_mask=token_mask,
             )
@@ -926,7 +1005,8 @@ def lm_prefill(params: dict, cfg: ModelConfig, input_ids: jax.Array,
 
 
 def lm_prefill_chunk(params: dict, cfg: ModelConfig, input_ids: jax.Array,
-                     state, token_mask: jax.Array | None = None):
+                     state, token_mask: jax.Array | None = None,
+                     return_load: bool = False):
     """Resumable prefill: one chunk of a prompt, carries threaded through.
 
     The chunked-prefill workhorse (serving/prefill.py): identical to the
@@ -959,12 +1039,16 @@ def lm_prefill_chunk(params: dict, cfg: ModelConfig, input_ids: jax.Array,
     pages are written in place.
 
     Returns (last_logits (b, V) fp32, new state) — same contract as
-    ``lm_prefill``.
+    ``lm_prefill``; with ``return_load`` also the expert layers' load
+    (``_moe_mlp``: (held + 1,) int32, of the positions ``token_mask``
+    marks) summed over the layers; None for a dense model.
     """
-    hidden, residual, new_state = _chunk_backbone(
+    hidden, residual, new_state, load = _chunk_backbone(
         params, cfg, input_ids, state, token_mask
     )
     logits = _final_logits(params, cfg, hidden[:, -1:], residual[:, -1:])
+    if return_load:
+        return logits[:, 0].astype(jnp.float32), new_state, load
     return logits[:, 0].astype(jnp.float32), new_state
 
 
@@ -992,7 +1076,7 @@ def lm_verify_chunk(params: dict, cfg: ModelConfig, input_ids: jax.Array,
     written cells are dead-by-``lengths`` and the next verify rewrites
     them — the same invariant the ragged kernels already honor for
     masked rows."""
-    hidden, residual, new_state = _chunk_backbone(
+    hidden, residual, new_state, _ = _chunk_backbone(
         params, cfg, input_ids, state, token_mask
     )
     logits = _final_logits(params, cfg, hidden, residual)
@@ -1018,6 +1102,19 @@ def _with_layer(stacked, i, new):
     )
 
 
+def _zero_load(cfg: ModelConfig):
+    """The expert load a layer loop starts from: zeros (``_moe_mlp``), or
+    None for a dense model (no leaf in the loop's carry, so its program is
+    what it was)."""
+    if not cfg.moe_num_experts:
+        return None
+    return jnp.zeros((cfg.moe_held[1] + 1,), jnp.int32)
+
+
+def _add_load(load, layer_load):
+    return None if load is None else load + layer_load
+
+
 def _hybrid_layers(params: dict, cfg: ModelConfig, hidden, residual, state,
                    block):
     """The layer loop of the two stateful steps (``lm_step``,
@@ -1025,11 +1122,14 @@ def _hybrid_layers(params: dict, cfg: ModelConfig, hidden, residual, state,
     hybrid, periodic (scanned by group) or not (unrolled), and a stack of
     parallel blocks (one scan).
 
-    ``block(bp, h, rs, st, kv, a) -> (h, rs, st', kv')`` runs one block:
-    ``st`` is its layer's ``(conv, ssm)`` state, None for an attention
-    block of a two-stack model; ``kv`` the WHOLE page pool and ``a`` its
-    layer's index into it, both None for a Mamba block, which hands None
-    back.  A parallel block (``cfg.attn_parallel``) gets and returns both.
+    ``block(bp, h, rs, st, kv, a) -> (h, rs, st', kv', load)`` runs one
+    block: ``st`` is its layer's ``(conv, ssm)`` state, None for an
+    attention block of a two-stack model; ``kv`` the WHOLE page pool and
+    ``a`` its layer's index into it, both None for a Mamba block, which
+    hands None back.  A parallel block (``cfg.attn_parallel``) gets and
+    returns both.  ``load`` is the block's expert load (``_moe_mlp``), None
+    for a dense block; the loop sums it over the layers in its carry, where
+    None adds no leaf.
 
     Both stacked states, ``state["blocks"]`` and ``state["attn_blocks"]``,
     ride the loop's CARRY, a layer addressed by its index (traced in the
@@ -1042,57 +1142,63 @@ def _hybrid_layers(params: dict, cfg: ModelConfig, hidden, residual, state,
     carry, on every sub-step and chunk (2 x 537 MB of pages at the
     benchmark's hybrid, five eighths of its device time).
 
-    Returns (hidden, residual, blocks', attn_blocks').
+    Returns (hidden, residual, blocks', attn_blocks', load).
     """
     blocks, akv = state["blocks"], state["attn_blocks"]
+    load = _zero_load(cfg)
 
     if cfg.attn_parallel:
         def layer(carry, xs):
-            h, rs, blocks, akv = carry
+            h, rs, blocks, akv, load = carry
             bp, i = xs
-            h, rs, st, akv = block(bp, h, rs, _layer_of(blocks, i), akv, i)
-            return (h, rs, _with_layer(blocks, i, st), akv), None
+            h, rs, st, akv, ld = block(
+                bp, h, rs, _layer_of(blocks, i), akv, i)
+            return (h, rs, _with_layer(blocks, i, st), akv,
+                    _add_load(load, ld)), None
 
         with jax.named_scope(scopes.ATTN_LAYERS):
-            (hidden, residual, blocks, akv), _ = jax.lax.scan(
-                layer, (hidden, residual, blocks, akv),
+            (hidden, residual, blocks, akv, load), _ = jax.lax.scan(
+                layer, (hidden, residual, blocks, akv, load),
                 (params["blocks"], jnp.arange(cfg.n_layer)),
             )
-        return hidden, residual, blocks, akv
+        return hidden, residual, blocks, akv, load
 
     def mamba(carry, xs):
-        h, rs, blocks = carry
+        h, rs, blocks, load = carry
         bp, i = xs
-        h, rs, st, _ = block(bp, h, rs, _layer_of(blocks, i), None, None)
-        return (h, rs, _with_layer(blocks, i, st)), None
+        h, rs, st, _, ld = block(bp, h, rs, _layer_of(blocks, i), None, None)
+        return (h, rs, _with_layer(blocks, i, st), _add_load(load, ld)), None
 
     if (per := _hybrid_period(cfg)) is not None:
         p, r = per
 
-        def group(carry, xs):
-            h, rs, blocks, akv = carry
-            mblk, ablk, g = xs
+        # Both loops run over layer INDICES and read a layer's weights out
+        # of the whole stack where it stands, as they read its state: a
+        # stack sliced by group, and a group's by the attention layer's
+        # place in it, would be copied on every sub-step and chunk (each
+        # slice is a buffer of its own: 4.5 GB of a routed model's experts).
+        def mamba_at(carry, i):
+            return mamba(carry, (_layer_of(params["blocks"], i), i))
+
+        def group(carry, g):
+            h, rs, blocks, akv, load = carry
             first = g * (p - 1)  # the group's first Mamba layer
             with jax.named_scope(scopes.LAYERS):
-                (h, rs, blocks), _ = jax.lax.scan(
-                    mamba, (h, rs, blocks),
-                    (jax.tree.map(lambda v: v[:r], mblk),
-                     first + jnp.arange(r)),
-                )
-            h, rs, _, akv = block(ablk, h, rs, None, akv, g)
+                (h, rs, blocks, load), _ = jax.lax.scan(
+                    mamba_at, (h, rs, blocks, load), first + jnp.arange(r))
+            h, rs, _, akv, ld = block(
+                _layer_of(params["attn_blocks"], g), h, rs, None, akv, g)
+            load = _add_load(load, ld)
             with jax.named_scope(scopes.LAYERS):
-                (h, rs, blocks), _ = jax.lax.scan(
-                    mamba, (h, rs, blocks),
-                    (jax.tree.map(lambda v: v[r:], mblk),
-                     first + r + jnp.arange(p - 1 - r)),
-                )
-            return (h, rs, blocks, akv), None
+                (h, rs, blocks, load), _ = jax.lax.scan(
+                    mamba_at, (h, rs, blocks, load),
+                    first + r + jnp.arange(p - 1 - r))
+            return (h, rs, blocks, akv, load), None
 
         with jax.named_scope(scopes.ATTN_LAYERS):
-            (hidden, residual, blocks, akv), _ = jax.lax.scan(
-                group, (hidden, residual, blocks, akv),
-                (_group_mamba_stack(params, cfg, p), params["attn_blocks"],
-                 jnp.arange(len(cfg.attn_layer_idx))),
+            (hidden, residual, blocks, akv, load), _ = jax.lax.scan(
+                group, (hidden, residual, blocks, akv, load),
+                jnp.arange(len(cfg.attn_layer_idx)),
             )
     else:
         attn_idx = set(cfg.attn_layer_idx)
@@ -1103,24 +1209,27 @@ def _hybrid_layers(params: dict, cfg: ModelConfig, hidden, residual, state,
                     bp = jax.tree.map(
                         lambda p_, j=ai: p_[j], params["attn_blocks"]
                     )
-                    hidden, residual, _, akv = block(
+                    hidden, residual, _, akv, ld = block(
                         bp, hidden, residual, None, akv, ai
                     )
+                    load = _add_load(load, ld)
                     ai += 1
                 else:
                     bp = jax.tree.map(lambda p_, j=mi: p_[j], params["blocks"])
-                    (hidden, residual, blocks), _ = mamba(
-                        (hidden, residual, blocks), (bp, mi)
+                    (hidden, residual, blocks, load), _ = mamba(
+                        (hidden, residual, blocks, load), (bp, mi)
                     )
                     mi += 1
-    return hidden, residual, blocks, akv
+    return hidden, residual, blocks, akv, load
 
 
 def _chunk_backbone(params: dict, cfg: ModelConfig, input_ids: jax.Array,
                     state, token_mask: jax.Array | None = None):
     """Shared body of ``lm_prefill_chunk``/``lm_verify_chunk``: embed ->
-    carry-threaded layer stack -> (hidden, residual, new state).  One
-    implementation so the prefill and verify paths cannot diverge."""
+    carry-threaded layer stack -> (hidden, residual, new state, load).  One
+    implementation so the prefill and verify paths cannot diverge.
+    ``load`` is the expert layers' (``_moe_mlp``, of the positions
+    ``token_mask`` marks), summed over the layers; None for a dense model."""
     compute_dtype = jnp.dtype(cfg.compute_dtype)
     hidden = _embed(params, cfg, input_ids)
     residual = jnp.zeros_like(
@@ -1128,13 +1237,13 @@ def _chunk_backbone(params: dict, cfg: ModelConfig, input_ids: jax.Array,
     )
 
     def body(carry, xs):
-        hidden, residual = carry
+        hidden, residual, load = carry
         bp, st = xs
-        hidden, residual, (new_st, _) = _block_fwd(
+        hidden, residual, (new_st, _), ld = _block_fwd(
             bp, cfg, hidden, residual, return_state=True,
             token_mask=token_mask, initial_state=(st, None),
         )
-        return (hidden, residual), new_st
+        return (hidden, residual, _add_load(load, ld)), new_st
 
     if cfg.attn_layer_idx:
         tbl, lengths = state["attn_meta"]
@@ -1148,26 +1257,27 @@ def _chunk_backbone(params: dict, cfg: ModelConfig, input_ids: jax.Array,
 
         def block(bp, h, rs, st, akv, a):
             paged = None if a is None else (akv, a, tbl, lengths)
-            h, rs, new = _block_fwd(
+            h, rs, new, ld = _block_fwd(
                 bp, cfg, h, rs, st is None, return_state=True,
                 token_mask=token_mask, initial_state=(st, paged),
             )
-            return (h, rs, *new)
+            return (h, rs, *new, ld)
 
-        hidden, residual, new_blocks, new_a = _hybrid_layers(
+        hidden, residual, new_blocks, new_a, load = _hybrid_layers(
             params, cfg, hidden, residual, state, block
         )
         return hidden, residual, {
             "blocks": new_blocks,
             "attn_blocks": new_a,
             "attn_meta": (tbl, lengths + n_real),
-        }
+        }, load
 
     with jax.named_scope(scopes.LAYERS):
-        (hidden, residual), state_blocks = jax.lax.scan(
-            body, (hidden, residual), (params["blocks"], state["blocks"])
+        (hidden, residual, load), state_blocks = jax.lax.scan(
+            body, (hidden, residual, _zero_load(cfg)),
+            (params["blocks"], state["blocks"])
         )
-    return hidden, residual, {"blocks": state_blocks}
+    return hidden, residual, {"blocks": state_blocks}, load
 
 
 def init_lm_blocks_state(cfg: ModelConfig, batch: int):
@@ -1206,9 +1316,10 @@ def init_lm_state(cfg: ModelConfig, batch: int, max_len: int = 0):
 
 
 def _block_step(bp, cfg: ModelConfig, hidden, residual, st, akv=None,
-                attn_ctx=None, state_mask=None):
+                attn_ctx=None, state_mask=None, row_mask=None):
     """One decode-step block (shared by the scan and unrolled paths) ->
-    (hidden, residual, st', akv').  ``st`` is the block's ``(conv, ssm)``
+    (hidden, residual, st', akv', load); ``load`` is the expert layer's
+    (``_moe_mlp``, of the rows ``row_mask`` marks), None for a dense block.  ``st`` is the block's ``(conv, ssm)``
     state, None for an attention block of a two-stack model; ``akv`` the
     whole page pool (it comes back whole) and ``attn_ctx = (page_table,
     lengths, write_mask, layer)`` the layer-shared paged-KV metadata plus
@@ -1244,27 +1355,32 @@ def _block_step(bp, cfg: ModelConfig, hidden, residual, st, akv=None,
         hidden, st = mix_step(
             bp["mixer"], cfg, normed, *st, state_mask=state_mask
         )
+    hidden = _scale_residual(cfg, hidden)
+    load = None
     if cfg.d_intermediate > 0:
         normed, residual = add_rms_norm(
             hidden, residual, bp["norm2"]["weight"], cfg.norm_eps,
         )
         if cfg.moe_num_experts:
-            hidden, _ = _moe_mlp(
-                bp["moe"], cfg, normed[:, None, :], compute_dtype
-            )
-            hidden = hidden[:, 0]
+            hidden, _, load = _expert_layer(bp, cfg, normed, compute_dtype,
+                                            row_mask)
         else:
             hidden = _gated_mlp(bp["mlp"], normed, compute_dtype,
                                 cfg.mlp_multipliers)
-    return hidden, residual, st, akv
+        hidden = _scale_residual(cfg, hidden)
+    return hidden, residual, st, akv, load
 
 
 def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
             write_mask: jax.Array | None = None, pipeline=None,
-            state_mask: jax.Array | None = None):
-    """One decode step.  token (b,) int32 -> (logits (b, V), new state).
+            state_mask: jax.Array | None = None, return_load: bool = False):
+    """One decode step.  token (b,) int32 -> (logits (b, V), new state);
+    with ``return_load`` also the expert layers' load (``_moe_mlp``:
+    (held + 1,) int32, of the rows ``write_mask`` marks) summed over the
+    layers; None for a dense model and under ``pipeline``.
 
-    ``write_mask`` (b,) bool (hybrid stacks only) marks rows whose paged
+    ``write_mask`` (b,) bool marks the rows that are served: the expert
+    load counts them alone, and on hybrid stacks they are the rows whose paged
     attention KV may be written this step; masked rows' writes land in
     the trash page and their ``lengths`` freeze — how the serving tick
     keeps dead/empty/prefilling slots from touching live pages while
@@ -1316,9 +1432,9 @@ def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
         def block(bp, h, rs, st, akv, a):
             ctx = None if a is None else (tbl, lengths, write_mask, a)
             return _block_step(bp, cfg, h, rs, st, akv, attn_ctx=ctx,
-                               state_mask=state_mask)
+                               state_mask=state_mask, row_mask=write_mask)
 
-        hidden, residual, new_blocks, new_a = _hybrid_layers(
+        hidden, residual, new_blocks, new_a, load = _hybrid_layers(
             params, cfg, hidden, residual, state, block
         )
         new_state = {
@@ -1328,6 +1444,7 @@ def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
         }
     else:
         residual = jnp.zeros_like(hidden, dtype=jnp.float32)
+        load = None
         if pipeline is not None:
             from mamba_distributed_tpu.parallel.pipeline import (
                 pipelined_decode_layers,
@@ -1339,8 +1456,8 @@ def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
             # no leaf), so each microbatch's rows travel the stages with it
             def pbody(act, bp, st):
                 h, rs, mask = act
-                h, rs, st, _ = _block_step(bp, cfg, h, rs, st,
-                                           state_mask=mask)
+                h, rs, st, _, _ = _block_step(bp, cfg, h, rs, st,
+                                              state_mask=mask)
                 return (h, rs, mask), st
 
             (hidden, residual, _), new_blocks = pipelined_decode_layers(
@@ -1354,16 +1471,18 @@ def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
             # updates one buffer in place from entry to exit, where scanned
             # inputs and outputs would be two pool-sized buffers
             def cbody(carry, xs):
-                h, rs, blocks = carry
+                h, rs, blocks, load = carry
                 bp, i = xs
-                h, rs, st, _ = _block_step(
+                h, rs, st, _, ld = _block_step(
                     bp, cfg, h, rs, _layer_of(blocks, i),
-                    state_mask=state_mask)
-                return (h, rs, _with_layer(blocks, i, st)), None
+                    state_mask=state_mask, row_mask=write_mask)
+                return (h, rs, _with_layer(blocks, i, st),
+                        _add_load(load, ld)), None
 
             with jax.named_scope(scopes.LAYERS):
-                (hidden, residual, new_blocks), _ = jax.lax.scan(
-                    cbody, (hidden, residual, state["blocks"]),
+                (hidden, residual, new_blocks, load), _ = jax.lax.scan(
+                    cbody, (hidden, residual, state["blocks"],
+                            _zero_load(cfg)),
                     (params["blocks"], jnp.arange(cfg.n_layer)),
                 )
         new_state = {"blocks": new_blocks}
@@ -1373,4 +1492,6 @@ def lm_step(params: dict, cfg: ModelConfig, state, token: jax.Array,
             hidden, residual, params["norm_f"]["weight"], cfg.norm_eps
         )
         logits = _head_logits(params, cfg, normed)
+    if return_load:
+        return logits.astype(jnp.float32), new_state, load
     return logits.astype(jnp.float32), new_state

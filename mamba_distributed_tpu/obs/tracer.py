@@ -24,6 +24,7 @@ import math
 import os
 import threading
 import time
+import types
 
 
 def jsonable(record: dict) -> dict:
@@ -156,13 +157,16 @@ class SpanTracer:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        """Time the enclosed host-side block as one span record."""
+        """Time the enclosed host-side block as one span record.  Yields a
+        handle whose ``attrs`` dict is the record's: what the block learns
+        while it runs (a launch's counters, once its fetch has returned) it
+        sets there before the span closes."""
         stack = self._stack()
         parent = stack[-1] if stack else None
         stack.append(name)
         t_start = self._clock()
         try:
-            yield
+            yield types.SimpleNamespace(attrs=attrs)
         finally:
             dur = self._clock() - t_start
             stack.pop()

@@ -72,6 +72,8 @@ _QUANT_RULES: tuple[tuple[tuple[str, ...], int], ...] = (
     (("mixer", "wqkv", "kernel"), -1),
     (("mlp", "fc1", "kernel"), -1),
     (("mlp", "fc2", "kernel"), -2),
+    (("shared", "fc1", "kernel"), -1),  # an expert layer's shared expert
+    (("shared", "fc2", "kernel"), -2),
     (("lm_head", "kernel"), -1),
 )
 
@@ -123,8 +125,10 @@ def quantize_serving_params(params: dict) -> dict:
     ``{"kernel": int8, "scale": f32}`` IN PLACE of its dict (bias and
     any other siblings ride along untouched), and the embedding array
     becomes the same dict form.  Everything else — conv, router,
-    dt_proj, biases, norms, SSM scalars, MoE experts — passes through
-    for the decode cast to handle as before.  Called from
+    dt_proj, biases, norms, SSM scalars, the routed experts ``moe/w1``
+    and ``moe/w2`` (einsum and ragged_dot operands, not ``linear()``'s;
+    int8 experts are ROADMAP D7) — passes through for the decode cast to
+    handle as before.  Called from
     ``inference/generate._decode_params`` (the ONE shared decode cast)
     when ``cfg.serving_weight_dtype == "int8"``."""
 

@@ -48,11 +48,15 @@ _TP_RULES: tuple[tuple[tuple[str, ...], int], ...] = (
     (("mixer", "wqkv", "kernel"), -1),
     (("mlp", "fc1", "kernel"), -1),
     (("mlp", "fc2", "kernel"), -2),
-    (("moe", "w1"), -1),                    # (E, d, 2*di): column
-    (("moe", "w2"), -2),                    # (E, di, d): row
+    (("moe", "w1"), -1),                    # (held, d, 2*di): column
+    (("moe", "w2"), -2),                    # (held, di, d): row
+    (("shared", "fc1", "kernel"), -1),      # the shared expert, an MLP
+    (("shared", "fc2", "kernel"), -2),
 )
 
-# leaves whose first non-layer axis is the MoE expert dimension
+# leaves whose first non-layer axis is the MoE expert dimension: the experts
+# HELD (cfg.moe_held), which ``mesh.expert`` divides further in training; the
+# router and the shared expert are every device's whole
 _EXPERT_RULES: tuple[tuple[str, ...], ...] = (
     ("moe", "w1"),
     ("moe", "w2"),

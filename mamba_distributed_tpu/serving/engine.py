@@ -170,11 +170,15 @@ def _tick(params: dict, pool: dict, tbl=None, lengths=None, lanes=None, *,
           cfg: ModelConfig, k_max: int, steps: int, mesh=None,
           n_micro=None):
     """Advance every slot ``steps`` tokens.  Returns (pool', tokens
-    (steps, S), emitted (steps, S), done (steps, S)) — ``emitted[j, s]``
-    marks a real token (slot live at sub-step j), ``done[j, s]`` the
+    (steps, S), emitted (steps, S), done (steps, S), load) — ``emitted[j,
+    s]`` marks a real token (slot live at sub-step j), ``done[j, s]`` the
     slot's finish state after it; the rest is masked garbage.  The host
     consumes ``done`` rather than re-deriving the finish rule, so there
-    is exactly one copy of it (here).
+    is exactly one copy of it (here).  ``load`` (held + 1,) int32 is the
+    expert layers' (models/lm._moe_mlp) over the launch's sub-steps and
+    layers: a held expert's rows from the live lanes, and last the held
+    experts reached; None for a dense model, whose program it leaves as
+    it was.
 
     ``lanes`` — ``(idx, keep)``, (W,) int32 and (W,) bool, both traced —
     makes the launch a NARROW rung of the engine's ladder: the rows of
@@ -305,15 +309,16 @@ def _tick(params: dict, pool: dict, tbl=None, lengths=None, lanes=None, *,
         advance = ~meta["prefilling"]
         if hybrid:
             state_in = {**pool["state"], "attn_meta": (tbl, lengths)}
-            logits, state = lm_step(params, cfg, state_in, tok,
-                                    write_mask=live, state_mask=advance)
+            logits, state, load = lm_step(
+                params, cfg, state_in, tok, write_mask=live,
+                state_mask=advance, return_load=True)
             lengths = state["attn_meta"][1]
             state = {k: v for k, v in state.items() if k != "attn_meta"}
         else:
-            logits, state = lm_step(
-                params, cfg, pool["state"], tok,
+            logits, state, load = lm_step(
+                params, cfg, pool["state"], tok, write_mask=live,
                 pipeline=((mesh, n_micro) if n_micro else None),
-                state_mask=advance,
+                state_mask=advance, return_load=True,
             )
         with jax.named_scope(scopes.POOL_SELECT):
             logits = jnp.where(advance[:, None], logits, pool["logits"])
@@ -326,19 +331,21 @@ def _tick(params: dict, pool: dict, tbl=None, lengths=None, lanes=None, *,
             "logits": logits,
             "meta": {**meta, "step": step, "done": done},
         }
-        return (new_pool, lengths), (tok, live, done)
+        return (new_pool, lengths), (tok, live, done, load)
 
     # the sub-step scan carries the whole pool (the slots' recurrent state;
     # hybrid: the KV pages too), so what it moves around its body is filed
     # as a layer scan's own
     with jax.named_scope(scopes.ATTN_LAYERS if hybrid else scopes.LAYERS):
-        (pool, _), (tokens, emitted, done) = jax.lax.scan(
+        (pool, _), (tokens, emitted, done, load) = jax.lax.scan(
             one, (pool, lengths), None, length=steps
         )
     if whole is not None:
         pool = _with_slot_rows(pool, state_cache.scatter_rows(
             _slot_rows(whole), _slot_rows(pool), *lanes, mesh=mesh))
-    return pool, tokens, emitted, done
+    if load is not None:
+        load = jnp.sum(load, axis=0)
+    return pool, tokens, emitted, done, load
 
 
 def _slot_rows(pool: dict) -> dict:
@@ -708,6 +715,10 @@ class ServingEngine:
         else:
             self.drafter = None
         self.hybrid = bool(cfg.attn_layer_idx)
+        # expert layers: every launch also returns its load (a held
+        # expert's rows), read with the tick's fetch (_note_expert_load)
+        self._moe = cfg.moe_num_experts > 0
+        self._chunk_loads: list = []  # (span, device load, real tokens)
         if self.hybrid:
             self.page_pool = state_cache.PagePool(
                 state_cache.hybrid_pool_pages(cfg, capacity,
@@ -1562,12 +1573,17 @@ class ServingEngine:
             t0 = time.perf_counter()
             with self.tracer.span("serving_prefill_chunk", slot=slot,
                                   chunk=i, of=plan.n_chunks,
-                                  trace=tracked.trace_id):
-                logits, state = prefill_chunk(
+                                  trace=tracked.trace_id) as span:
+                logits, state, *load = prefill_chunk(
                     self._params, ids, mask, state, cfg=self.cfg,
-                    mesh=self._tp_mesh,
+                    mesh=self._tp_mesh, return_load=self._moe,
                     **self._lora_call_kw(tracked),
                 )
+                if load:
+                    # only dispatched here: read when the next tick's
+                    # fetch has returned (_note_expert_load)
+                    self._chunk_loads.append(
+                        (span, load[0], plan.real_tokens(i)))
                 if self.hybrid:
                     # pages were written in place (donated): swap the
                     # fresh buffers into the pool IMMEDIATELY — before
@@ -2528,7 +2544,7 @@ class ServingEngine:
         return (self.stage_shards if width % self.stage_shards == 0
                 else 1)
 
-    def _run_tick(self, live_slots, width: int, bucket=None):
+    def _run_tick(self, live_slots, width: int, bucket=None, span=None):
         """One decode tick at rung ``width``: a single ``_tick`` launch.
         Under the capacity the launch is NARROW — the live slots' rows
         gathered into ``width`` lanes, advanced, and written back into
@@ -2542,7 +2558,10 @@ class ServingEngine:
 
         With no ``live_slots`` the launch is the ladder's warm-up: every
         lane pad (at the capacity: every slot parked by ``idle_meta``),
-        nothing advanced, nothing written, nothing fetched."""
+        nothing advanced, nothing written, nothing fetched.
+
+        ``span`` is the launch's ``serving_tick`` span: a model with expert
+        layers sets the launch's counters on it (``_note_expert_load``)."""
         narrow = width < self.capacity
         warm = not live_slots
         pool, tick_kv, lanes_dev, parked = self.pool, (), None, None
@@ -2557,7 +2576,7 @@ class ServingEngine:
             if warm:
                 parked = pool["meta"]  # kept out of the launch's donation
                 pool = {**pool, "meta": state_cache.idle_meta(parked)}
-        pool, tokens, emitted, done = _tick(
+        pool, tokens, emitted, done, load = _tick(
             self._params, pool, *map(jnp.asarray, tick_kv),
             lanes=lanes_dev, cfg=self.cfg, k_max=self.max_top_k,
             steps=self.tokens_per_tick, mesh=self.mesh,
@@ -2571,6 +2590,10 @@ class ServingEngine:
         tokens = np.asarray(tokens)  # (steps, width) — the host sync
         emitted = np.asarray(emitted)
         done = np.asarray(done)
+        if load is not None:
+            # the launch has returned: its load, and that of the chunks
+            # dispatched before it, are there to be read
+            self._note_expert_load(span, load, int(emitted.sum()))
         if narrow:
             steps = tokens.shape[0]
             cols = np.fromiter(lanes.keys(), np.int64, len(lanes))
@@ -2587,6 +2610,23 @@ class ServingEngine:
             # sub-step, exactly what `emitted` marks
             self._kv_len += emitted.sum(axis=0).astype(np.int32)
         return tokens, emitted, done
+
+    def _note_expert_load(self, span, load, rows: int) -> None:
+        """A launch's expert load, once its fetch has returned: counted in
+        ``self.metrics`` and set on the launch's span as ``expert_rows``
+        (with ``expert_hits``), ``expert_rows_share`` and
+        ``expert_load_max_over_mean``.  ``rows``
+        is what the launch served (live lane sub-steps, real chunk tokens);
+        each offered ``moe_top_k`` choices in each layer.  The chunks
+        dispatched before this launch ran before it: theirs are read here
+        too, with no fetch of their own."""
+        offered = self.cfg.moe_top_k * self.cfg.n_layer
+        for sp, ld, n in (*self._chunk_loads, (span, load, rows)):
+            stats = self.metrics.record_expert_load(
+                np.asarray(ld), n * offered)
+            if getattr(sp, "attrs", None) is not None:
+                sp.attrs.update(stats)
+        self._chunk_loads.clear()
 
     def _warm_ladder(self, width: int, bucket) -> None:
         """A launch shape the engine has not run brings its whole ladder
@@ -2842,7 +2882,7 @@ class ServingEngine:
                               prefill_tokens=(
                                   self._pending_chunk_tokens
                                   + self._pending_oneshot_real_tokens),
-                              traces=live_traces):
+                              traces=live_traces) as span:
             if self.spec:
                 # speculative draft-verify tick: one lm_verify_chunk
                 # launch commits up to spec_width+1 tokens per slot
@@ -2855,7 +2895,7 @@ class ServingEngine:
                           else None)
                 self._warm_ladder(width, bucket)
                 tokens, emitted, done = self._run_tick(
-                    live_slots, width, bucket)
+                    live_slots, width, bucket, span)
         t_now = time.perf_counter()
         with self.tracer.span("serving_emit"):
             return self._emit(tokens, emitted, done, t0, t_now, occupied,

@@ -173,13 +173,18 @@ def chunk_inputs(
     return jnp.asarray(out), jnp.asarray(mask)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "mesh"),
+@functools.partial(jax.jit, static_argnames=("cfg", "mesh", "return_load"),
                    donate_argnums=(3,))
 def prefill_chunk(
     params: dict, ids: jax.Array, mask: jax.Array, state, cfg: ModelConfig,
     mesh=None, adapter_ids: jax.Array | None = None,
+    return_load: bool = False,
 ):
     """The compiled chunk step: (ids, mask, carry) -> (last logits, carry').
+
+    ``return_load`` (static; the engine sets it for a model with expert
+    layers) adds a third result: the chunk's expert load, (held + 1,) int32,
+    of its REAL tokens over the layers (models/lm._moe_mlp).
 
     ``params`` must already be decode-cast (``cast_decode_params``) —
     both drivers pass the same cast output, which is what makes their
@@ -217,7 +222,8 @@ def prefill_chunk(
         )
 
         params = bind_adapter_ids(params, adapter_ids)
-    return lm_prefill_chunk(params, cfg, ids, state, token_mask=mask)
+    return lm_prefill_chunk(params, cfg, ids, state, token_mask=mask,
+                            return_load=return_load)
 
 
 def chunked_prefill(

@@ -117,14 +117,15 @@ def flops_per_token(
         if cfg.d_intermediate > 0:
             mlp = 6 * cfg.d_model * cfg.d_intermediate
             if cfg.moe_num_experts:
-                # each token runs top_k experts ("model"); the executed
-                # capacity slots include the cf padding ("hardware")
-                mult = (
-                    cfg.moe_top_k * cfg.moe_capacity_factor
-                    if convention == "hardware" else cfg.moe_top_k
-                )
-                total += mlp * mult
+                # a token's top_k choices fall on the experts held here in
+                # the held share of the router's width (a seed's router is
+                # near uniform); dropless, so both conventions count the
+                # same products.  The router is whole, and the shared
+                # expert is every token's.
+                total += mlp * cfg.moe_top_k * (
+                    cfg.moe_held[1] / cfg.moe_num_experts)
                 total += 2 * cfg.d_model * cfg.moe_num_experts  # router
+                total += 6 * cfg.d_model * cfg.moe_shared_intermediate
             else:
                 total += mlp
     total += 2 * cfg.d_model * cfg.vocab_size_padded  # LM head

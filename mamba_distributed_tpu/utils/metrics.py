@@ -173,6 +173,13 @@ class ServingMetrics:
         self.queue_wait_ms = StreamingHistogram()
         self.ttft_ms = StreamingHistogram()
         self.itl_ms = StreamingHistogram()
+        # expert layers (models/lm._moe_mlp; the engine reports a launch's
+        # load once its fetch has returned): rows routed to the experts
+        # held here, rows offered (served rows x top_k x layers), and each
+        # launch's largest-over-mean load of a held expert
+        self.expert_rows = 0
+        self.expert_rows_offered = 0
+        self.expert_load_max_over_mean = StreamingHistogram()
         # prefix-state cache (serving/prefix_cache.py): the engine calls
         # configure_prefix_cache() when the cache is on, unlocking the
         # summary()["prefix_cache"] section — hit-rate, saved prefill
@@ -355,6 +362,27 @@ class ServingMetrics:
         self.prefill_chunks += 1
         self.prefill_chunk_tokens += chunk_tokens
         self.prefill_chunk_time_s += dt_s
+
+    def record_expert_load(self, load, rows_offered: int) -> dict:
+        """One launch (a decode tick, a prefill chunk) of a model with
+        expert layers, as ``models/lm._moe_mlp`` counts it over the
+        launch's sub-steps and layers: ``load[e]`` rows reached held expert
+        ``e``, and last the held experts reached; of ``rows_offered``
+        (served rows x top_k x layers).  Returns the launch's figures under
+        the names its span carries them."""
+        load, hits = load[:-1], int(load[-1])
+        rows, held = int(load.sum()), len(load)
+        self.expert_rows += rows
+        self.expert_rows_offered += rows_offered
+        out = {"expert_rows": rows, "expert_hits": hits,
+               "expert_rows_share": rows / rows_offered if rows_offered
+               else None,
+               "expert_load_max_over_mean":
+                   float(load.max()) * held / rows if rows else None}
+        if rows:
+            self.expert_load_max_over_mean.record(
+                out["expert_load_max_over_mean"])
+        return out
 
     def record_prefill_stall(self, dt_s: float) -> None:
         """Host seconds one engine step spent on prefill work (admissions
@@ -1059,6 +1087,13 @@ class ServingMetrics:
                     "frees": self.kv_page_frees,
                 }
             ),
+            "experts": (None if not self.expert_rows_offered else {
+                "rows": self.expert_rows,
+                "rows_offered": self.expert_rows_offered,
+                "rows_share": self.expert_rows / self.expert_rows_offered,
+                "load_max_over_mean":
+                    self.expert_load_max_over_mean.summary(),
+            }),
             "compile": (None if not self._compile_on else {
                 "compiles": self.compiles,
                 "compile_ms": round(self.compile_ms_total, 3),

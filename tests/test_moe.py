@@ -31,10 +31,10 @@ MOE_KW = dict(
 
 
 def test_identical_experts_match_dense(rng):
-    """With every expert holding the SAME weights and ample capacity, the
-    top-k mixture must equal the dense gated MLP (combine weights sum
-    to 1) — the routing/dispatch/combine algebra's exact oracle."""
-    cfg = ModelConfig(**MOE_KW, moe_capacity_factor=8.0)
+    """With every expert holding the SAME weights, the top-k mixture must
+    equal the dense gated MLP (the gates sum to 1) — the routing/grouping/
+    combine algebra's exact oracle."""
+    cfg = ModelConfig(**MOE_KW)
     d, di, E = cfg.d_model, cfg.d_intermediate, cfg.moe_num_experts
     k1, k2, k3, k4 = jax.random.split(rng, 4)
     w1 = jax.random.normal(k1, (d, 2 * di)) * 0.1
@@ -47,7 +47,7 @@ def test_identical_experts_match_dense(rng):
     x = jax.random.normal(k4, (2, 16, d))
     dense = _gated_mlp({"fc1": {"kernel": w1}, "fc2": {"kernel": w2}},
                        x, jnp.float32)
-    out, aux = _moe_mlp(params, cfg, x, jnp.float32)
+    out, aux, _ = _moe_mlp(params, cfg, x, jnp.float32)
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                atol=1e-5, rtol=1e-5)
     assert np.isfinite(float(aux))
@@ -63,14 +63,37 @@ def test_aux_loss_is_one_at_perfect_balance(rng):
         "w2": jnp.zeros((E, di, d)),
     }
     x = jax.random.normal(rng, (2, 32, d))
-    _, aux = _moe_mlp(params, cfg, x, jnp.float32)
+    _, aux, _ = _moe_mlp(params, cfg, x, jnp.float32)
     np.testing.assert_allclose(float(aux), 1.0, rtol=1e-6)
 
 
-def test_capacity_drops_are_harmless(rng):
-    """A tiny capacity factor forces drops; the layer must stay finite
-    (dropped tokens ride the residual) and gradients must flow."""
-    cfg = ModelConfig(**MOE_KW, moe_capacity_factor=0.25)
+@pytest.mark.parametrize("rows", [8, 512])
+def test_nothing_is_dropped_at_any_load(rng, rows, monkeypatch):
+    """Every row routed to ONE expert (the load the old capacity dropped
+    at): the layer must answer each row with that expert's whole product
+    under gate 1, in the masked form (few rows) and the grouped one (many),
+    and gradients must flow through the model with that router."""
+    from mamba_distributed_tpu.models import lm as lm_mod
+
+    monkeypatch.setattr(lm_mod, "MOE_DENSE_MAX_ROWS", 64)
+    cfg = ModelConfig(**MOE_KW, moe_top_k=1)
+    d, di, E = cfg.d_model, cfg.d_intermediate, cfg.moe_num_experts
+    k1, k2, k3 = jax.random.split(rng, 3)
+    w1 = jax.random.normal(k1, (E, d, 2 * di)) * 0.1
+    w2 = jax.random.normal(k2, (E, di, d)) * 0.1
+    # positive inputs and a router whose column 2 is largest: all to 2
+    x = jnp.abs(jax.random.normal(k3, (1, rows, d))) + 0.1
+    router = jnp.zeros((d, E)).at[:, 2].set(1.0)
+    out, _, load = _moe_mlp(
+        {"router": {"kernel": router}, "w1": w1, "w2": w2}, cfg, x,
+        jnp.float32)
+    want = _gated_mlp({"fc1": {"kernel": w1[2]}, "fc2": {"kernel": w2[2]}},
+                      x, jnp.float32)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+    # a held expert's rows, then the held experts reached
+    assert np.asarray(load).tolist() == [0, 0, rows, 0, 1]
+
     params = init_lm_params(jax.random.PRNGKey(0), cfg)
     ids = jax.random.randint(rng, (2, 32), 0, cfg.vocab_size)
     tgt = jax.random.randint(jax.random.fold_in(rng, 1), (2, 32), 0,
@@ -133,7 +156,7 @@ def test_config_rejects_bad_moe():
 def _trainer_losses(tmp, mesh, micro, steps=3):
     from mamba_distributed_tpu.training import Trainer
 
-    model = ModelConfig(**{**MOE_KW, "moe_capacity_factor": 8.0})
+    model = ModelConfig(**MOE_KW)
     dp = mesh.data * mesh.fsdp * mesh.expert
     cfg = TrainConfig(
         model=model,

@@ -330,7 +330,7 @@ def test_stash_survives_tick():
     assert np.asarray(pool["meta"]["prefilling"]).tolist() == [False, True]
     before = [np.asarray(x) for x in jax.tree.leaves(
         state_cache.read_state(pool, 1))]
-    pool, tokens, emitted, done = engine_mod._tick(
+    pool, tokens, emitted, done, _ = engine_mod._tick(
         dparams, pool, cfg=cfg, k_max=5, steps=3
     )
     # slot 0 decoded, slot 1 emitted nothing and its carry is untouched

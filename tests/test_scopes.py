@@ -209,7 +209,8 @@ def test_table_is_constants_only():
     assert len(set(scopes.ALL)) == len(scopes.ALL) == 19
     # the grouping scopes stand beside the table, not in it (the benchmark
     # holds ``ALL`` equal to its own copy, which only a benchmark PR edits)
-    assert scopes.BLOCK_PARTS == ("ssm_branch", "attn_branch", "mlp")
+    assert scopes.BLOCK_PARTS == ("ssm_branch", "attn_branch", "mlp",
+                                  "moe", "router", "experts")
     assert not set(scopes.BLOCK_PARTS) & set(scopes.ALL)
     public = {k: v for k, v in vars(scopes).items()
               if k.isupper() and k not in ("ALL", "BLOCK_PARTS")}
